@@ -30,9 +30,6 @@ def main(argv=None):
 
     import jax
 
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
     from mine_tpu.utils import configure_compile_cache
     configure_compile_cache()
 

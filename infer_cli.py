@@ -47,11 +47,6 @@ def main():
                         help="drift proxy (default: serve.session.drift_mode)")
     args = parser.parse_args()
 
-    import jax
-
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
     from mine_tpu.utils import configure_compile_cache
     configure_compile_cache()
 

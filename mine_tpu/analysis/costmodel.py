@@ -20,11 +20,11 @@ program change. This is the HBM-fit oracle the ROADMAP's MPMD-pipeline and
 AOT-cold-start items need: "does this program's working set fit one chip"
 becomes a table lookup instead of an OOM on silicon.
 
-The roofline estimate prices a program against a chip model given
-``MINE_TPU_BENCH_PEAK_TFLOPS`` (bench.py's knob, v5e bf16 default) and
-``MINE_TPU_BENCH_HBM_GBPS``: expected step time is the max of the compute
-and memory legs, and the binding leg names the bottleneck. Env-dependent,
-so it is *reported* (pass details, flops_report) but never baseline-gated.
+The roofline estimate prices a program against the published peaks of a
+named chip (`CHIP_PEAKS`, keyed by jax's `device_kind`; a kind that is not
+in the table is an error, never a default): expected step time is the max
+of the compute and memory legs, and the binding leg names the bottleneck.
+It is *reported* (pass details, flops_report) but never baseline-gated.
 
 tools/flops_report.py is now a thin CLI shim over `attribution_report`
 below (same precedent as tools/dtype_audit.py -> analysis/dtype.py).
@@ -32,7 +32,6 @@ below (same precedent as tools/dtype_audit.py -> analysis/dtype.py).
 
 from __future__ import annotations
 
-import os
 import sys
 from typing import Dict, Optional
 
@@ -41,29 +40,33 @@ from typing import Dict, Optional
 COST_KEYS = ("flops", "bytes_accessed", "argument_bytes", "output_bytes",
              "temp_bytes", "alias_bytes", "peak_hbm_bytes")
 
-# chip model defaults: v5e bf16 peak (bench.py's CHIP_PEAK_TFLOPS default)
-# and v5e HBM bandwidth. Both overridable via the bench env knobs.
-DEFAULT_PEAK_TFLOPS = 197.0
-DEFAULT_HBM_GBPS = 819.0
+# Published peaks of ONE chip, keyed by `jax.devices()[0].device_kind`,
+# each with its source. The one table bench.py's physics audit and the
+# roofline below both price against.
+CHIP_PEAKS = {
+    "TPU v5 lite": {
+        "peak_tflops": 197.0,   # bf16
+        "hbm_gbps": 819.0,
+        "hbm_gb": 16.0,
+        "source": 'Google Cloud documentation, "TPU v5e": 197 TFLOP/s '
+                  'bf16, 16 GB HBM2e at 819 GB/s per chip',
+    },
+}
+# the chip the static cost model (cost_budget audit pass, pipeline planner)
+# prices programs FOR: it runs on the CPU, where no device can be asked
+MODEL_DEVICE_KIND = "TPU v5 lite"
 
 
-def chip_model() -> Dict[str, float]:
-    """The (peak TFLOP/s, HBM GB/s) pair the roofline prices against."""
-    return {
-        "peak_tflops": float(os.environ.get("MINE_TPU_BENCH_PEAK_TFLOPS",
-                                            DEFAULT_PEAK_TFLOPS)),
-        "hbm_gbps": float(os.environ.get("MINE_TPU_BENCH_HBM_GBPS",
-                                         DEFAULT_HBM_GBPS)),
-    }
-
-
-def _unwrap_cost_analysis(compiled) -> Dict:
-    """jax 0.4.x returns one properties-dict per partition as a list;
-    newer versions return the dict directly. Normalize to the dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
+def chip_model(device_kind: str) -> Dict[str, object]:
+    """Published peaks of `device_kind`; an unknown kind is an error
+    wherever a device number is priced, never a default."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}: add it "
+            f"to CHIP_PEAKS (analysis/costmodel.py) with its source; "
+            f"known: {sorted(CHIP_PEAKS)}") from None
 
 
 def compiled_cost(jit_fn, args) -> Dict[str, int]:
@@ -71,7 +74,7 @@ def compiled_cost(jit_fn, args) -> Dict[str, int]:
     (COST_KEYS). Works on CPU: XLA's cost and buffer-assignment analyses
     run on the optimized HLO regardless of backend."""
     compiled = jit_fn.lower(*args).compile()
-    ca = _unwrap_cost_analysis(compiled)
+    ca = dict(compiled.cost_analysis() or {})
     ma = compiled.memory_analysis()
     arg = int(getattr(ma, "argument_size_in_bytes", 0) or 0)
     out = int(getattr(ma, "output_size_in_bytes", 0) or 0)
@@ -95,11 +98,12 @@ def measure_program(program) -> Dict[str, int]:
 
 def roofline(cost: Dict[str, int],
              peak_tflops: Optional[float] = None,
-             hbm_gbps: Optional[float] = None) -> Dict[str, object]:
+             hbm_gbps: Optional[float] = None,
+             device_kind: str = MODEL_DEVICE_KIND) -> Dict[str, object]:
     """Two-leg roofline: expected time is max(flops/peak, bytes/bandwidth),
     the binding leg is the bottleneck, and arithmetic intensity (flops per
     byte accessed) tells how far from the ridge the program sits."""
-    chip = chip_model()
+    chip = chip_model(device_kind)
     peak = peak_tflops if peak_tflops is not None else chip["peak_tflops"]
     bw = hbm_gbps if hbm_gbps is not None else chip["hbm_gbps"]
     compute_ms = cost["flops"] / (peak * 1e12) * 1e3
@@ -120,7 +124,7 @@ def roofline(cost: Dict[str, int],
 
 # ------------------------------------------------- flops_report attribution
 
-V5E_BF16_PEAK_TFLOPS = 197.0
+V5E_BF16_PEAK_TFLOPS = CHIP_PEAKS[MODEL_DEVICE_KIND]["peak_tflops"]
 
 
 def attribution_report(argv=None) -> None:

@@ -23,6 +23,7 @@ from typing import Dict, List
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from mine_tpu import geometry
@@ -44,6 +45,25 @@ def _smooth_noise(rng: np.random.RandomState, h: int, w: int, c: int,
     top = small[y0][:, x0] * (1 - tx) + small[y0][:, x1] * tx
     bot = small[y1][:, x0] * (1 - tx) + small[y1][:, x1] * tx
     return top * (1 - ty) + bot * ty
+
+
+@jax.jit
+def _render_views(mpi_rgb, mpi_sigma, disparity, K_33, G_V44):
+    """The one canonical MPI [1,S,...] rendered into V poses, as ONE
+    program (op by op this is ~100 small compiles per dataset, about a
+    second each on the TPU). Returns (rgb [V,3,H,W], depth [V,H,W])."""
+    H, W = mpi_rgb.shape[-2:]
+    V = G_V44.shape[0]
+    K = jnp.broadcast_to(K_33, (V, 3, 3))
+    K_inv = geometry.inverse_intrinsics(K)
+    disp = jnp.broadcast_to(disparity, (V,) + disparity.shape[1:])
+    xyz_world = geometry.plane_xyz_src(geometry.cached_pixel_grid(H, W),
+                                       disp, K_inv)
+    res = rendering.render_tgt_rgb_depth(
+        jnp.broadcast_to(mpi_rgb, (V,) + mpi_rgb.shape[1:]),
+        jnp.broadcast_to(mpi_sigma, (V,) + mpi_sigma.shape[1:]),
+        disp, geometry.plane_xyz_tgt(xyz_world, G_V44), G_V44, K_inv, K)
+    return res.rgb, res.depth[:, 0]
 
 
 class SyntheticMPIDataset:
@@ -98,22 +118,12 @@ class SyntheticMPIDataset:
             self.G_cam_world.append(G)
 
         # render every view from the canonical MPI
-        K_j = jnp.asarray(K)[None]
-        K_inv_j = geometry.inverse_intrinsics(K_j)
-        grid = geometry.cached_pixel_grid(H, W)
-        xyz_world = geometry.plane_xyz_src(grid, self.disparity, K_inv_j)
-
-        self.images: List[np.ndarray] = []
-        self.depths: List[np.ndarray] = []
-        for G in self.G_cam_world:
-            Gj = jnp.asarray(G)[None]
-            xyz_v = geometry.plane_xyz_tgt(xyz_world, Gj)
-            res = rendering.render_tgt_rgb_depth(
-                self.mpi_rgb, self.mpi_sigma, self.disparity, xyz_v, Gj,
-                K_inv_j, K_j)
-            img = np.asarray(res.rgb[0])          # [3,H,W]
-            self.images.append(np.clip(img, 0.0, 1.0))
-            self.depths.append(np.asarray(res.depth[0, 0]))  # [H,W]
+        rgb_v, depth_v = _render_views(
+            self.mpi_rgb, self.mpi_sigma, self.disparity, jnp.asarray(K),
+            jnp.asarray(np.stack(self.G_cam_world)))
+        self.images: List[np.ndarray] = [
+            np.clip(img, 0.0, 1.0) for img in np.asarray(rgb_v)]  # [3,H,W]
+        self.depths: List[np.ndarray] = list(np.asarray(depth_v))  # [H,W]
 
         # per-view camera-frame 3D points from rendered depth
         self.pt3d: List[np.ndarray] = []

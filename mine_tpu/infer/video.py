@@ -19,6 +19,8 @@ moviepy (the reference's writer) is not in this image.
 
 from __future__ import annotations
 
+import functools
+import logging
 import os
 from typing import Dict, List, Optional
 
@@ -35,6 +37,8 @@ from mine_tpu.serve import (ContinuousBatcher, MPICache, RenderEngine,
                             SessionManager, image_id_for)
 from mine_tpu.train.step import sample_disparity
 from mine_tpu.utils import disparity_normalization_vis
+
+_log = logging.getLogger(__name__)
 
 
 def path_planning(num_frames: int, x: float, y: float, z: float,
@@ -141,6 +145,33 @@ def _blend_mpi(cfg, backend: str, mpi, img_1hw3, disparity, K_inv):
     return mpi_rgb, sigma
 
 
+@jax.jit
+def _src_from_tgt_homographies(K_33, K_inv_33, poses_F44, depths_S):
+    """[F,S,3,3] target->source homographies: one source of truth, the
+    same composition the device warp uses (geometry.homography_tgt_src),
+    batched over [F,S]; one program, not one per op."""
+    F, S = poses_F44.shape[0], depths_S.shape[0]
+    Hts = geometry.homography_tgt_src(
+        jnp.broadcast_to(K_33, (F, S, 3, 3)),
+        jnp.broadcast_to(K_inv_33, (F, S, 3, 3)),
+        jnp.broadcast_to(poses_F44[:, None], (F, S, 4, 4)),
+        jnp.broadcast_to(depths_S[None, :], (F, S)))
+    return geometry.inverse_3x3(Hts)
+
+
+@functools.lru_cache(maxsize=8)
+def _encode_program(model, cfg, backend: str):
+    """Network pass + source blend as ONE jitted program per (model,
+    config, backend), shared by every image of a run. Op by op, the same
+    ResNet-50 encode is ~600 small programs, and on the TPU each costs its
+    own compile of about a second (chip run, PR 24: the serve child spent
+    376 s compiling and never reached a render)."""
+    def encode(variables, img_1hw3, disparity, K_inv):
+        mpi = model.apply(variables, img_1hw3, disparity, train=False)[0]
+        return _blend_mpi(cfg, backend, mpi, img_1hw3, disparity, K_inv)
+    return jax.jit(encode)
+
+
 class VideoGenerator:
     """Encode one image, then render trajectories in jitted pose chunks."""
 
@@ -169,6 +200,16 @@ class VideoGenerator:
 
         self.K = jnp.asarray(geometry.intrinsics_from_fov(H, W, 90.0))[None]
         self.K_inv = geometry.inverse_intrinsics(self.K)
+        self.image_id = image_id_for(np.asarray(self.img))
+        self.disparity = sample_disparity(jax.random.PRNGKey(seed), 1,
+                                          self.cfg)
+
+        # a shared engine that already holds this image's planes serves it
+        # render-only: the encoder does not run again (serve_cli.py)
+        self.engine = engine
+        self.encoded = engine is None or self.image_id not in engine.cache
+        if not self.encoded:
+            return
 
         model = MPIPredictor(
             num_layers=self.cfg.num_layers,
@@ -177,10 +218,11 @@ class VideoGenerator:
             dtype=dtype)
 
         # one network pass (reference infer_network :112-153)
-        disparity = sample_disparity(jax.random.PRNGKey(seed), 1, self.cfg)
         if encoder_quant == "off":
             variables = {"params": params, "batch_stats": batch_stats}
-            mpi = model.apply(variables, self.img, disparity, train=False)[0]
+            self.mpi_rgb, self.mpi_sigma = _encode_program(
+                model, self.cfg, self.backend)(
+                    variables, self.img, self.disparity, self.K_inv)
         else:
             # serve.encoder_quant=int8: weights stored per-channel int8 with
             # the widening dequant fused into the jitted encode
@@ -189,24 +231,21 @@ class VideoGenerator:
             from mine_tpu.serve.encoder import make_encode_fn
             encode = make_encode_fn(model, params, batch_stats,
                                     encoder_quant=encoder_quant)
-            mpi = encode(self.img, disparity)
-        self.disparity = disparity
-
-        self.mpi_rgb, self.mpi_sigma = _blend_mpi(
-            self.cfg, self.backend, mpi, self.img, disparity, self.K_inv)
+            mpi = encode(self.img, self.disparity)
+            self.mpi_rgb, self.mpi_sigma = _blend_mpi(
+                self.cfg, self.backend, mpi, self.img, self.disparity,
+                self.K_inv)
 
         # hand the encode to the serving engine's cache; trajectories render
         # through its bucketed jitted program (one compile set per warp impl)
         if engine is None:
-            engine = RenderEngine(
+            self.engine = engine = RenderEngine(
                 use_alpha=self.cfg.use_alpha,
                 is_bg_depth_inf=self.cfg.is_bg_depth_inf,
                 backend=self.backend,
                 warp_band=WARP_BAND,
                 max_bucket=chunk,
                 cache=MPICache(quant=cache_quant))
-        self.engine = engine
-        self.image_id = image_id_for(np.asarray(self.img))
         engine.put(self.image_id, self.mpi_rgb[0], self.mpi_sigma[0],
                    self.disparity[0], self.K[0])
 
@@ -221,15 +260,9 @@ class VideoGenerator:
         depths = 1.0 / np.asarray(self.disparity[0])  # [S]
         S = depths.shape[0]
 
-        # one source of truth: the same homography composition the device
-        # warp uses (geometry.homography_tgt_src), batched over [F,S]
-        G = jnp.broadcast_to(jnp.asarray(poses_F44)[:, None], (F, S, 4, 4))
-        d = jnp.broadcast_to(jnp.asarray(depths)[None, :], (F, S))
-        Hts = geometry.homography_tgt_src(
-            jnp.broadcast_to(self.K[0], (F, S, 3, 3)),
-            jnp.broadcast_to(self.K_inv[0], (F, S, 3, 3)),
-            G, d)
-        Hst = np.asarray(geometry.inverse_3x3(Hts))          # [F,S,3,3]
+        Hst = np.asarray(_src_from_tgt_homographies(
+            self.K[0], self.K_inv[0], jnp.asarray(poses_F44),
+            jnp.asarray(depths)))                            # [F,S,3,3]
 
         # block-boundary rows x coarse columns
         rows = np.stack([np.arange(0, H, rows_per_block),
@@ -260,6 +293,7 @@ class VideoGenerator:
             slack = _align_slack(WARP_BAND, int(self.cfg.img_h))
             if span + 4 + slack <= WARP_BAND:
                 warp_impl = "pallas"
+        self.last_warp_impl = warp_impl
         rgb, depth = self.engine.render(
             self.image_id, np.asarray(poses_F44, np.float32),
             warp_impl=warp_impl)
@@ -274,6 +308,11 @@ class VideoGenerator:
         written = []
         for poses, name in zip(trajectories, meta["names"]):
             rgb, disp = self.render_poses(poses)
+            _log.info("views %s_%s: warp=%s n=%d finite=%s rgb_min=%.4f "
+                      "rgb_max=%.4f rgb_std=%.4f", output_name, name,
+                      self.last_warp_impl, rgb.shape[0],
+                      bool(np.isfinite(rgb).all() and np.isfinite(disp).all()),
+                      rgb.min(), rgb.max(), rgb.std())
             disp_vis = disparity_normalization_vis(disp)
             rgb_u8 = _to_uint8_frames(rgb)
             disp_u8 = _colormap_frames(disp_vis)
@@ -341,26 +380,27 @@ class StreamRenderer:
                                           self.cfg)
         if encoder_quant == "off":
             variables = {"params": params, "batch_stats": batch_stats}
+            program = _encode_program(model, self.cfg, self.backend)
 
-            def _network(img_1hw3):
-                return model.apply(variables, img_1hw3, self.disparity,
-                                   train=False)[0]
+            def _encode(img_1hw3):
+                return program(variables, img_1hw3, self.disparity,
+                               self.K_inv)
         else:
             from mine_tpu.serve.encoder import make_encode_fn
             encode = make_encode_fn(model, params, batch_stats,
                                     encoder_quant=encoder_quant)
 
-            def _network(img_1hw3):
-                return encode(img_1hw3, self.disparity)
+            def _encode(img_1hw3):
+                return _blend_mpi(self.cfg, self.backend,
+                                  encode(img_1hw3, self.disparity),
+                                  img_1hw3, self.disparity, self.K_inv)
 
         def _encode_frame(img_hwc):
             """engine encode_fn: full network pass + source blend for ONE
-            observed frame — the keyframe path (identical ops to
-            VideoGenerator.__init__, via _blend_mpi)."""
+            observed frame — the keyframe path (the same program as
+            VideoGenerator.__init__)."""
             img = jnp.asarray(img_hwc, jnp.float32)[None]
-            mpi = _network(img)
-            mpi_rgb, mpi_sigma = _blend_mpi(self.cfg, self.backend, mpi,
-                                            img, self.disparity, self.K_inv)
+            mpi_rgb, mpi_sigma = _encode(img)
             return (mpi_rgb[0], mpi_sigma[0], self.disparity[0], self.K[0])
 
         self.encode_frame = _encode_frame

@@ -54,7 +54,8 @@ class MPIDecoder(nn.Module):
     #   default).
     # "packed": the stride-2->1 stage (upconv_0_* + dispconv_0 — the
     #   largest-pixel-count convs, capped at 16/128 MXU lanes by the
-    #   reference's tiny channel counts; BENCH_NOTES_r03.md lane table)
+    #   reference's tiny channel counts; lane table of the round-3 notes,
+    #   git history)
     #   computes at stride 2 with 4x channels and a depth-to-space at the
     #   head, lifting that stage to 64-lane occupancy. Conversion story: a
     #   nearest-upsample followed by a 3x3 conv is exactly a 4-phase conv

@@ -5,7 +5,8 @@ Flax module so the whole forward lives in one XLA graph.
 
 Plane-chunked decoding (`plane_chunks > 1`): the decoder's effective batch is
 B*S (depth_decoder.py:105-116) and its activations are the step's HBM peak —
-B=8 at LLFF shapes overflows a 16 GB v5e (BENCH_NOTES_r02.md). Chunking runs
+B=8 at LLFF shapes overflows a 16 GB v5e (round-2 notes in git history).
+Chunking runs
 the decoder plane_chunks times on S/plane_chunks planes each, with each call
 under jax.checkpoint, so the backward pass holds ONE chunk's activations at
 a time instead of all B*S.

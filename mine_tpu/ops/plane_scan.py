@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from mine_tpu.parallel.mesh import DATA_AXIS, PLANE_AXIS, axis_size
+from mine_tpu.parallel.mesh import DATA_AXIS, PLANE_AXIS
 
 
 def _local_composite(rgb, sigma, xyz, z_mask: bool, axis: str):
@@ -41,7 +41,7 @@ def _local_composite(rgb, sigma, xyz, z_mask: bool, axis: str):
     LOCAL shard's [B, S_loc, C, H, W]."""
     B, S_loc, _, H, W = rgb.shape
     idx = jax.lax.axis_index(axis)
-    n_shards = axis_size(axis)
+    n_shards = jax.lax.axis_size(axis)
 
     if z_mask:
         sigma = jnp.where(xyz[:, :, 2:3] >= 0.0, sigma, 0.0)

@@ -107,35 +107,12 @@ def put_batch(np_batch, mesh: Optional[Mesh]):
             for k, v in np_batch.items()}
 
 
-def shard_map(f, mesh: Mesh, in_specs, out_specs, check=False):
-    """Version-portable shard_map for every in-repo call site.
-
-    jax >= 0.7 exports `jax.shard_map` and spells the replication-check
-    flag `check_vma`; the 0.4.x line has it at
-    `jax.experimental.shard_map.shard_map` spelled `check_rep`. The checks
-    stay off either way: the wrapped bodies contain pallas_call outputs,
-    which carry no mesh-variance info for the checker to verify.
-    """
-    try:
-        from jax import shard_map as sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check)
-    except TypeError:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check)
-
-
-def axis_size(axis_name: str) -> int:
-    """Version-portable static mesh-axis size inside a shard_map body:
-    jax >= 0.6 has jax.lax.axis_size; earlier versions constant-fold
-    psum(1, axis) to the same Python int."""
-    try:
-        return jax.lax.axis_size(axis_name)
-    except AttributeError:
-        return jax.lax.psum(1, axis_name)
+def shard_map(f, mesh: Mesh, in_specs, out_specs):
+    """`jax.shard_map` with the variance check off, for every in-repo call
+    site: the wrapped bodies contain pallas_call outputs, which carry no
+    mesh-variance info for the checker to verify."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def constrain(x, mesh: Optional[Mesh], *spec):

@@ -2,7 +2,8 @@
 
 The fused train step (train/step.py _train_step_impl) is one XLA program;
 past the single-slice regime its activation footprint is the binding
-constraint (BENCH_NOTES_r02.md: B=8 LLFF overflows a 16 GB v5e). This module
+constraint (B=8 LLFF overflows a 16 GB v5e; round-2 notes in git
+history). This module
 schedules the step's four natural sub-programs — encoder, decoder,
 warp/composite, fused loss (SynthesisTrainer.stage_encode/stage_decode/
 stage_render/stage_loss) — as separately jitted stages over

@@ -29,6 +29,7 @@ executable back so the next replica boots warm.
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import json
 import logging
@@ -67,6 +68,26 @@ def env_fingerprint() -> Dict[str, Any]:
         "devices": f"{len(devices)}x{devices[0].device_kind}",
         "processes": jax.process_count(),
     }
+
+
+@contextlib.contextmanager
+def fresh_compile():
+    """Compile inside this block for real, never from JAX's persistent
+    compile cache. What goes into the store must come from the compiler:
+    XLA:CPU (jaxlib 0.9.0) serializes an executable it loaded from that
+    cache without its kernel functions, and the artifact then fails at its
+    first call in the replica that boots from it ("Function ... not
+    found"). The store is the persistence for these programs anyway."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
 
 
 def key_digest(key: Dict[str, Any]) -> str:
@@ -127,8 +148,14 @@ class AOTStore:
                 # digest collision or a hand-edited artifact: treat as a
                 # corrupt entry, never hand back a mismatched executable
                 raise ValueError("artifact key does not match request key")
+            # onto the devices the program was compiled for, in its own
+            # order: given none, jax loads onto every device of the backend
+            # and a one-device program then wants a shard per device
+            import jax
+            by_id = {d.id: d for d in jax.devices()}
             exe = se.deserialize_and_load(
-                blob["payload"], blob["in_tree"], blob["out_tree"])
+                blob["payload"], blob["in_tree"], blob["out_tree"],
+                execution_devices=[by_id[i] for i in blob["device_ids"]])
         except Exception as e:  # noqa: BLE001 - any failure means "miss"
             self.load_errors += 1
             telemetry.counter("serve.aot.load_errors").inc()
@@ -148,8 +175,11 @@ class AOTStore:
         try:
             from jax.experimental import serialize_executable as se
             payload, in_tree, out_tree = se.serialize(compiled)
+            device_ids = [d.id for d in
+                          compiled.runtime_executable().local_devices()]
             blob = pickle.dumps({"key": key, "payload": payload,
-                                 "in_tree": in_tree, "out_tree": out_tree})
+                                 "in_tree": in_tree, "out_tree": out_tree,
+                                 "device_ids": device_ids})
             os.makedirs(self.root, exist_ok=True)
             digest = key_digest(key)
             art, side = self._paths(digest)

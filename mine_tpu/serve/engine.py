@@ -365,8 +365,10 @@ class RenderEngine:
                 # miss or failed deserialize: live compile, write back so
                 # the NEXT replica boots warm (the store is an accelerator,
                 # never a correctness dependency)
-                exe = self._render.lower(*args,
-                                         warp_impl=warp_impl).compile()
+                from mine_tpu.serve import aot as _aot
+                with _aot.fresh_compile():
+                    exe = self._render.lower(*args,
+                                             warp_impl=warp_impl).compile()
                 self.aot_store.save(pkey, exe)
                 source = "compile"
             self._aot_execs[key] = exe

@@ -976,10 +976,6 @@ def main(argv=None) -> int:
                          "program-key-compatible")
     args = ap.parse_args(argv)
 
-    import jax
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
     from mine_tpu.serve import aot as serve_aot
 
     if args.build_artifact:

@@ -730,6 +730,11 @@ class TrainLoop:
                m["loss_rgb_src"], m["loss_ssim_src"], m["loss_disp_pt3dsrc"],
                m["loss_rgb_tgt"], m["loss_ssim_tgt"], m["loss_disp_pt3dtgt"],
                m["psnr_tgt"], step_line))
+        diag = " ".join("%s = %.6g" % (k, m[k]) for k in (
+            "skipped_steps", "guard_consecutive", "warp_fallback_frac")
+            if k in m)
+        if diag:
+            self._log("        diag: " + diag)
         if self.telem.enabled:
             # registry mirror: per-interval time breakdown histograms, the
             # guard's cumulative counters as gauges (they live in the

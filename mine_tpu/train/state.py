@@ -70,7 +70,8 @@ def make_optimizer(config: Dict[str, Any], steps_per_epoch: int) -> optax.Gradie
     training.grad_accum_steps > 1 wraps the whole thing in optax.MultiSteps
     (no reference equivalent — SURVEY.md section 2c "Gradient accumulation:
     NO"; added because one v5e chip caps the per-step batch at B<=4 at LLFF
-    shapes, BENCH_NOTES_r02.md): every micro-batch goes through the normal
+    shapes, round-2 notes in git history): every micro-batch goes through the
+    normal
     train_step, updates are emitted every k-th call with mean gradients,
     and state.step stays in micro-batch units everywhere (logging,
     checkpoint cadence, resume epoch math, current_lrs). The inner LR

@@ -249,10 +249,20 @@ class SynthesisTrainer:
         if seed is None:
             seed = int(self.config.get("training.seed", 0))
         H, W = self.cfg.img_h, self.cfg.img_w
-        img = jnp.zeros((batch_size, H, W, 3), jnp.float32)
-        disp = jnp.full((batch_size, self.cfg.num_bins_total), 0.5, jnp.float32)
-        return create_train_state(self.model, self.config, self.steps_per_epoch,
-                                  img, disp, seed=seed)
+
+        def init():
+            img = jnp.zeros((batch_size, H, W, 3), jnp.float32)
+            disp = jnp.full((batch_size, self.cfg.num_bins_total), 0.5,
+                            jnp.float32)
+            return create_train_state(self.model, self.config,
+                                      self.steps_per_epoch, img, disp,
+                                      seed=seed)
+
+        # ONE compiled program, placed where the step wants its state. Run
+        # op by op, a ResNet-50 init is ~700 small programs and the TPU's
+        # compiler takes about a second for each (chip run, PR 24: 337 s).
+        out = mesh_lib.replicated(self.mesh) if self.mesh is not None else None
+        return jax.jit(init, out_shardings=out)()
 
     # ---------------- forward ----------------
 

@@ -45,13 +45,8 @@ def main():
                         help="pre-compile every pose bucket before timing")
     args = parser.parse_args()
 
-    import jax
-
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
     from mine_tpu.utils import configure_compile_cache
-    configure_compile_cache()
+    compile_cache = configure_compile_cache()
 
     import cv2
     import numpy as np
@@ -67,10 +62,12 @@ def main():
     from mine_tpu.serve import (AOTStore, MPICache, RenderEngine, ServeFleet,
                                 quantize_weights_int8)
     from mine_tpu.train.step import SynthesisTrainer
-    from mine_tpu.utils import make_logger
+    from mine_tpu.utils import describe_runtime, make_logger
 
     os.makedirs(args.output_dir, exist_ok=True)
     logger = make_logger(os.path.join(args.output_dir, "serve.log"))
+    logger.info("Runtime: %s", json.dumps(
+        dict(describe_runtime(), compile_cache=compile_cache)))
 
     ckpt_dir = os.path.dirname(os.path.abspath(args.checkpoint_path))
     params_yaml = os.path.join(ckpt_dir, "params.yaml")
@@ -150,6 +147,8 @@ def main():
     # (mine_tpu/serve/fleet.py); the video path renders synchronously, so
     # the fleet's scheduler thread is left unstarted.
     backend = "pallas" if on_tpu_backend() else "xla"
+    logger.info("Backends: composite=%s warp=%s", backend,
+                serve_cfg.warp_backend)
     engine_kw = dict(
         use_alpha=bool(config.get("mpi.use_alpha", False)),
         is_bg_depth_inf=bool(config.get("mpi.is_bg_depth_inf", False)),
@@ -316,6 +315,8 @@ def main():
         gen = VideoGenerator(config, params, batch_stats, img,
                              chunk=serve_cfg.max_bucket, engine=engine,
                              encoder_quant=serve_cfg.encoder_quant)
+        logger.info("image %s: id=%s encode=%s", os.path.basename(path),
+                    gen.image_id[:12], "ran" if gen.encoded else "cached")
         if args.warmup and views == 0:
             engine.warmup(gen.image_id)
             if engine.aot_store is not None:
@@ -353,7 +354,8 @@ def main():
     logger.info("serve stats: entries=%d nbytes=%d hits=%d misses=%d "
                 "evictions=%d quant=%s device_calls=%d sync_encodes=%d "
                 "owner_hits=%d remote_routes=%d owner_encodes=%d "
-                "rebalances=%d aot_hits=%d aot_misses=%d aot_saves=%d",
+                "rebalances=%d aot_hits=%d aot_misses=%d aot_saves=%d "
+                "load_errors=%d",
                 stats["entries"], stats["nbytes"], stats["hits"],
                 stats["misses"], stats["evictions"], stats["quant"],
                 engine.device_calls, engine.sync_encodes,
@@ -361,7 +363,8 @@ def main():
                 stats.get("owner_encodes", 0), stats.get("rebalances", 0),
                 aot_store.hits if aot_store is not None else 0,
                 aot_store.misses if aot_store is not None else 0,
-                aot_store.saves if aot_store is not None else 0)
+                aot_store.saves if aot_store is not None else 0,
+                aot_store.load_errors if aot_store is not None else 0)
     if ring is not None:
         rs = ring.stats()
         logger.info("ring stats: hosts=%d alive=%d draining=%d dead=%d "

@@ -1,0 +1,169 @@
+"""CPU rehearsal of chip_smoke.py (the on-chip-measurement guide, section 2,
+rehearsals 1 and 2): the same script, the same CLIs as child processes, at
+tiny shapes with ResNet-18 and interpret-mode kernels on JAX_PLATFORMS=cpu.
+
+The script has no option for any of this. The rehearsal swaps its module
+constants from a driver process (which must itself stay off JAX, so it
+cannot be this pytest process): the platform the children are held to, the
+shapes, and the one check that needs Mosaic — a `tpu_custom_call` in the
+lowered program — is reduced to "the program was dumped".
+
+The failure cases stub the child processes instead of paying for real
+ones: what they pin is that the script turns a bad child into a non-zero
+exit and prints no result line.
+"""
+
+import json
+import os
+import subprocess
+import sys
+import textwrap
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+DRIVER = textwrap.dedent("""
+    import os, sys
+    sys.path.insert(0, %(root)r)
+    import chip_smoke as cs
+    cs.OUT = %(out)r
+    cs.PLATFORM = "cpu"                  # the platform check, inverted
+    cs.PALLAS_BACKENDS += ("xla",)       # serve_cli composites in XLA off-chip
+    cs.ONE_CHIP_ENV = {"XLA_FLAGS": "--xla_force_host_platform_device_count=1"}
+    cs.TRAIN_EXTRA.update({
+        "data.img_h": 64, "data.img_w": 64, "model.num_layers": 18,
+        "mpi.num_bins_coarse": 4, "training.epochs": 2,
+        "training.warp_backend": "pallas_diff",
+        "training.composite_backend": "pallas_diff",
+        "training.warp_band": 16})
+    cs.lowered_holds_kernel = lambda name, fn: any(
+        fn in f for f in os.listdir(os.path.join(cs.OUT, name + "_ir")))
+    rc = cs.main(sys.argv[1:])
+    print("PARENT_JAX_FREE" if "jax" not in sys.modules
+          else "PARENT_IMPORTED_JAX")
+    sys.exit(rc)
+""")
+
+
+def _rehearse(tmp_path, n_devices, args):
+    out = str(tmp_path / "smoke_out")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=%d"
+               % n_devices)
+    proc = subprocess.run(
+        [sys.executable, "-c", DRIVER % {"root": ROOT, "out": out}] + args,
+        env=env, capture_output=True, text=True, timeout=900)
+    assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
+    lines = proc.stdout.strip().splitlines()
+    assert lines[-1] == "PARENT_JAX_FREE"
+    return lines[:-1], out
+
+
+def _started(lines):
+    """Names of the child processes, in the order the script started them."""
+    return [ln.split("] ")[1].split(":")[0] for ln in lines
+            if ": exit " in ln]
+
+
+def test_rehearsal_one_chip(tmp_path):
+    lines, out = _rehearse(tmp_path, 1, [])
+    assert json.loads(lines[-1]) == {
+        "ok": True, "device": {"platform": "cpu", "kind": "cpu", "count": 1}}
+    # train has exited before serve starts; nothing else runs
+    assert _started(lines) == ["train", "serve"]
+    text = "\n".join(lines)
+    assert "'warp': 'pallas_diff'" in text
+    assert "'cached')" in text          # the repeated image hit the cache
+    assert os.path.exists(os.path.join(out, "ws", "smoke",
+                                       "checkpoint_latest"))
+
+
+def test_rehearsal_four_chips(tmp_path):
+    lines, _ = _rehearse(tmp_path, 4, ["--chips", "4"])
+    assert json.loads(lines[-1])["device"]["count"] == 4
+    # only the mesh run and its one-device comparison start
+    assert _started(lines) == ["mesh", "one_chip"]
+    text = "\n".join(lines)
+    assert "'data': 2" in text and "'plane': 2" in text
+    assert "first-step loss: mesh" in text
+
+
+# ---------------- failure cases, with stubbed children ----------------
+
+GOOD_TRAIN = """\
+Runtime: {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+Backends: warp=pallas_diff composite=pallas_diff
+global_step = 1 total_loss = 6.0
+        diag: skipped_steps = 0 guard_consecutive = 0 warp_fallback_frac = 0
+global_step = 2 total_loss = 5.5
+        diag: skipped_steps = 0 guard_consecutive = 0 warp_fallback_frac = 0
+global_step = 3 total_loss = 5.0
+        diag: skipped_steps = 0 guard_consecutive = 0 warp_fallback_frac = 0
+"""
+
+
+@pytest.fixture
+def smoke(tmp_path, monkeypatch):
+    monkeypatch.syspath_prepend(ROOT)
+    import chip_smoke
+    monkeypatch.setattr(chip_smoke, "OUT", str(tmp_path / "out"))
+    monkeypatch.setattr(chip_smoke, "lowered_holds_kernel",
+                        lambda name, fn: True)
+    return chip_smoke
+
+
+def _stub_children(monkeypatch, smoke, train_text):
+    def run_child(name, argv, env):
+        assert name == "train", "a failed train phase must end the run"
+        assert env["JAX_PLATFORMS"] == "tpu"
+        return train_text
+    monkeypatch.setattr(smoke, "run_child", run_child)
+
+
+@pytest.mark.parametrize("bad, why", [
+    (GOOD_TRAIN.replace("total_loss = 5.5", "total_loss = nan"),
+     "non-finite loss"),
+    (GOOD_TRAIN.replace("skipped_steps = 0 guard_consecutive = 0 "
+                        "warp_fallback_frac = 0\nglobal_step = 3",
+                        "skipped_steps = 1 guard_consecutive = 1 "
+                        "warp_fallback_frac = 0\nglobal_step = 3"),
+     "guard skipped"),
+    (GOOD_TRAIN.replace("total_loss = 5.0", "total_loss = 6.5"),
+     "did not decrease"),
+    (GOOD_TRAIN.replace("warp_fallback_frac = 0", "warp_fallback_frac = 1"),
+     "gather fallback"),
+    (GOOD_TRAIN.replace("warp=pallas_diff", "warp=xla"),
+     "non-Pallas"),
+    (GOOD_TRAIN.replace('"platform": "tpu"', '"platform": "cpu"'),
+     "platform"),
+])
+def test_bad_train_output_fails_the_smoke(smoke, monkeypatch, capsys,
+                                          bad, why):
+    _stub_children(monkeypatch, smoke, bad)
+    assert smoke.main([]) == 1
+    captured = capsys.readouterr()
+    assert '"ok"' not in captured.out
+    assert why in captured.err
+
+
+def test_child_exiting_nonzero_fails_the_smoke(smoke, monkeypatch, capsys):
+    """A real child, run by the script's own run_child: it exits 3."""
+    monkeypatch.setattr(
+        smoke, "train_argv",
+        lambda *a, **k: ["-c", "import sys; print('boom'); sys.exit(3)"])
+    assert smoke.main([]) == 1
+    captured = capsys.readouterr()
+    assert '"ok"' not in captured.out
+    assert "train exited 3" in captured.err
+
+
+def test_missing_repo_fails_before_anything_runs(tmp_path):
+    """In a directory that holds chip_smoke.py and nothing else of the
+    repo, the script exits non-zero and prints no result."""
+    import shutil
+    shutil.copy(os.path.join(ROOT, "chip_smoke.py"), tmp_path)
+    proc = subprocess.run([sys.executable, "chip_smoke.py"], cwd=tmp_path,
+                          capture_output=True, text=True, timeout=60)
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout

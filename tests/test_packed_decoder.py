@@ -1,8 +1,8 @@
 """Packed-head decoder variant (model.decoder_variant: "packed").
 
 The reference geometry's stride-2->1 output stage is its worst MXU stage
-(16/128 output lanes at the largest pixel counts — BENCH_NOTES_r03.md lane
-table). The packed variant computes that stage at stride 2 with 4x channels
+(16/128 output lanes at the largest pixel counts — lane table of the
+round-3 notes, git history). The packed variant computes that stage at stride 2 with 4x channels
 and a depth-to-space head (models/decoder.py). These tests pin down:
 
   * the conversion story: reference stage-0 weights map EXACTLY onto the

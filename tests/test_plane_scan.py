@@ -6,7 +6,6 @@ halo exchange) is exact, not approximate."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from mine_tpu.ops import rendering
 from mine_tpu.ops.plane_scan import plane_sharded_volume_render
@@ -77,12 +76,6 @@ def test_gradients_match_serial():
                                    rtol=tol, atol=tol)
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="ROADMAP 'Mesh-vs-single numeric divergence at 8 CPU devices': "
-           "GSPMD partitioner diverges ~2-3% on any 8-device CPU mesh "
-           "(identical value for both factorizations, plain-XLA path too — "
-           "not repo logic). Re-check on jax upgrade / real TPU.")
 def test_train_step_plane_scan_matches_xla():
     """training.composite_backend=plane_scan on a plane-parallel mesh: the
     full train step matches the single-device XLA step numerically."""

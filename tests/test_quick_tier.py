@@ -38,7 +38,7 @@ def test_quick_tier_covers_most_suites():
         "test_train_variants.py", # every test jits a full train step
         "test_plane_sharding.py", # mesh train-step compiles
         "test_multiprocess.py",   # env-gated 2-process job
-        "test_crosscheck.py",     # env-gated ~7-min TPU cross-lowering
+        "test_tpu_compile.py",    # libtpu compiles, ~80 s for the file
         "test_serve_trace_e2e.py",  # every test is slow-marked (two fleets,
                                     # 2x32 traced requests)
     }

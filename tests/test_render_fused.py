@@ -96,8 +96,19 @@ def test_fused_matches_xla_reference(kin):
 def test_in_kernel_dequant_bitwise_vs_pre_dequant(kin, quant):
     """The tentpole's dequant pin: the quantized planes through the kernel
     (scales in SMEM, dequant in registers) equal the pre-dequantized f32
-    planes through the SAME kernel exactly — the bf16 widen and the int8
-    scale multiply commute with the fused read bit-for-bit."""
+    planes through the SAME kernel exactly — the bf16 widen commutes with
+    the fused read bit-for-bit.
+
+    int8 is held to the house kernel tolerances instead. Under jax 0.9 the
+    interpreter compiles the int8-input kernel and the f32-input kernel as
+    two XLA:CPU programs whose tent matmuls accumulate differently: every
+    output is a sum of two non-zero products, and fma(a, b, round(c*d))
+    and round(a*b) + round(c*d) differ by one ulp (measured: 47% of
+    elements, max 1.8e-7). It is not the scale multiply that moves — the
+    mismatch is unchanged with exact power-of-two scales — so what the pin
+    is about, where the dequant happens, is asserted bitwise in a form
+    both programs share: the dequantized volume through the in-kernel
+    multiply with unit scales equals it through the no-dequant branch."""
     q, scales = quantize_planes(jnp.asarray(kin["vol"][0]), quant)
     q = jnp.asarray(q)[None].repeat(2, axis=0)
     if scales is not None:
@@ -107,8 +118,15 @@ def test_in_kernel_dequant_bitwise_vs_pre_dequant(kin, quant):
         dq = dq * scales
     r_q, d_q = _fused(np.asarray(q), scales, kin)
     r_dq, d_dq = _fused(np.asarray(dq), None, kin)
-    np.testing.assert_array_equal(r_q, r_dq)
-    np.testing.assert_array_equal(d_q, d_dq)
+    if quant == "int8":
+        np.testing.assert_allclose(r_q, r_dq, **RGB_TOL)
+        np.testing.assert_allclose(d_q, d_dq, **DEPTH_TOL)
+        r_1, d_1 = _fused(np.asarray(dq), jnp.ones_like(scales), kin)
+        np.testing.assert_array_equal(r_1, r_dq)
+        np.testing.assert_array_equal(d_1, d_dq)
+    else:
+        np.testing.assert_array_equal(r_q, r_dq)
+        np.testing.assert_array_equal(d_q, d_dq)
 
 
 def test_int8_roundtrip_bound_survives_fused_read(kin):

@@ -1,0 +1,161 @@
+"""The main path's Pallas kernels, handed to the chip's compiler at the
+widths params_llff.yaml ships — without a chip.
+
+libtpu compiles for a *described* v5e here on the CPU host (the
+on-chip-measurement guide, section 2): what Mosaic refuses — a band slice
+off the sublane tile, a scalar table past SMEM, too much VMEM — fails
+these tests at no chip time. Interpret mode cannot show any of that, so
+every call site's `interpret=not on_tpu_backend()` is steered to False
+from here. Nothing runs: a compile that passes is not a chip run
+(`python chip_smoke.py` is).
+
+The topology is described inside a fixture, never at import: only one
+process may load libtpu, and under xdist every worker imports this file.
+Compiles happen in this process, with the persistent compile cache off
+around them (an entry written for a described device cannot be read back
+and only warns). Keep these tests in this one file.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+# params_llff.yaml: 384x512, N=32 planes, per-chip batch 2 -> B*S = 64
+B, S = 2, 32
+FULL = (384, 512)
+SMALLEST = (48, 64)      # the 4-scale loss pyramid's last level
+BAND = 48                # training.warp_band default; all of a 48-row image
+SERVE_BAND = 32          # infer/video.py WARP_BAND
+SERVE_POSES = 8          # serve.max_bucket default
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no libtpu, or its lock is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _sq(out):
+    return sum(jnp.sum(o.astype(jnp.float32) ** 2)
+               for o in jax.tree_util.tree_leaves(out))
+
+
+def _warp_case(kernel_name, hw):
+    """fwd + VJP of a guarded training warp over all B*S planes (7 channels:
+    rgb + sigma + xyz, ops/rendering.py)."""
+    def build():
+        from mine_tpu.kernels import warp_sep, warp_vjp
+        kernel = {"pallas_diff": warp_vjp.bilinear_sample_diff_guarded,
+                  "pallas_sep": warp_sep.separable_sample_diff_guarded
+                  }[kernel_name]
+        fn = functools.partial(kernel, band=BAND, interpret=False)
+        H, W = hw
+        shapes = [((B * S, 7, H, W), jnp.float32),
+                  ((B * S, H, W), jnp.float32), ((B * S, H, W), jnp.float32)]
+        return jax.grad(lambda s, x, y: _sq(fn(s, x, y))), shapes
+    return build
+
+
+def _composite_case(hw):
+    def build():
+        from mine_tpu.kernels.composite_vjp import fused_volume_render_diff
+        H, W = hw
+        shapes = [((B, S, c, H, W), jnp.float32) for c in (3, 1, 3)]
+        return jax.grad(
+            lambda r, s, z: _sq(fused_volume_render_diff(
+                r, s, z, interpret=False)), argnums=(0, 1)), shapes
+    return build
+
+
+def _src_blend_case(hw):
+    def build():
+        from mine_tpu.kernels.composite import fused_src_render_blend
+        H, W = hw
+        shapes = [((1, S, c, H, W), jnp.float32) for c in (3, 1, 3)]
+        shapes.append(((1, 3, H, W), jnp.float32))
+        return functools.partial(fused_src_render_blend,
+                                 is_bg_depth_inf=False,
+                                 interpret=False), shapes
+    return build
+
+
+def _serve_warp_case():
+    from mine_tpu.kernels.warp import pallas_bilinear_sample
+    H, W = FULL
+    n = SERVE_POSES * S
+    return (functools.partial(pallas_bilinear_sample, band=SERVE_BAND,
+                              interpret=False),
+            [((n, 4, H, W), jnp.float32), ((n, H, W), jnp.float32),
+             ((n, H, W), jnp.float32)])
+
+
+def _serve_composite_case():
+    from mine_tpu.kernels.composite import fused_volume_render
+    H, W = FULL
+    return (functools.partial(fused_volume_render, interpret=False),
+            [((SERVE_POSES, S, c, H, W), jnp.float32) for c in (3, 1, 3)])
+
+
+def _megakernel_case():
+    """kernels/render_fused.py reading the default bf16 cache directly."""
+    from mine_tpu.kernels.render_fused import fused_plane_render
+    H, W = FULL
+    return (lambda v, xyz, cx, cy: fused_plane_render(
+                v, None, xyz, cx, cy, band=SERVE_BAND, interpret=False),
+            [((1, S, 4, H, W), jnp.bfloat16), ((1, S, 3, H, W), jnp.float32),
+             ((1, S, H, W), jnp.float32), ((1, S, H, W), jnp.float32)])
+
+
+CASES = {
+    "warp_diff_vjp-384x512": _warp_case("pallas_diff", FULL),
+    "warp_diff_vjp-48x64": _warp_case("pallas_diff", SMALLEST),
+    "composite_vjp-384x512": _composite_case(FULL),
+    "composite_vjp-48x64": _composite_case(SMALLEST),
+    "warp_sep_vjp-384x512": _warp_case("pallas_sep", FULL),
+    "warp_sep_vjp-48x64": _warp_case("pallas_sep", SMALLEST),
+    "src_render_blend-384x512": _src_blend_case(FULL),
+    "src_render_blend-48x64": _src_blend_case(SMALLEST),
+    "serve_warp_fwd-384x512": _serve_warp_case,
+    "serve_composite_fwd-384x512": _serve_composite_case,
+    "megakernel_bf16-384x512": _megakernel_case,
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(case, one_chip, no_compile_cache):
+    fn, shapes = CASES[case]()
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in shapes]
+    # conftest.py raises the matmul precision to "highest" for the CPU
+    # numerics tests; the CLIs run at JAX's default, and so does this (at
+    # "highest" the megakernel's f32 tent matmul unrolls into six bf16
+    # passes and takes 184 s to compile instead of 40)
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), (
+        f"{case}: compiled, but no Pallas kernel is in the program")

@@ -207,12 +207,6 @@ def test_overfit_synthetic_scene():
     assert np.mean(psnrs[-3:]) > np.mean(psnrs[:3]) + 0.5, (psnrs[:3], psnrs[-3:])
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="ROADMAP 'Mesh-vs-single numeric divergence at 8 CPU devices': "
-           "the GSPMD drift is nondeterministic across processes (0.4% to "
-           "4x observed on the same build) — parity holds on 2/4-device "
-           "meshes; retire with the other 8-device xfails on a fixed jax")
 def test_train_step_sharded_matches_single_device():
     """Same math on the 8-device ('data','plane') mesh: runs, and the loss
     matches the unsharded step (GSPMD = SyncBN + DDP semantics)."""
@@ -240,12 +234,6 @@ def test_train_step_sharded_matches_single_device():
     assert np.isfinite(float(m2["loss"]))
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="ROADMAP 'Mesh-vs-single numeric divergence at 8 CPU devices': "
-           "the GSPMD drift is nondeterministic across processes (0.4% to "
-           "4x observed on the same build) — parity holds on 2/4-device "
-           "meshes; retire with the other 8-device xfails on a fixed jax")
 def test_eval_step_masked_sharded_matches_single_device():
     """The masked (padded-tail) eval jit on the 8-device mesh — the exact
     program multi-host run_eval executes — must match the unsharded masked
@@ -309,12 +297,6 @@ def test_plane_chunked_decoder_composes_with_mesh():
                                float(m_plain["loss"]), rtol=0.05)
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="ROADMAP 'Mesh-vs-single numeric divergence at 8 CPU devices': "
-           "GSPMD partitioner diverges ~2-3% on any 8-device CPU mesh "
-           "(identical value for both factorizations, plain-XLA path too — "
-           "not repo logic). Re-check on jax upgrade / real TPU.")
 def test_train_step_pallas_backends_on_mesh():
     """pallas_diff composite + warp compose with the multi-device mesh via
     shard_map (VERDICT r1 item 4 — the single-device guard is gone): the

@@ -154,17 +154,21 @@ def test_pipeline_off_default_routes_fused_bitwise(fused_trainer, batch2):
 def test_staged_1x1_matches_fused(pipe_trainer, fused_trainer, batch2):
     """1 stage x 1 microbatch: the staged schedule is the fused step cut at
     its seams. Metrics and BN statistics must match the fused step to house
-    float tolerances. Gradients are held to a LOOSE structural bar (25%
-    scaled inf-norm): composing the staged functions under one
-    value_and_grad reproduces the fused gradient BITWISE (the cut is
-    exact), but the executor runs each stage as its own XLA program, and
-    cross-program float noise gets amplified by BN normalization and by
-    discrete warp-domain decisions — ~1e-5 at the feature boundary grows
-    to percent-level on a few gradient leaves. 25% still catches every
-    structural failure (a dropped stage, wrong RNG, a missing mean) while
-    the M-microbatch test below pins the schedule's bookkeeping bitwise.
-    Gradient-level via the keep_grads hook: Adam flips update signs on
-    near-zero gradients, so param deltas can't pin accumulation numerics."""
+    float tolerances. The reference is the fused function JITTED, as the
+    trainer runs it: under jax 0.9 the same function run eagerly, op by
+    op, differs from its own jitted form by up to 71% of a leaf's scale
+    (decoder.conv_up1.bn.bias; 1% on loss_ssim_src), which is what failed
+    the 25% bar this test used to hold against an eager reference — the
+    staged path was never the side that moved. Against the jitted step
+    every staged gradient leaf sits within a fifth of 2e-3 * scale + 1e-6
+    (measured), so the bar is 2% + 1e-5: composing the staged functions under
+    one value_and_grad reproduces the fused gradient BITWISE (the cut is
+    exact), the executor runs each stage as its own XLA program, and
+    XLA-CPU's threaded reductions make the cross-program noise vary run
+    to run. The M-microbatch test below pins the schedule's bookkeeping
+    bitwise. Gradient-level via the keep_grads hook: Adam flips update
+    signs on near-zero gradients, so param deltas can't pin accumulation
+    numerics."""
     ex = pipe_trainer._pipeline
     state_p = pipe_trainer.init_state(batch_size=2, seed=3)
     state_f = fused_trainer.init_state(batch_size=2, seed=3)
@@ -178,12 +182,12 @@ def test_staged_1x1_matches_fused(pipe_trainer, fused_trainer, batch2):
         ex.last_grads = None
 
     key = jax.random.fold_in(state_f.rng, state_f.step)
-    g_ref, m_ref, stats_ref = fused_trainer._grads_and_metrics(
+    g_ref, m_ref, stats_ref = jax.jit(fused_trainer._grads_and_metrics)(
         state_f, batch2, key)
 
-    _leaf_close(g_pipe["backbone"], g_ref["backbone"], rtol=0.25,
+    _leaf_close(g_pipe["backbone"], g_ref["backbone"], rtol=2e-2,
                 atol=1e-5, err_msg="backbone")
-    _leaf_close(g_pipe["decoder"], g_ref["decoder"], rtol=0.25,
+    _leaf_close(g_pipe["decoder"], g_ref["decoder"], rtol=2e-2,
                 atol=1e-5, err_msg="decoder")
     # every fused metric the staged path also computes (the staged update
     # adds the same layer/guard keys via the shared _apply_update body).

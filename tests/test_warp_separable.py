@@ -188,7 +188,7 @@ def test_trainer_accepts_separable():
 
 # ---------------------------------------------------------------------------
 # Pallas pair (kernels/warp_sep.py) — interpret mode on CPU, real kernels
-# with MINE_TPU_TESTS_ON_TPU=1 (tests/kernel_test_utils.py)
+# with JAX_PLATFORMS=tpu on a chip (tests/kernel_test_utils.py)
 # ---------------------------------------------------------------------------
 
 

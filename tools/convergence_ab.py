@@ -105,8 +105,6 @@ def main(argv=None):
     args = parser.parse_args(argv)
 
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import jax
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
 
     results, ok_all = {}, True
     for pair in args.pairs.split(","):

@@ -19,7 +19,7 @@ tools/analysis_baseline.json.)
 
 This is also the sanity denominator for throughput claims: images/sec
 readings whose implied FLOP rate exceeds the chip's peak are measurement
-artifacts (BENCH_NOTES_r02.md round-2 example: 226 img/s x 4.53
+artifacts (round-2 example, notes in git history: 226 img/s x 4.53
 TFLOP/step = 256 TFLOP/s > the v5e's ~197 TFLOP/s bf16 peak => bogus).
 
 Usage: python tools/flops_report.py [--json]

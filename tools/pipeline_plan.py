@@ -13,8 +13,8 @@ and reproducible; --measure AOT-compiles the stage programs live instead
 
 Per-stage peak-HBM is the EXACT integer sum of the member programs' cost
 rows (mine_tpu/analysis/planner.py documents the bound); step-time
-estimates are the costmodel roofline under the declared chip model
-(MINE_TPU_BENCH_PEAK_TFLOPS / MINE_TPU_BENCH_HBM_GBPS).
+estimates are the costmodel roofline at the published peaks of the chip
+the model prices for (analysis/costmodel.py CHIP_PEAKS).
 
 Usage:
   python tools/pipeline_plan.py --budget-gb 16

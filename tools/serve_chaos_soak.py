@@ -612,9 +612,8 @@ def main():
                          "incidents/ next to the event stream)")
     args = ap.parse_args()
 
-    import jax
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
+    from mine_tpu.utils import refuse_on_tpu
+    refuse_on_tpu("serve_chaos_soak.py")  # an in-process fleet AND hosts
 
     from mine_tpu.serve import ServeFleet
     from mine_tpu.serve.admission import (TIER_BEST_EFFORT, TIER_CRITICAL,

@@ -34,14 +34,8 @@ def main():
 
     import jax
 
-    # Some containers register accelerator plugins that force-override
-    # jax_platforms via jax.config; re-assert the user's JAX_PLATFORMS so the
-    # standard env-var contract holds.
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
     from mine_tpu.utils import configure_compile_cache
-    configure_compile_cache()
+    compile_cache = configure_compile_cache()
 
     if args.distributed:
         jax.distributed.initialize()
@@ -52,7 +46,7 @@ def main():
     from mine_tpu.parallel.mesh import make_mesh
     from mine_tpu.train.loop import TrainLoop
     from mine_tpu.train.step import SynthesisTrainer
-    from mine_tpu.utils import make_logger
+    from mine_tpu.utils import describe_runtime, make_logger
 
     config_path = args.config_path or os.path.join(CONFIG_DIR,
                                                    "params_llff.yaml")
@@ -80,6 +74,8 @@ def main():
         indent=0))
     logger.info("JAX devices: %s (process %d/%d)", jax.devices(),
                 jax.process_index(), jax.process_count())
+    logger.info("Runtime: %s", json.dumps(
+        dict(describe_runtime(), compile_cache=compile_cache)))
 
     tb_writer = None
     if is_lead:
@@ -115,6 +111,8 @@ def main():
     trainer = SynthesisTrainer(config, mesh=mesh,
                                steps_per_epoch=steps_per_epoch,
                                lpips_params=lpips_params)
+    logger.info("Backends: warp=%s composite=%s", trainer.cfg.warp_backend,
+                trainer.cfg.composite_backend)
 
     state = trainer.init_state(trainer.global_batch_size())
     pretrained = config.get("model.pretrained_weights_path") or \
@@ -131,7 +129,17 @@ def main():
 
     loop = TrainLoop(trainer, train_ds, val_ds, workspace,
                      logger=logger, tb_writer=tb_writer)
-    loop.run(state)
+    state = loop.run(state)
+    # where the run left things: one parameter leaf's placement, and what
+    # each local device holds now and held at its peak (None on the CPU)
+    leaf = jax.tree_util.tree_leaves(state.params)[0]
+    logger.info("Param placement: %s", json.dumps({
+        "devices": len(leaf.sharding.device_set),
+        "replicated": bool(leaf.sharding.is_fully_replicated)}))
+    logger.info("Device memory: %s", json.dumps([
+        dict(id=d.id, **{k: (d.memory_stats() or {}).get(k)
+                         for k in ("bytes_in_use", "peak_bytes_in_use")})
+        for d in jax.local_devices()]))
     if loop.preempted:
         # clean preemption exit: the emergency checkpoint is on disk and a
         # relaunch resumes exactly; exit 0 so supervisors treat this as a

@@ -33,19 +33,22 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 from typing import Callable, Dict, Iterator, NamedTuple
 
 import numpy as np
 
+from mine_tpu import telemetry
 from mine_tpu.data import common
 
 _END = object()
 
 
-def prefetch(iterator: Iterator, depth: int = 2) -> Iterator:
+def prefetch(iterator: Iterator, depth: int = 2,
+             name: str = "host") -> Iterator:
     """Background-thread prefetch: overlaps producing `iterator`'s items
-    with whatever the consumer does between `next()` calls.
+    with whatever the consumer does between `next()` calls. `name` is the
+    stage's: a consumer that finds the queue empty waits inside a
+    `data.<name>.starved` span (telemetry/spans.py).
 
     Abandoning the generator (consumer raised / broke out) stops the
     producer promptly instead of leaving a thread blocked on a full queue
@@ -77,9 +80,14 @@ def prefetch(iterator: Iterator, depth: int = 2) -> Iterator:
     t = threading.Thread(target=producer, daemon=True,
                          name="mine-tpu-prefetch")
     t.start()
+    starved = "data.%s.starved" % name
     try:
         while True:
-            item = q.get()
+            try:
+                item = q.get_nowait()
+            except queue.Empty:
+                with telemetry.span(starved):
+                    item = q.get()
             if item is _END:
                 if err:
                     raise err[0]
@@ -117,6 +125,11 @@ def threaded_pair_batches(num_items: int,
     the consumer respawns it (bounded budget, counted in
     common.PIPELINE_STATS.worker_respawns) instead of raising.
     """
+    # what an epoch's edge costs: from this generator's first step (order,
+    # pool start) until its first batch is ready
+    opening = telemetry.span("data.iterator.open", epoch=epoch,
+                             workers=workers)
+    opening.__enter__()
     order = common.shard_order(num_items, shuffle, seed, epoch, shard_index,
                                num_shards)
     nb = common.num_batches(len(order), batch_size, drop_last)
@@ -144,8 +157,9 @@ def threaded_pair_batches(num_items: int,
                     b = next_batch[0]
                     next_batch[0] += 1
             try:
-                batch = common.assemble_batch(get_pair, order, b, batch_size,
-                                              seed, epoch)
+                with telemetry.span("data.assemble.batch", batch=b):
+                    batch = common.assemble_batch(get_pair, order, b,
+                                                  batch_size, seed, epoch)
             except Exception as e:
                 with cv:
                     errors.append((b, e))
@@ -197,9 +211,14 @@ def threaded_pair_batches(num_items: int,
                             "batch %d" % b)
                     cv.wait(0.1)
                 batch = results.pop(b)
+            if opening is not None:
+                opening.__exit__(None, None, None)
+                opening = None
             yield batch
             credits.release()
     finally:
+        if opening is not None:  # closed or failed before a first batch
+            opening.__exit__(None, None, None)
         stop.set()
         with cv:
             cv.notify_all()
@@ -237,9 +256,9 @@ class DeviceStager:
         def stage():
             import jax
             for np_batch in self._host_batches:
-                t0 = time.perf_counter()
-                dev = self._put_fn(np_batch)
-                jax.block_until_ready(dev)
-                yield StagedBatch(dev, (time.perf_counter() - t0) * 1e3)
+                with telemetry.span("data.stage.h2d") as h2d:
+                    dev = self._put_fn(np_batch)
+                    jax.block_until_ready(dev)
+                yield StagedBatch(dev, h2d.ms)
 
-        return prefetch(stage(), depth=self.depth)
+        return prefetch(stage(), depth=self.depth, name="stage")

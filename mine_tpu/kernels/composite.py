@@ -229,6 +229,7 @@ def fused_volume_render(rgb_BS3HW: jnp.ndarray,
             jax.ShapeDtypeStruct((B, 3, H, W), jnp.float32),
             jax.ShapeDtypeStruct((B, 1, H, W), jnp.float32),
         ],
+        name="composite_volume_render_fwd",
         interpret=interpret,
     )(rgb_BS3HW.astype(jnp.float32), sigma_BS1HW.astype(jnp.float32),
       xyz_BS3HW.astype(jnp.float32))
@@ -326,6 +327,7 @@ def fused_src_render_blend(rgb_BS3HW: jnp.ndarray,
             jax.ShapeDtypeStruct((B, 1, H, W), jnp.float32),
             jax.ShapeDtypeStruct((B, S, 3, H, W), jnp.float32),
         ],
+        name="composite_src_render_blend_fwd",
         interpret=interpret,
     )(rgb_BS3HW.astype(jnp.float32), sigma_BS1HW.astype(jnp.float32),
       xyz_BS3HW.astype(jnp.float32), src_img_B3HW.astype(jnp.float32))

@@ -172,6 +172,7 @@ def _composite_bwd(rgb, sigma, xyz, g_rgb, g_depth,
             pltpu.VMEM((S, TH, TW), jnp.float32),
             pltpu.VMEM((S, TH, TW), jnp.float32),
         ],
+        name="composite_volume_render_bwd",
         interpret=interpret,
     )(rgb.astype(jnp.float32), sigma.astype(jnp.float32),
       xyz.astype(jnp.float32), g_rgb.astype(jnp.float32),

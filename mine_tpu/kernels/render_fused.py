@@ -274,6 +274,7 @@ def fused_plane_render(vol_q: jnp.ndarray,
             pltpu.SemaphoreType.DMA(()),
             pltpu.SemaphoreType.DMA(()),
         ],
+        name="render_fused_warp_composite",
         interpret=interpret,
     )(y0, scale_2d, xc, yc, vol_q, xyz_tgt.astype(jnp.float32))
 

@@ -160,6 +160,7 @@ def pallas_bilinear_sample(src: jnp.ndarray,
             pltpu.VMEM((C, band, W_s), jnp.float32),
             pltpu.SemaphoreType.DMA(()),
         ],
+        name="warp_bilinear_sample_fwd",
         interpret=interpret,
     )(y0, xc, yc, src.astype(jnp.float32))
 

@@ -165,6 +165,7 @@ def pallas_sep_bilinear_sample(src: jnp.ndarray,
             pltpu.VMEM((C, band, W_sp), jnp.float32),
             pltpu.SemaphoreType.DMA(()),
         ],
+        name="warp_sep_bilinear_sample_fwd",
         interpret=interpret,
     )(y0, sy, xc, src.astype(jnp.float32))
 
@@ -250,6 +251,7 @@ def _sep_bwd(g, coords_x, coords_y, src_shape,
                                lambda b, w, r: (b, 0, 0, w),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((Bp, C, H_pad, W_s), jnp.float32),
+        name="warp_sep_bilinear_sample_bwd",
         interpret=interpret,
     )(y0, sy, g.astype(jnp.float32), xc)
     return out[:, :, :H_s, :]

@@ -157,6 +157,7 @@ def _warp_bwd(g, coords_x, coords_y, src_shape,
                                lambda b, w, r: (b, 0, 0, w),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((Bp, C, H_pad, W_s), jnp.float32),
+        name="warp_bilinear_sample_bwd",
         interpret=interpret,
     )(y0, g.astype(jnp.float32), xc, yc)
     return out[:, :, :H_s, :]

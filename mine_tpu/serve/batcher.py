@@ -11,12 +11,18 @@ Thread model: callers `submit` from any thread; a single daemon flush thread
 owns the device dispatch, so the engine's jitted call never races. Tests
 drive `flush()` directly with `start=False` (no timing dependence).
 
-Observability: a request carrying a TraceContext (telemetry/tracing.py —
-attached by `ServeFleet.submit`, or started here when sampling is on) rides
-the pending tuple across the thread handoff; the flush path records its
-"queue" span (enqueue -> dispatch, tagged with which trigger released the
-batch: a full bucket or the deadline), hands the trace to the engine for
-pad/render/encode spans, and seals the trace when the future resolves.
+Observability: the flush thread's time is covered by three spans
+(telemetry/spans.py) — `serve.batcher.idle` (empty queue),
+`serve.batcher.linger` (waiting for co-riders) and `serve.batcher.flush`
+(one dispatch: the engine's spans and `serve.batcher.deliver` are its
+children) — and each request's time in the queue is one pre-measured
+`serve.batcher.queue_wait` record whose parent is the flush that released
+it. A request carrying a TraceContext (telemetry/tracing.py — attached by
+`ServeFleet.submit`, or started here when sampling is on) rides the pending
+tuple across the thread handoff; the queue-wait record forwards itself to
+it as its "queue" span (tagged with which trigger released the batch: a
+full bucket or the deadline), the engine's spans as pad/render/encode, and
+the trace is sealed when the future resolves.
 An attached `slo` tracker (telemetry/slo.py) sees EVERY request's
 end-to-end latency — SLO accounting is never sampled.
 
@@ -211,47 +217,70 @@ class MicroBatcher:
                 tracing.finish(r.trace, ok=False)
         if not batch:
             return 0
-        now = time.perf_counter()
         cause = "full" if len(batch) >= self.max_requests else "deadline"
-        wait_hist = telemetry.histogram("serve.batcher.queue_wait_ms")
-        for r in batch:
-            wait_hist.record((now - r.t_enq) * 1e3)
-            if r.trace is not None:
-                r.trace.add_span("queue", (now - r.t_enq) * 1e3, t0=r.t_enq,
-                                 flush_cause=cause, batch_size=len(batch))
-        telemetry.histogram(
-            "serve.batcher.coalesce_size",
-            edges=telemetry.pow2_buckets(1024)).record(len(batch))
-        try:
-            results = self.engine.render_many(
-                [(r.image_id, r.pose) for r in batch],
-                traces=[r.trace for r in batch],
-                images=[r.image for r in batch],
-                degraded=[r.degraded for r in batch])
-            self.flushes += 1
-            done = time.perf_counter()
-            bucket = pow2_bucket(len(batch))
-            for r, res in zip(batch, results):
-                r.fut.set_result(res)
-                if self.slo is not None:
-                    self.slo.record((done - r.t_enq) * 1e3, bucket=bucket,
-                                    tier=r.tier)
-                tracing.finish(r.trace)
-        except Exception as e:
+        bucket = pow2_bucket(len(batch))
+        with telemetry.span("serve.batcher.flush", n=len(batch),
+                            bucket=bucket, cause=cause,
+                            seq=self.flushes) as flush_span:
+            now = time.perf_counter()
             for r in batch:
-                if not r.fut.done():
-                    r.fut.set_exception(e)
-                tracing.finish(r.trace, ok=False)
-        finally:
-            with self._cv:
-                self._inflight -= len(batch)
+                # enqueue -> dispatch: started on the submitting thread,
+                # released by this flush (its parent); a traced request
+                # sees it as its "queue" span
+                telemetry.spans.record(
+                    "serve.batcher.queue_wait", int(r.t_enq * 1e9),
+                    int(now * 1e9), parent=flush_span.span_id,
+                    riders=(r.trace,), rider_name="queue",
+                    flush_cause=cause, batch_size=len(batch))
+            telemetry.histogram(
+                "serve.batcher.coalesce_size",
+                edges=telemetry.pow2_buckets(1024)).record(len(batch))
+            try:
+                results = self.engine.render_many(
+                    [(r.image_id, r.pose) for r in batch],
+                    traces=[r.trace for r in batch],
+                    images=[r.image for r in batch],
+                    degraded=[r.degraded for r in batch])
+                self.flushes += 1
+                # resolving the futures runs their callbacks here
+                with telemetry.span("serve.batcher.deliver", n=len(batch)):
+                    done = time.perf_counter()
+                    for r, res in zip(batch, results):
+                        r.fut.set_result(res)
+                        if self.slo is not None:
+                            self.slo.record((done - r.t_enq) * 1e3,
+                                            bucket=bucket, tier=r.tier)
+                        tracing.finish(r.trace)
+            except Exception as e:
+                for r in batch:
+                    if not r.fut.done():
+                        r.fut.set_exception(e)
+                    tracing.finish(r.trace, ok=False)
+            finally:
+                with self._cv:
+                    self._inflight -= len(batch)
         return len(batch)
+
+    def _wait_for_work(self) -> None:
+        """Block (callers hold self._cv) until a request is pending or the
+        batcher closes: the thread's `serve.batcher.idle` time."""
+        if self._pending or self._closed:
+            return
+        with telemetry.span("serve.batcher.idle"):
+            while not self._pending and not self._closed:
+                self._cv.wait()
+
+    def _linger(self, timeout: float) -> None:
+        """Wait (callers hold self._cv) for co-riders with requests
+        pending: the thread's `serve.batcher.linger` time."""
+        with telemetry.span("serve.batcher.linger",
+                            pending=len(self._pending)):
+            self._cv.wait(timeout=timeout)
 
     def _run(self) -> None:
         while True:
             with self._cv:
-                while not self._pending and not self._closed:
-                    self._cv.wait()
+                self._wait_for_work()
                 if self._closed and not self._pending:
                     return
                 # first request in: linger up to max_wait_s for co-riders
@@ -259,7 +288,7 @@ class MicroBatcher:
                 # flushes immediately)
                 if (self.max_wait_s > 0 and not self._closed
                         and len(self._pending) < self.max_requests):
-                    self._cv.wait(timeout=self.max_wait_s)
+                    self._linger(self.max_wait_s)
             self.flush()
 
     def close(self, timeout: float = 10.0) -> bool:
@@ -333,8 +362,7 @@ class ContinuousBatcher(MicroBatcher):
     def _run(self) -> None:
         while True:
             with self._cv:
-                while not self._pending and not self._closed:
-                    self._cv.wait()
+                self._wait_for_work()
                 if self._closed and not self._pending:
                     return
                 now = time.perf_counter()
@@ -342,7 +370,7 @@ class ContinuousBatcher(MicroBatcher):
                     # sleep only to the oldest deadline; a submit that
                     # fills the bucket notifies earlier. Loop back to
                     # re-decide instead of flushing blindly on wake.
-                    self._cv.wait(timeout=max(
+                    self._linger(max(
                         0.0, self._pending[0][3] + self.max_wait_s - now))
                     continue
             self.flush()

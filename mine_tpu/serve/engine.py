@@ -189,10 +189,13 @@ class RenderEngine:
             # next-cheaper storage mode (None = already at the floor)
             step = DEGRADE_QUANT.get(self.cache.quant)
             quant = step if step != self.cache.quant else None
-        t0 = time.perf_counter()
         attempts = max(0, self.encode_retries) + 1
-        # emit=False: the span event would duplicate the richer one below
-        with telemetry.span("serve.sync_encode", emit=False):
+        # every traced request waiting on this entry pays the encode: the
+        # span lands in each of their traces ("encode"), not just the one
+        # that missed
+        with telemetry.span("serve.sync_encode", riders=traces,
+                            rider_name="encode", image_id=image_id[:12],
+                            sync=True):
             for attempt in range(attempts):
                 try:
                     faults.on_encode(image_id)  # chaos seam (no-op unplanned)
@@ -219,13 +222,6 @@ class RenderEngine:
                        "y" if attempt == 1 else "ies")
         else:
             _warn_sync_encode(id(self), image_id)
-        encode_ms = (time.perf_counter() - t0) * 1e3
-        # every traced request waiting on this entry pays the encode: the
-        # span lands in each of their traces, not just the one that missed
-        for trace in traces:
-            if trace is not None:
-                trace.add_span("encode", encode_ms, t0=t0,
-                               image_id=image_id[:12], sync=True)
         telemetry.emit("serve.sync_encode", image_id=image_id[:12],
                        total=self.sync_encodes, retries=attempt,
                        degraded=degraded)
@@ -398,16 +394,77 @@ class RenderEngine:
     def _call(self, entries: Sequence[MPIEntry], idx: np.ndarray,
               poses: np.ndarray, warp_impl: Optional[str],
               traces: Optional[Sequence] = None):
-        """Bucket R and P, pad, dispatch ONE device call, slice."""
-        t0 = time.perf_counter()
+        """Bucket R and P, pad, dispatch ONE device call, slice. One
+        `serve.render_call` span whose children are `serve.render.pad_place`
+        (stack, pad, place; a traced rider's "pad") and `serve.render.device`
+        (dispatch -> views on the host; a rider's "render"), itself split
+        into `.dispatch`, `.device_wait` and `serve.render_fetch`."""
+        traces = traces or ()
         warp_impl = warp_impl or self.warp_impl
         P = poses.shape[0]
         Pb = max(pow2_bucket(P), self._min_pose_bucket)
+        Rb = pow2_bucket(len(entries))
+        with telemetry.span("serve.render_call", entries_bucket=Rb,
+                            poses_bucket=Pb, poses=P) as call:
+            with telemetry.span("serve.render.pad_place", riders=traces,
+                                rider_name="pad", entries_bucket=Rb,
+                                poses_bucket=Pb, padded_poses=Pb - P):
+                args = self._stack_pad_place(entries, idx, poses, Rb, Pb)
+            dtype = str(args[0].dtype)
+            bucket = (Rb, Pb, warp_impl, dtype)
+            compiled = bucket not in self._seen_buckets
+            # serve.render_call_ms is the WARM latency: a bucket's first
+            # visit is a serve.bucket_compile event instead (below)
+            call.histogram = not compiled
+            call.fields["compiled"] = compiled
+            with telemetry.span("serve.render.device", riders=traces,
+                                rider_name="render", warp_impl=warp_impl,
+                                compiled=compiled,
+                                **self._render_span_fields()):
+                faults.on_render()  # chaos seam: slow device (no-op unplanned)
+                with telemetry.span("serve.render.dispatch"):
+                    rgb, depth, source = self._dispatch(args, warp_impl)
+                self.device_calls += 1
+                # waiting and copying are two spans; np.asarray below would
+                # make this same sync
+                with telemetry.span("serve.render.device_wait"):
+                    jax.block_until_ready((rgb, depth))
+                with telemetry.host_readback("serve.render_fetch"):
+                    out = np.asarray(rgb[:P]), np.asarray(depth[:P])
+        if compiled:
+            # first dispatch of this (shape-bucket, impl, dtype) key: the
+            # executable arrived either via a live jit trace+compile or a
+            # store load (serve/aot.py), so this call's time is cold-path
+            # dominated — recorded as a cold-bucket event, NOT into the
+            # warm-latency histogram it would wreck
+            self._seen_buckets.add(bucket)
+            store_hit = source == "load"
+            if store_hit:
+                self.bucket_loads += 1
+                telemetry.counter("serve.bucket_loads").inc()
+            else:
+                self.bucket_compiles += 1
+                telemetry.counter("serve.bucket_compiles").inc()
+            telemetry.emit("serve.bucket_compile", entries_bucket=Rb,
+                           poses_bucket=Pb, warp_impl=warp_impl,
+                           dtype=dtype, compile_ms=round(call.ms, 3),
+                           store_hit=store_hit, backend=warp_impl)
+        else:
+            # per-backend label (a separate registry name, not a schema
+            # change): lets obs_report attribute warm render-time movement
+            # to the kernel backend that produced it
+            telemetry.histogram(
+                f"serve.render_call_ms[{warp_impl}]").record(call.ms)
+        return out
+
+    def _stack_pad_place(self, entries: Sequence[MPIEntry], idx: np.ndarray,
+                         poses: np.ndarray, Rb: int, Pb: int):
+        """The render program's arguments for one call: entries stacked and
+        padded to Rb, poses and gather indices padded to Pb, placed."""
+        P, R = poses.shape[0], len(entries)
         if P < Pb:
             poses = np.concatenate([poses, _identity_poses(Pb - P)], axis=0)
             idx = np.concatenate([idx, np.zeros(Pb - P, idx.dtype)])
-        R = len(entries)
-        Rb = pow2_bucket(R)
         if len({str(e.planes.dtype) for e in entries}) > 1:
             # degraded placements (serve/admission.py) can coalesce entries
             # of different storage dtypes into one batch; stacking would
@@ -432,62 +489,9 @@ class RenderEngine:
             if scales is not None:
                 scales = pad_r(scales)
         K_inv = geometry.inverse_intrinsics(K)
-        args = self._place(planes, scales, disp, K, K_inv,
+        return self._place(planes, scales, disp, K, K_inv,
                            jnp.asarray(idx, jnp.int32),
                            jnp.asarray(poses, jnp.float32))
-        t_dispatch = time.perf_counter()
-        faults.on_render()  # chaos seam: injected slow device (no-op unplanned)
-        rgb, depth, source = self._dispatch(args, warp_impl)
-        self.device_calls += 1
-        with telemetry.host_readback("serve.render_fetch"):  # device sync
-            out = np.asarray(rgb[:P]), np.asarray(depth[:P])
-        t_end = time.perf_counter()
-        elapsed_ms = (t_end - t0) * 1e3
-        bucket = (Rb, Pb, warp_impl, str(planes.dtype))
-        compiled = bucket not in self._seen_buckets
-        if compiled:
-            # first dispatch of this (shape-bucket, impl, dtype) key: the
-            # executable arrived either via a live jit trace+compile or a
-            # store load (serve/aot.py), so this call's time is cold-path
-            # dominated — recorded as a cold-bucket event, NOT into the
-            # warm-latency histogram it would wreck
-            self._seen_buckets.add(bucket)
-            store_hit = source == "load"
-            if store_hit:
-                self.bucket_loads += 1
-                telemetry.counter("serve.bucket_loads").inc()
-            else:
-                self.bucket_compiles += 1
-                telemetry.counter("serve.bucket_compiles").inc()
-            telemetry.emit("serve.bucket_compile", entries_bucket=Rb,
-                           poses_bucket=Pb, warp_impl=warp_impl,
-                           dtype=str(planes.dtype),
-                           compile_ms=round(elapsed_ms, 3),
-                           store_hit=store_hit, backend=warp_impl)
-        else:
-            telemetry.histogram("serve.render_call_ms").record(elapsed_ms)
-            # per-backend label (a separate registry name, not a schema
-            # change): lets obs_report attribute warm render-time movement
-            # to the kernel backend that produced it
-            telemetry.histogram(
-                f"serve.render_call_ms[{warp_impl}]").record(elapsed_ms)
-        if traces:
-            # two host-side spans per traced rider: the stack/pad/place
-            # work before dispatch, then the device call itself (dispatch
-            # to output sync — compile-dominated on a cold bucket, which
-            # the compiled flag marks so waterfalls aren't misread)
-            extra = self._render_span_fields()
-            pad_ms = (t_dispatch - t0) * 1e3
-            render_ms = (t_end - t_dispatch) * 1e3
-            for trace in traces:
-                if trace is None:
-                    continue
-                trace.add_span("pad", pad_ms, t0=t0, entries_bucket=Rb,
-                               poses_bucket=Pb, padded_poses=Pb - P)
-                trace.add_span("render", render_ms, t0=t_dispatch,
-                               warp_impl=warp_impl, compiled=compiled,
-                               **extra)
-        return out
 
     # ---------------- public render paths ----------------
 
@@ -538,23 +542,29 @@ class RenderEngine:
             images = [None] * len(requests)
         if degraded is None:
             degraded = [False] * len(requests)
-        order: List[str] = []
-        for image_id, _ in requests:
-            if image_id not in order:
-                order.append(image_id)
-        entries = [
-            self._entry(i,
-                        image=next((im for (rid, _), im
-                                    in zip(requests, images)
-                                    if im is not None and rid == i), None),
-                        traces=[t for (rid, _), t
-                                in zip(requests, traces)
-                                if t is not None and rid == i],
-                        degraded=all(d for (rid, _), d
-                                     in zip(requests, degraded) if rid == i))
-            for i in order]
-        idx = np.asarray([order.index(i) for i, _ in requests], np.int32)
-        poses = np.stack([np.asarray(p, np.float32) for _, p in requests])
+        # de-duplication and the cache look-ups (a miss pays its own
+        # serve.sync_encode span inside)
+        with telemetry.span("serve.render.gather", n=len(requests)):
+            order: List[str] = []
+            for image_id, _ in requests:
+                if image_id not in order:
+                    order.append(image_id)
+            entries = [
+                self._entry(i,
+                            image=next((im for (rid, _), im
+                                        in zip(requests, images)
+                                        if im is not None and rid == i),
+                                       None),
+                            traces=[t for (rid, _), t
+                                    in zip(requests, traces)
+                                    if t is not None and rid == i],
+                            degraded=all(d for (rid, _), d
+                                         in zip(requests, degraded)
+                                         if rid == i))
+                for i in order]
+            idx = np.asarray([order.index(i) for i, _ in requests], np.int32)
+            poses = np.stack([np.asarray(p, np.float32)
+                              for _, p in requests])
         rgb, depth = self._call(entries, idx, poses, warp_impl,
                                 traces=[t for t in traces if t is not None])
         return [(rgb[j], depth[j]) for j in range(len(requests))]

@@ -280,9 +280,9 @@ class StreamSession:
             return
         name = ("serve.session.keyframe_encode" if kind == "keyframe"
                 else "serve.session.interp_render")
-        telemetry.histogram(name + "_ms").record(ms)
-        telemetry.emit("span", name=name, ms=round(ms, 3), ok=True,
-                       session=sid)
+        # a done-callback interval (submit -> resolved): a pre-measured span
+        telemetry.spans.record(name, int(t0 * 1e9), int(t0 * 1e9 + ms * 1e6),
+                               emit=True, session=sid)
         drift = 0.0
         if probe is not None:
             rgb, _ = fut.result()

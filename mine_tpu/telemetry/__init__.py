@@ -12,9 +12,15 @@ coherent, parseable surface:
   events.py    append-only schema-versioned JSONL event sink — non-fatal on
                write failure, validated in CI (tools/validate_events.py),
                consumed by tools/obs_report.py
-  spans.py     scoped wall-clock timers feeding both of the above
+  spans.py     THE span primitive: span(name, **fields) -> a bounded ring
+               of records, the `<name>_ms` histogram, and a
+               jax.profiler.TraceAnnotation "mine.<name>" (README
+               "Observability" lists the spans)
+  programs.py  the jitted programs' device ops named by layer: HLO
+               instruction name -> encoder / decoder / render / ...
   tracing.py   request-level traces: per-request span trees carried across
-               threads, emitted as trace.span events (serve path anatomy)
+               threads (the same record with `trace` set), emitted as
+               trace.span events (serve path anatomy)
   slo.py       rolling-window SLO tracker: sliding p50/p99 vs a
                configurable objective, error-budget burn, breach events
   export.py    Prometheus text exposition of the registry + the opt-in
@@ -38,7 +44,7 @@ device sync — the bitwise-parity tests in tests/test_telemetry.py and
 tests/test_serve_trace_e2e.py hold the package to that.
 """
 
-from mine_tpu.telemetry import recorder, resource, tracing
+from mine_tpu.telemetry import programs, recorder, resource, spans, tracing
 from mine_tpu.telemetry.events import (KIND_FIELDS, emit, ensure_configured,
                                        validate_file, validate_line)
 from mine_tpu.telemetry.export import (OpsServer, parse_prometheus,
@@ -52,7 +58,7 @@ from mine_tpu.telemetry.registry import (REGISTRY, Counter, Gauge, Histogram,
                                          default_latency_buckets_ms, gauge,
                                          histogram, pow2_buckets)
 from mine_tpu.telemetry.slo import SLOTracker
-from mine_tpu.telemetry.spans import current_span_path, span
+from mine_tpu.telemetry.spans import SpanRecord, span
 from mine_tpu.telemetry.stepline import (STEP_KEYS, STEP_SCHEMA, TIME_KEYS,
                                          format_step_line, parse_line,
                                          parse_lines)
@@ -61,11 +67,12 @@ from mine_tpu.telemetry.tracing import TraceContext
 __all__ = [
     "FlightRecorder", "KIND_FIELDS", "OpsServer", "REGISTRY", "Counter",
     "Gauge", "Histogram", "MetricsRegistry", "ProfileWindow",
-    "ResourceSampler", "SLOTracker", "TraceContext",
-    "STEP_KEYS", "STEP_SCHEMA", "TIME_KEYS", "counter", "current_span_path",
+    "ResourceSampler", "SLOTracker", "SpanRecord", "TraceContext",
+    "STEP_KEYS", "STEP_SCHEMA", "TIME_KEYS", "counter",
     "default_latency_buckets_ms", "emit", "ensure_configured",
     "format_step_line", "gauge", "histogram", "host_readback", "parse_line",
-    "parse_lines", "parse_prometheus", "pow2_buckets", "readback_counts",
-    "recorder", "render_prometheus", "resource", "span", "tracing",
+    "parse_lines", "parse_prometheus", "pow2_buckets", "programs",
+    "readback_counts", "recorder", "render_prometheus", "resource", "span",
+    "spans", "tracing",
     "validate_file", "validate_line",
 ]

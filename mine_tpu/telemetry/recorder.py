@@ -57,18 +57,20 @@ from typing import Callable, Dict, List, Optional
 from mine_tpu.analysis.locks import ordered_condition, ordered_lock
 from mine_tpu.telemetry import events as _events
 from mine_tpu.telemetry import registry as _registry
+from mine_tpu.telemetry import spans as _spans
 from mine_tpu.telemetry import tracing as _tracing
 
 _log = logging.getLogger(__name__)
 
 BUNDLE_SCHEMA = "mtpu-inc1"
+SPANS_IN_BUNDLE = 4096  # newest records of the span ring a bundle keeps
 
 # Files every complete bundle carries; tools/postmortem.py refuses a
 # bundle missing any of them (append-only: new files may join the set).
 BUNDLE_FILES = ("manifest.json", "events.jsonl", "metrics.prom",
                 "metrics.json", "snapshots.jsonl", "traces.json",
                 "slo.json", "state.json", "config.json", "environment.json",
-                "steplines.txt")
+                "steplines.txt", "spans.jsonl")
 
 # Event kinds the tee auto-triggers on. A predicate (or None = always)
 # decides from the payload; edge-triggered sources (SLO breach, admission
@@ -399,6 +401,14 @@ class FlightRecorder:
             f.write(prom)
         with open(os.path.join(d, "steplines.txt"), "w") as f:
             f.write("\n".join(steplines) + ("\n" if steplines else ""))
+        # the span ring (telemetry/spans.py), newest last, as the `span`
+        # events export() would write: what every thread was doing
+        with open(os.path.join(d, "spans.jsonl"), "w") as f:
+            for rec in _spans.records()[-SPANS_IN_BUNDLE:]:
+                f.write(json.dumps(
+                    {"schema": _events.SCHEMA, "ts": manifest["ts"],
+                     "kind": "span", **_spans.as_event_fields(rec)},
+                    default=_events._jsonify) + "\n")
 
     def _prune(self) -> None:
         """Keep-last-K retention over completed bundle dirs (lexicographic

@@ -21,9 +21,11 @@ Design constraints, same as the rest of the package:
     rendering identical with tracing on vs off.
   * Cross-THREAD by explicit handoff, not thread-locals: a request's
     TraceContext rides inside the batcher's pending tuple from the
-    submitting thread to the flush thread (contrast spans.py, whose
-    nesting is deliberately thread-local). TraceContext is therefore
-    thread-safe.
+    submitting thread to the flush thread, and the program spans that
+    serve it (telemetry/spans.py: the queue wait, the pad, the render)
+    forward themselves to it as riders. TraceContext is therefore
+    thread-safe. Every child span is the same record the program's spans
+    write, in the same ring, with `trace` set to this trace's id.
   * Sampling is decided ONCE at `start()` (head sampling): an unsampled
     request costs one RNG draw and nothing else — no context object, no
     span records, no events.
@@ -45,6 +47,7 @@ from typing import Dict, List, Optional
 from mine_tpu.analysis.locks import ordered_lock
 from mine_tpu.telemetry import events as _events
 from mine_tpu.telemetry import registry as _registry
+from mine_tpu.telemetry import spans as _spans
 
 EVENT_KIND = "trace.span"
 DEFAULT_RECENT = 256
@@ -63,7 +66,7 @@ class TraceContext:
     spans recorded after finish are dropped (the trace is sealed)."""
 
     __slots__ = ("trace_id", "root_id", "name", "fields", "ts",
-                 "_t0", "_lock", "spans", "finished", "total_ms", "ok")
+                 "_t0_ns", "_lock", "spans", "finished", "total_ms", "ok")
 
     def __init__(self, name: str, **fields):
         self.trace_id = _new_id()
@@ -71,15 +74,15 @@ class TraceContext:
         self.name = str(name)
         self.fields = dict(fields)
         self.ts = time.time()           # wall clock, for the recent() view
-        self._t0 = time.perf_counter()  # monotonic origin for t_off_ms
+        self._t0_ns = time.perf_counter_ns()  # origin for t_off_ms
         self._lock = ordered_lock("telemetry.tracing.ctx")
         self.spans: List[Dict] = []
         self.finished = False
         self.total_ms: Optional[float] = None
         self.ok = True
 
-    def _off_ms(self, t_perf: float) -> float:
-        return (t_perf - self._t0) * 1e3
+    def _off_ms(self, t_ns: int) -> float:
+        return (t_ns - self._t0_ns) / 1e6
 
     def add_span(self, name: str, ms: float,
                  t0: Optional[float] = None,
@@ -89,51 +92,47 @@ class TraceContext:
         the trace-relative offset `t_off_ms`; defaults to now - ms).
         `parent` defaults to the root span. Returns the span record (None
         if the trace was already finished)."""
-        now = time.perf_counter()
-        if t0 is None:
-            t0 = now - ms / 1e3
+        dur_ns = int(round(float(ms) * 1e6))
+        t0_ns = (time.perf_counter_ns() - dur_ns if t0 is None
+                 else int(t0 * 1e9))
+        return self.note(name, t0_ns, t0_ns + dur_ns, parent, fields)
+
+    def note(self, name: str, t0_ns: int, t1_ns: int,
+             parent: Optional[str], fields: Dict,
+             span_id: Optional[int] = None,
+             cause: Optional[int] = None) -> Optional[Dict]:
+        """File one interval (`time.perf_counter_ns` instants) as a child
+        span of this trace: the telemetry ring's record with `trace` set
+        to this trace's id, and one `trace.span` event. `parent` is the hex
+        id of a span of this trace (default: the root); `span_id` / `cause`
+        are the ring's integer ids of the program span this is, and of the
+        one that caused it (telemetry/spans.py calls this for a span opened
+        with `trace=` or `riders=`)."""
         rec = {"trace": self.trace_id, "span": _new_id(),
                "parent": parent if parent is not None else self.root_id,
-               "name": str(name), "ms": round(float(ms), 3),
+               "name": str(name), "ms": round((t1_ns - t0_ns) / 1e6, 3),
                # clamp: a span cannot start before its trace (the default
                # now-ms back-dating of a pre-measured duration may land
                # fractionally before the root's origin)
-               "t_off_ms": round(max(0.0, self._off_ms(t0)), 3)}
+               "t_off_ms": round(max(0.0, self._off_ms(t0_ns)), 3)}
         rec.update(fields)
         with self._lock:
             if self.finished:
                 return None
             self.spans.append(rec)
+        _spans.append(str(name), t0_ns, t1_ns, cause, self.trace_id, fields,
+                      span_id=span_id)
         _events.emit(EVENT_KIND, **rec)
         return rec
 
-    class _Child:
-        __slots__ = ("ctx", "name", "parent", "fields", "_t0")
-
-        def __init__(self, ctx, name, parent, fields):
-            self.ctx, self.name = ctx, name
-            self.parent, self.fields = parent, fields
-            self._t0 = 0.0
-
-        def __enter__(self):
-            self._t0 = time.perf_counter()
-            return self
-
-        def __exit__(self, exc_type, exc, tb):
-            ms = (time.perf_counter() - self._t0) * 1e3
-            if exc_type is not None:
-                self.fields.setdefault("ok", False)
-            self.ctx.add_span(self.name, ms, t0=self._t0,
-                              parent=self.parent, **self.fields)
-            return False
-
     def child(self, name: str, parent: Optional[str] = None, **fields):
-        """Context manager measuring a block as a child span:
+        """Context manager measuring a block as a child span (a
+        `telemetry.span` that files itself under this trace):
 
             with ctx.child("route", owner_shard=o):
                 ...
         """
-        return TraceContext._Child(self, name, parent, dict(fields))
+        return _spans.span(name, trace=self, trace_parent=parent, **fields)
 
     def annotate(self, **fields) -> None:
         """Attach fields to the ROOT span (carried on its finish event)."""
@@ -182,7 +181,7 @@ class _Tracer:
                **fields) -> None:
         if ctx is None:
             return
-        now = time.perf_counter()
+        now = time.perf_counter_ns()
         with ctx._lock:
             if ctx.finished:
                 return

@@ -207,7 +207,8 @@ class CheckpointManager:
         # the span covers dispatch only — the save itself is async, so
         # this measures how long the TPU-side loop was actually held up
         # (mirror reap + previous-save settle + save dispatch)
-        with telemetry.span("ckpt.save_latest", step=int(state.step)):
+        with telemetry.span("ckpt.save_latest", emit=True,
+                            step=int(state.step)):
             # an in-flight mirror may still be reading checkpoint_latest;
             # finish (or kill) it before force-overwriting its source
             self._reap_mirror(block=True)
@@ -227,7 +228,8 @@ class CheckpointManager:
         with a commit marker is final and skipped; a marker-less dir is a
         partial save from a crashed run and is overwritten (the old
         os.path.exists guard refused to ever re-save that step)."""
-        with telemetry.span("ckpt.save_step", step=int(state.step)):
+        with telemetry.span("ckpt.save_step", emit=True,
+                            step=int(state.step)):
             self._flush_commits()
             path = self._path(STEP_FMT % int(state.step))
             if os.path.exists(path):
@@ -286,7 +288,7 @@ class CheckpointManager:
         the next instead of killing the run. Markers are advisory here
         (pre-marker workspaces restore fine). Only when every candidate
         fails does the chain raise, with the config-mismatch hint."""
-        with telemetry.span("ckpt.restore"):
+        with telemetry.span("ckpt.restore", emit=True):
             return self._restore(template, name)
 
     def _restore(self, template: TrainState,

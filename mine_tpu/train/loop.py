@@ -273,8 +273,10 @@ class TrainLoop:
                 self._ops.close()  # join before the thread-leak tripwire
                 self._ops = None
             self._resource.close()
+            # the span ring (feed, step dispatch, declared readbacks) and
             # one end-of-run registry snapshot into the event stream so
-            # obs_report sees final counter values without scraping logs
+            # obs_report sees final values without scraping logs
+            telemetry.spans.export()
             telemetry.emit(
                 "metrics.snapshot", scope="train.run_end",
                 gstep=int(state.step),

@@ -178,6 +178,7 @@ GUARDED_WARP_BACKENDS = ("pallas_diff", "xla_banded", "separable",
                          "pallas_sep")
 
 
+@jax.named_scope("render")  # layer `render` (telemetry/programs.py)
 def render_per_scale(scale: int,
                      plan_s: ScaleInputs,
                      mpi: jnp.ndarray,
@@ -465,6 +466,7 @@ def loss_per_scale(scale: int,
     return loss_dict, visuals, rendered["scale_factor"]
 
 
+@jax.named_scope("loss_pyramid")  # layer of all that no inner scope claims
 def compute_losses(mpi_list,
                    disparity: jnp.ndarray,
                    batch: Batch,
@@ -543,6 +545,7 @@ def render_all_scales(mpi_list, disparity: jnp.ndarray, batch: Batch,
     return rendered
 
 
+@jax.named_scope("loss_pyramid")
 def loss_from_rendered(rendered_list, batch: Batch, cfg: MPIConfig,
                        is_val: bool = False, lpips_params=None,
                        example_weight=None):

@@ -15,6 +15,7 @@ step counter — reproducible and resumable by construction.
 from __future__ import annotations
 
 import functools
+import weakref
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -22,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-from mine_tpu import geometry
+from mine_tpu import geometry, telemetry
 from mine_tpu.config import (MPIConfig, mpi_config_from_dict,
                              pipeline_config_from_dict,
                              validate_model_shapes)
@@ -215,6 +216,7 @@ class SynthesisTrainer:
         # constructed and the fused jitted step above runs untouched —
         # bitwise-identical outputs, same-compiled program.
         self.pipeline_cfg = pipeline_config_from_dict(config)
+        self._step_registered = False  # telemetry.programs has the step
         self._pipeline = None
         if self.pipeline_cfg.enabled:
             from mine_tpu.parallel.pipeline import PipelineExecutor
@@ -561,9 +563,40 @@ class SynthesisTrainer:
     # ---------------- public API ----------------
 
     def train_step(self, state: TrainState, batch) -> Tuple[TrainState, Dict]:
-        if self._pipeline is not None:
-            return self._pipeline.step(state, batch)
-        return self._train_step(state, batch)
+        with telemetry.span("train.step.dispatch"):
+            if self._pipeline is not None:
+                return self._pipeline.step(state, batch)
+            if not self._step_registered:
+                self._register_step_program(state, batch)
+            return self._train_step(state, batch)
+
+    def _register_step_program(self, state: TrainState, batch) -> None:
+        """Remember the first call's avals and shardings, and tell
+        telemetry/programs.py how to get the step's optimized HLO text from
+        them (instruction name -> layer, for readers of a device trace).
+        Lazy: nothing is lowered unless `programs.layers` is asked, after
+        the run; the compile it then makes is the one this call makes, so
+        the compile caches have it."""
+        self._step_registered = True
+
+        def aval(x):
+            # a sharding only where the array is committed to it: an aval
+            # that commits an uncommitted argument lowers to another
+            # module, and compiles again (chip run, PR 28: 116 s)
+            sharding = x.sharding if getattr(x, "committed", False) else None
+            return jax.ShapeDtypeStruct(jnp.shape(x), jnp.result_type(x),
+                                        sharding=sharding)
+
+        avals = jax.tree_util.tree_map(aval, (state, batch))
+        trainer = weakref.ref(self)
+
+        def text_fn() -> str:
+            me = trainer()
+            if me is None:
+                return ""
+            return me._train_step.lower(*avals).compile().as_text()
+
+        telemetry.programs.register(self._train_step_impl.__name__, text_fn)
 
     def eval_step(self, state: TrainState, batch, eval_key):
         return self._eval_step(state, batch, eval_key)

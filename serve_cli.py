@@ -389,6 +389,7 @@ def main():
     telemetry.emit("serve.stats", views=views, images=len(paths),
                    seconds=round(dt, 3), device_calls=engine.device_calls,
                    sync_encodes=engine.sync_encodes, **stats)
+    telemetry.spans.export()  # the span ring, as `span` events
     telemetry.emit("metrics.snapshot", scope="serve_cli_end",
                    metrics=telemetry.REGISTRY.snapshot("serve."))
     resource_sampler.close()

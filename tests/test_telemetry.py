@@ -26,7 +26,8 @@ from mine_tpu import telemetry
 from mine_tpu.telemetry import events as tevents
 from mine_tpu.telemetry import stepline
 from mine_tpu.telemetry.registry import Histogram, MetricsRegistry
-from mine_tpu.telemetry.spans import current_span_path, span
+from mine_tpu.telemetry import spans
+from mine_tpu.telemetry.spans import span
 
 
 @pytest.fixture
@@ -198,14 +199,18 @@ def test_env_var_funnel_and_explicit_override(tmp_path, clean_sink,
 # ---------------- spans ----------------
 
 def test_span_nesting_paths_and_histograms(tmp_path, clean_sink):
+    """Names are absolute; nesting is the record's `parent`. emit=True
+    writes the span event at once."""
     tevents.configure(str(tmp_path / "ev.jsonl"))
     reg = MetricsRegistry()
-    with span("outer", registry=reg):
-        assert current_span_path() == "outer"
-        with span("inner", registry=reg, detail="x"):
-            assert current_span_path() == "outer.inner"
-        assert current_span_path() == "outer"
-    assert current_span_path() is None
+    with span("outer", emit=True, registry=reg) as outer:
+        assert spans.current() == outer.span_id
+        with span("outer.inner", emit=True, registry=reg,
+                  detail="x") as inner:
+            assert spans.current() == inner.span_id
+            assert inner.parent == outer.span_id
+        assert spans.current() == outer.span_id
+    assert spans.current() is None
     assert reg.histogram("outer_ms").count == 1
     assert reg.histogram("outer.inner_ms").count == 1
     tevents.current_sink().close()
@@ -220,8 +225,9 @@ def test_span_unwinds_and_propagates_on_exception(clean_sink):
     with pytest.raises(RuntimeError):
         with span("boom", registry=reg):
             raise RuntimeError("inner failure")
-    assert current_span_path() is None  # stack unwound
+    assert spans.current() is None  # stack unwound
     assert reg.histogram("boom_ms").count == 1  # failure time still counts
+    assert spans.records("boom")[-1].fields["ok"] is False
 
 
 # ---------------- the frozen st1 step line ----------------

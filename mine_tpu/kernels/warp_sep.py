@@ -12,8 +12,7 @@ file is only about the TPU mapping:
     Per row the y pass is a VPU weighted reduction over the band with ONE
     scalar tent per row (the anchor lives in an SMEM [B', H_t] table —
     scalar-varying weights don't batch into a single MXU op without a
-    band transpose, and the banded kernels measured VPU-bound anyway,
-    round-4/5 profiles), and the x pass is the ONLY MXU contraction:
+    band transpose), and the x pass is the ONLY MXU contraction:
     [C, W_s] @ [W_s, W_t] per row — vs the 2D kernel's [C*BAND, W_s] @
     [W_s, W_t], the full (2*BAND/W)x-and-better MXU cut of the tentpole;
   * backward is the transposed forward, reusing the kernels/warp_vjp.py

@@ -24,7 +24,13 @@ band ("oband") had to cover the worst target-row touch span — 54+ rows
 under vertical compression, 16x the forward's per-block tent work, and a
 step-dominating VPU cost. The transposed form does exactly the forward's
 tent work, needs no oband concept, no manual DMA, and no lane padding
-(all operands are static VMEM blocks).
+(all operands are static VMEM blocks). Like the forward it multiplies only
+what a unit's taps reach (kernels/warp.py, "the band is sized for a
+BLOCK"): its unit is the block's 8 rows of one lane tile, splatted into a
+sub-band of 24 rows and the forward's column window, the 8 rows summed
+inside one accumulation so d_src is read and written once per unit. The
+whole-band form was MXU-bound on zeros (27.5 ms at 64x7x384x512, band 48,
+v5e); this one takes 10.2 ms (my chip run, PR 29).
 
 Because the backward mirrors the forward's band placement row-for-row, it
 is the EXACT adjoint of the actual (band-clamped) forward everywhere —
@@ -47,56 +53,119 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (API parity)
+from jax.experimental.pallas import tpu as pltpu
 
-from mine_tpu.kernels.warp import (SUBLANE_ALIGN, band_start, fwd_domain_ok,
-                                   mosaic_band_geometry,
-                                   pallas_bilinear_sample)
+from mine_tpu.kernels.warp import (LANE_ALIGN, SUBLANE_ALIGN, _f32,
+                                   _iota_rows, _lane_tiles, _pick_row, _tent,
+                                   band_plan, fwd_domain_ok,
+                                   pallas_bilinear_sample, subband_frac,
+                                   subband_geometry)
 
 
-def _bwd_splat_kernel(C: int, BAND: int, RT: int, TW: int,
-                      mxu_dtype, y0_ref, g_ref, xc_ref, yc_ref, out_ref):
+def _bwd_splat_kernel(C: int, BAND: int, SUB: int, RT: int, TW: int,
+                      TILE: int, KW: int, mxu_dtype, y0_ref, plan_ref, g_ref,
+                      xc_ref, yc_ref, out_ref):
     """Grid step (b, W_s-tile, target-row-block): splat the block's RT
     gradient rows into its source band; d_src accumulates in the revisited
     full-height output block (W_s-tiled when wide). The row-block dim is
     INNERMOST so each (b, w) output block's revisits are consecutive — a
     non-innermost reduction dim would flush the partial block between
-    revisits and corrupt the accumulation (review catch, round 4)."""
+    revisits and corrupt the accumulation (review catch, round 4).
+
+    The forward's windows, transposed (kernels/warp.py _warp_kernel), with
+    the block's rows of one lane tile as the unit: a unit that fits its
+    window splats into SUB band rows and KW source columns only, its RT
+    rows summed inside one accumulation and added to d_src once; a unit
+    that does not fit is masked out of that pass and splatted into the
+    whole band, as before the windowed form existed. Same tent weights,
+    zero terms left out: the pair stays adjoint unit by unit."""
     W_t = xc_ref.shape[2]
+    T = W_t // TILE
     # bf16 matmul operands compile only at lane-aligned output widths
-    # (Mosaic "Bad lhs type" on silicon); f32 fallback elsewhere — free,
-    # the kernels are VPU-bound
+    # (Mosaic "Bad lhs type" on silicon); f32 fallback elsewhere
     if TW % 128:
         mxu_dtype = jnp.float32
     nb = pl.program_id(2)
     y0 = pl.multiple_of(y0_ref[pl.program_id(0), nb], SUBLANE_ALIGN)
-    x_off = (pl.program_id(1) * TW).astype(jnp.float32)
+    x_off = pl.program_id(1) * TW
+    y0f = y0.astype(jnp.float32)
 
     @pl.when(nb == 0)
     def _zero():
         out_ref[0] = jnp.zeros_like(out_ref[0])
 
-    # source-x positions of this W_s tile along lanes; band row index
-    ws = jax.lax.broadcasted_iota(jnp.int32, (W_t, TW), 1).astype(
-        jnp.float32) + x_off
-    ys = jax.lax.broadcasted_iota(jnp.int32, (BAND, W_t), 0).astype(
-        jnp.float32)
+    iota_rows = functools.partial(_iota_rows, width=TILE)
 
-    acc = jnp.zeros((C * BAND, TW), jnp.float32)
-    for r in range(RT):
-        sx = xc_ref[0, r:r + 1, :]                      # [1, W_t]
-        sy = yc_ref[0, r:r + 1, :] - y0.astype(jnp.float32)
-        sy = jnp.clip(sy, 0.0, BAND - 1.0)  # mirror the fwd coverage clamp
-        wy = jnp.maximum(1.0 - jnp.abs(ys - sy), 0.0)   # [BAND, W_t]
-        g_r = g_ref[0, :, r, :]                         # [C, W_t]
-        A = g_r[:, None, :] * wy[None]                  # [C, BAND, W_t]
-        wxT = jnp.maximum(1.0 - jnp.abs(ws - sx.T), 0.0)  # [W_t, TW]
-        acc = acc + jnp.dot(
-            A.reshape(C * BAND, W_t).astype(mxu_dtype),
-            wxT.astype(mxu_dtype), preferred_element_type=jnp.float32)
+    def splat(g_r, wy, wx):
+        """[C, 1, TILE] gradient row x [n, TILE] row weights on the VPU,
+        then x [K, TILE] column weights on the MXU: [C * n, K]. The column
+        weights keep the forward's layout (source columns on sublanes,
+        target columns on lanes) and the matmul contracts the lane axes of
+        both operands: A @ wx^T with no transpose of either."""
+        A = (g_r * wy[None]).reshape(C * wy.shape[0], TILE)
+        return jax.lax.dot_general(
+            A.astype(mxu_dtype), wx.astype(mxu_dtype),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
-    cur = out_ref[0, :, pl.ds(y0, BAND), :]             # [C, BAND, TW]
-    out_ref[0, :, pl.ds(y0, BAND), :] = cur + acc.reshape(C, BAND, TW)
+    def band_rel(sy):
+        # the forward's coverage clamp, spelled as it is there
+        return jnp.minimum(jnp.maximum(sy - y0f, 0.0), BAND - 1.0)
+
+    xs, ys = iota_rows(KW), iota_rows(SUB)
+
+    def windowed(j, lanes):
+        # both starts are tile-aligned by subband_plan; multiple_of carries
+        # that to Mosaic for the dynamic VMEM slices
+        s0 = pl.multiple_of(plan_ref[0, 0, j], SUBLANE_ALIGN) \
+            if SUB < BAND else 0
+        # this W_s tile's part of the unit's column window
+        k0 = pl.multiple_of(
+            jnp.clip(plan_ref[0, 0, T + j] - x_off, 0, TW - KW),
+            LANE_ALIGN) if KW < TW else 0
+        fits = plan_ref[0, 0, 2 * T + j].astype(jnp.float32)
+        acc = jnp.zeros((C * SUB, KW), jnp.float32)
+        for r in range(RT):
+            sx = xc_ref[0, r:r + 1, lanes]                  # [1, TILE]
+            sy = band_rel(yc_ref[0, r:r + 1, lanes])
+            g_r = g_ref[0, :, r:r + 1, lanes] * fits        # [C, 1, TILE]
+            acc = acc + splat(g_r, _tent(ys - (sy - _f32(s0))),
+                              _tent(xs - (sx - _f32(k0 + x_off))))
+        rows = pl.ds(pl.multiple_of(y0 + s0, SUBLANE_ALIGN), SUB)
+        out_ref[0, :, rows, pl.ds(k0, KW)] += acc.reshape(C, SUB, KW)
+
+    _lane_tiles(T, TILE, windowed, unroll=True)
+
+    @pl.when(plan_ref[0, 0, 3 * T] != 0)   # a unit of this block overflows
+    def _whole_band():
+        # the rare path: loops over rows and tiles keep it small; the row
+        # is picked by a masked sum (_pick_row)
+        xs_all = iota_rows(TW) + _f32(x_off)
+        ys_all = iota_rows(BAND)
+
+        def row_of_unit(r, j, lanes):
+            @pl.when(plan_ref[0, 0, 2 * T + j] == 0)
+            def _overflows():
+                sx = _pick_row(xc_ref[0, :, lanes], r)
+                sy = band_rel(_pick_row(yc_ref[0, :, lanes], r))
+                d = splat(_pick_row(g_ref[0, :, :, lanes], r),
+                          _tent(ys_all - sy), _tent(xs_all - sx))
+                out_ref[0, :, pl.ds(y0, BAND), :] += d.reshape(C, BAND, TW)
+
+        def row(r, carry):
+            _lane_tiles(T, TILE, functools.partial(row_of_unit, r))
+            return carry
+
+        jax.lax.fori_loop(0, RT, row, 0)
+
+
+# The resident d_src block of the backward: a whole-width block of the
+# widest shipped shapes (7 x 384 x 512 and 7 x 256 x 768 float32: 5.5 MB,
+# twice for the pipeline's two buffers) lets a unit's column window lie
+# anywhere in the row, where two W_s tiles of 256 each splat every unit
+# twice (LLFF full resolution, v5e, PR 29: 10.2 ms against 13.7). That is
+# past Mosaic's default 16 MiB of scoped VMEM, of the chip's 128.
+_DSRC_BLOCK_BUDGET = 8 * 1024 * 1024
+_VMEM_LIMIT_BYTES = 48 * 1024 * 1024
 
 
 def _pick_out_tile_w(C: int, H_pad: int, W_s: int,
@@ -120,28 +189,30 @@ def _warp_bwd(g, coords_x, coords_y, src_shape,
     Bp, C, H_s, W_s = src_shape
     _, H_t, W_t = coords_x.shape
     RT = rows_per_block
-    assert H_t % RT == 0, (H_t, RT)
     NB = H_t // RT
 
-    xc = jnp.clip(coords_x, 0.0, W_s - 1.0).astype(jnp.float32)
-    yc = jnp.clip(coords_y, 0.0, H_s - 1.0).astype(jnp.float32)
-
-    # EXACTLY the forward's band geometry (kernels/warp.py): ceil band,
-    # pad H so the clipped start stays covered, floor-align the starts.
-    band = min(band, H_s)
-    band, pad_h, _ = mosaic_band_geometry(band, H_s, W_s)
+    # EXACTLY the forward's band geometry and windows (kernels/warp.py
+    # band_plan): ceil band, pad H so the clipped start stays covered,
+    # floor-align the starts, the plan over the lane-padded width.
+    xc, yc, band, pad_h, pad_w, y0, plan, _ = band_plan(
+        src_shape, coords_x, coords_y, band, RT, unit_rows=RT)
     H_pad = H_s + pad_h
-    y0 = band_start(yc, H_pad, band, RT)
-    y0 = (y0 // SUBLANE_ALIGN) * SUBLANE_ALIGN
+    tile, sub, kw = subband_geometry(band, W_t, W_s + pad_w, unit_rows=RT)
+    NP = plan.shape[-1]
 
-    TW = _pick_out_tile_w(C, H_pad, W_s)
-    kernel = functools.partial(_bwd_splat_kernel, C, band, RT, TW,
-                               mxu_dtype)
+    TW = _pick_out_tile_w(C, H_pad, W_s, _DSRC_BLOCK_BUDGET)
+    # a W_s tile no wider than the window (or an unpadded, unaligned width)
+    # is splatted whole
+    kw = min(kw, TW) if TW % LANE_ALIGN == 0 else TW
+    kernel = functools.partial(_bwd_splat_kernel, C, band, sub, RT, TW,
+                               tile, kw, mxu_dtype)
     out = pl.pallas_call(
         kernel,
         grid=(Bp, W_s // TW, NB),  # row-blocks INNERMOST (see kernel doc)
         in_specs=[
             pl.BlockSpec((Bp, NB), lambda b, w, r: (0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, NP), lambda b, w, r: (b * NB + r, 0, 0),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((1, C, RT, W_t), lambda b, w, r: (b, 0, r, 0),
                          memory_space=pltpu.VMEM),
@@ -158,8 +229,10 @@ def _warp_bwd(g, coords_x, coords_y, src_shape,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((Bp, C, H_pad, W_s), jnp.float32),
         name="warp_bilinear_sample_bwd",
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(y0, g.astype(jnp.float32), xc, yc)
+    )(y0, plan, g.astype(jnp.float32), xc, yc)
     return out[:, :, :H_s, :]
 
 
@@ -222,6 +295,18 @@ def guard_ok(src_shape, coords_y, band: int = 48,
     return diff_domain_ok(src_shape, coords_y, band, rows_per_block)
 
 
+def guarded_subband_frac(src_shape, coords_x, coords_y, band: int = 48,
+                         rows_per_block: int = 8) -> jnp.ndarray:
+    """kernels/warp.subband_frac of a bilinear_sample_diff_guarded call:
+    scalar f32, 0.0 where the call takes the gather fallback (no unit runs
+    a kernel there)."""
+    if coords_y.shape[1] % rows_per_block or src_shape[2] % rows_per_block:
+        return jnp.zeros((), jnp.float32)
+    return jnp.where(guard_ok(src_shape, coords_y, band, rows_per_block),
+                     subband_frac(src_shape, coords_x, coords_y, band,
+                                  rows_per_block), 0.0)
+
+
 def bilinear_sample_diff_guarded(src, coords_x, coords_y,
                                  band: int = 48,
                                  rows_per_block: int = 8,
@@ -230,27 +315,34 @@ def bilinear_sample_diff_guarded(src, coords_x, coords_y,
     """Banded differentiable warp with a runtime XLA-gather fallback.
 
     `lax.cond` on the (data-dependent, pose-derived) band-domain check: the
-    Pallas fast path for translation-dominated warps, the autodiffed gather
-    for rotation-heavy ones. Both branches are differentiable, so this
+    Pallas fast path for translation-dominated warps, the gather (forward
+    and its transpose, ops/warp._bilinear_sample_cast) for rotation-heavy
+    ones. Both branches are differentiable, so this
     composes with jax.grad in the training step. Always returns float32
     (the kernel's accumulation dtype) so the two cond branches agree."""
-    from mine_tpu.ops.warp import bilinear_sample
+    from mine_tpu.ops.warp import _bilinear_sample_cast, bilinear_sample
 
     # the gather fallback honors the same reduced-precision knob as the
-    # kernel (mxu_dtype) via the f32-accumulating bf16 gather path, so
-    # fallback steps keep the HBM-traffic benefit (parity with
-    # ops/warp_banded.py's guard); f32 is a no-op knob
-    gather_dtype = mxu_dtype
+    # kernel (mxu_dtype) via the f32-accumulating gather path, so fallback
+    # steps keep the HBM-traffic benefit (parity with ops/warp_banded.py's
+    # guard). Under the cond it is ALWAYS the custom-VJP form, float32
+    # included: its residuals are the coordinates, as the kernel branch's
+    # are. The autodiffed gather would carry its four [B',H,W,2] int32 index
+    # arrays out of the cond as residuals, and XLA is free to lay those out
+    # with the 2 on the lane axis (64x padding: 6 GB each at 64x384x512,
+    # hit when the plan table joined the kernel branch). Coordinates get
+    # zero cotangents on both branches.
+    gather_dtype = jnp.dtype(mxu_dtype).name
     src = src.astype(jnp.float32)
     H_t = coords_x.shape[1]
     if H_t % rows_per_block != 0 or src.shape[2] % rows_per_block != 0:
         return bilinear_sample(src, coords_x, coords_y,
-                               gather_dtype=gather_dtype)
+                               gather_dtype=mxu_dtype)
 
     ok = guard_ok(src.shape, coords_y, band, rows_per_block)
     return jax.lax.cond(
         ok,
         lambda s, x, y: bilinear_sample_diff(
             s, x, y, band, rows_per_block, interpret, mxu_dtype),
-        lambda s, x, y: bilinear_sample(s, x, y, gather_dtype=gather_dtype),
+        lambda s, x, y: _bilinear_sample_cast(s, x, y, gather_dtype),
         src, coords_x, coords_y)

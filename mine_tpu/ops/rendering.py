@@ -219,6 +219,10 @@ class TgtRender(NamedTuple):
     # path this call, 0.0 = runtime gather fallback, NaN = backend has no
     # guard (ops/warp.homography_warp with_domain_flag)
     warp_in_domain: jnp.ndarray = None
+    # scalar f32: share of the banded Pallas warp's (row, lane tile) units
+    # contracted against their window alone; NaN on the other backends
+    # (ops/warp.homography_warp with_subband_frac)
+    warp_subband: jnp.ndarray = None
 
 
 def render_tgt_rgb_depth(mpi_rgb_src: jnp.ndarray,
@@ -297,7 +301,7 @@ def render_tgt_rgb_depth(mpi_rgb_src: jnp.ndarray,
         return jnp.repeat(x, S, axis=0)  # [B,...] -> [B*S,...] (plane-major per b)
 
     grid = geometry.cached_pixel_grid(H, W)
-    warped, valid, warp_in_domain = warp.homography_warp(
+    warped, valid, warp_in_domain, warp_subband = warp.homography_warp(
         volume_bs,
         mpi_depth_src.reshape(B * S),
         expand(G_tgt_src),
@@ -310,6 +314,7 @@ def render_tgt_rgb_depth(mpi_rgb_src: jnp.ndarray,
         mxu_dtype=jnp.bfloat16 if warp_dtype == "bfloat16" else jnp.float32,
         with_domain_flag=True,
         sep_tol=warp_sep_tol,
+        with_subband_frac=True,
     )
 
     warped = warped.reshape(B, S, 7, H, W)
@@ -389,7 +394,8 @@ def render_tgt_rgb_depth(mpi_rgb_src: jnp.ndarray,
     mask = jnp.sum(valid.reshape(B, S, H, W).astype(jnp.float32),
                    axis=1, keepdims=True)  # [B,1,H,W]
     return TgtRender(rgb=rgb_syn, depth=depth_syn, mask=mask,
-                     warp_in_domain=warp_in_domain)
+                     warp_in_domain=warp_in_domain,
+                     warp_subband=warp_subband)
 
 
 def predict_mpi_coarse_to_fine(mpi_predictor,

@@ -165,7 +165,8 @@ def homography_warp(src_BCHW: jnp.ndarray,
                     mesh=None,
                     mxu_dtype=jnp.float32,
                     with_domain_flag: bool = False,
-                    sep_tol: float = 0.5):
+                    sep_tol: float = 0.5,
+                    with_subband_frac: bool = False):
     """Warp source-plane images into the target camera via inverse homography.
 
     For each batch element: compose H_tgt_src = K_tgt (R - t n^T / -d) K_src^-1,
@@ -214,9 +215,18 @@ def homography_warp(src_BCHW: jnp.ndarray,
       sep_tol: separable backends only (training.warp_sep_tol) — max
         admitted per-row anchor deviation in source rows; poses above it
         take the gather fallback (ops/warp_separable.py error bound).
+      with_subband_frac: also return `subband_frac`, a scalar f32 — the
+        share of this call's (output row, lane tile) units that the banded
+        Pallas training kernels (pallas_diff / pallas_fused) contracted
+        against their window alone (kernels/warp.subband_frac), 0.0 for
+        the part of the call on the gather fallback, NaN for the other
+        backends (the forward-only `pallas` among them: serving reads no
+        metric, and every render program would pay the plan's trace a
+        second time). Sharded like `in_domain`. Powers `warp_subband_frac`.
     Returns:
       tgt [B', C, Ht, Wt], valid_mask [B', Ht, Wt] (bool)
       [, in_domain scalar f32 — only when with_domain_flag]
+      [, subband_frac scalar f32 — only when with_subband_frac]
     """
     Bp, C, H, W = src_BCHW.shape
     _, Ht, Wt = meshgrid_tgt.shape
@@ -227,6 +237,11 @@ def homography_warp(src_BCHW: jnp.ndarray,
     # diagnostic only — mirrors each guarded backend's fallback decision
     # (NaN = backend has no runtime guard to measure)
     in_domain = jnp.full((), jnp.nan, jnp.float32)
+    subband = jnp.full((), jnp.nan, jnp.float32)
+
+    def result(tgt):
+        return (tgt, valid) + ((in_domain,) if with_domain_flag else ()) \
+            + ((subband,) if with_subband_frac else ())
 
     if impl == "pallas":
         from mine_tpu.kernels import on_tpu_backend
@@ -260,6 +275,14 @@ def homography_warp(src_BCHW: jnp.ndarray,
         # non-learnable (no-grad inverse above), so stop_gradient keeps the
         # two branches' autodiff structurally identical.
         from mine_tpu.kernels import on_tpu_backend
+
+        counts_windows = with_subband_frac and impl != "pallas_sep"
+
+        def _subband(src_shape, cx, cy):
+            if not counts_windows:
+                return subband  # NaN: no windows here, or nobody asked
+            from mine_tpu.kernels.warp_vjp import guarded_subband_frac
+            return guarded_subband_frac(src_shape, cx, cy, band)
         if impl in ("pallas_diff", "pallas_fused"):
             # "pallas_fused" fuses warp+dequant+composite inside
             # render_tgt_rgb_depth (kernels/render_fused.py); under the
@@ -303,19 +326,20 @@ def homography_warp(src_BCHW: jnp.ndarray,
                     # fast path (the old global-coords flag collapsed any
                     # single out-of-band shard to fallback=1.0 for the whole
                     # step, VERDICT r5: per-shard accounting)
+                    # (the windows' share likewise: each shard's own)
+                    def over_shards(v):
+                        return jax.lax.pmean(jax.lax.pmean(v, DATA_AXIS),
+                                             PLANE_AXIS)
                     ok = _diff_guard_ok(s.shape, cy).astype(jnp.float32)
-                    ok = jax.lax.pmean(jax.lax.pmean(ok, DATA_AXIS),
-                                       PLANE_AXIS)
-                    return kernel_fn(s, cx, cy), ok
+                    return (kernel_fn(s, cx, cy), over_shards(ok),
+                            over_shards(_subband(s.shape, cx, cy)))
 
                 sharded = shard_map(
                     functools.partial(sharded, fn), mesh=mesh,
                     in_specs=(P(bs_axes), P(bs_axes), P(bs_axes)),
-                    out_specs=(P(bs_axes), P()))
-                tgt, in_domain = sharded(src_BCHW, xs, ys)
-                if with_domain_flag:
-                    return tgt, valid, in_domain
-                return tgt, valid
+                    out_specs=(P(bs_axes), P(), P()))
+                tgt, in_domain, subband = sharded(src_BCHW, xs, ys)
+                return result(tgt)
             # a bare pallas_call inside a GSPMD-partitioned program has
             # no partitioning spec — fall back to the autodiffed gather
             # for non-divisible batches (e.g. remainder eval examples);
@@ -323,14 +347,15 @@ def homography_warp(src_BCHW: jnp.ndarray,
             fn = functools.partial(bilinear_sample,
                                    gather_dtype=mxu_dtype)
             in_domain = jnp.zeros((), jnp.float32)
+            if counts_windows:
+                subband = jnp.zeros((), jnp.float32)
         else:
             in_domain = _diff_guard_ok(src_BCHW.shape,
                                        ys).astype(jnp.float32)
+            subband = _subband(src_BCHW.shape, xs, ys)
         tgt = fn(src_BCHW, xs, ys)
     else:
         # training.warp_dtype reaches the gather too: bf16 storage halves
         # the volume's HBM traffic, lerp stays f32 (f32 is a no-op knob)
         tgt = bilinear_sample(src_BCHW, x, y, gather_dtype=mxu_dtype)
-    if with_domain_flag:
-        return tgt, valid, in_domain
-    return tgt, valid
+    return result(tgt)

@@ -733,8 +733,8 @@ class TrainLoop:
                m["loss_rgb_tgt"], m["loss_ssim_tgt"], m["loss_disp_pt3dtgt"],
                m["psnr_tgt"], step_line))
         diag = " ".join("%s = %.6g" % (k, m[k]) for k in (
-            "skipped_steps", "guard_consecutive", "warp_fallback_frac")
-            if k in m)
+            "skipped_steps", "guard_consecutive", "warp_fallback_frac",
+            "warp_subband_frac") if k in m)
         if diag:
             self._log("        diag: " + diag)
         if self.telem.enabled:
@@ -747,7 +747,8 @@ class TrainLoop:
             for src_key, gauge_name in (
                     ("skipped_steps", "train.guard.skipped_steps"),
                     ("guard_consecutive", "train.guard.consecutive"),
-                    ("warp_fallback_frac", "train.warp_fallback_frac")):
+                    ("warp_fallback_frac", "train.warp_fallback_frac"),
+                    ("warp_subband_frac", "train.warp_subband_frac")):
                 if src_key in m:
                     telemetry.gauge(gauge_name).set(m[src_key])
             telemetry.emit(

@@ -273,6 +273,8 @@ def render_per_scale(scale: int,
         rendered["src_pt_disp_syn"] = src_pt_disp_syn
     if cfg.warp_backend in GUARDED_WARP_BACKENDS:
         rendered["warp_in_domain"] = res.warp_in_domain
+    if cfg.warp_backend == "pallas_diff":
+        rendered["warp_subband"] = res.warp_subband
     return rendered
 
 
@@ -417,6 +419,11 @@ def loss_terms_per_scale(scale: int,
         # backend bailed to the gather (key absent on unguarded backends)
         loss_dict["warp_fallback"] = jax.lax.stop_gradient(
             1.0 - rendered["warp_in_domain"])
+    if "warp_subband" in rendered:
+        # kernel diagnostic, not a loss: the share of this scale's warp
+        # units on the windowed contraction (key absent off pallas_diff)
+        loss_dict["warp_subband"] = jax.lax.stop_gradient(
+            rendered["warp_subband"])
     visuals = {
         "src_disparity_syn": src_disp_syn,
         "tgt_disparity_syn": tgt_disp_syn,
@@ -523,6 +530,12 @@ def aggregate_scale_losses(dicts, cfg: MPIConfig):
         del metrics["warp_fallback"]
         metrics["warp_fallback_frac"] = jnp.mean(
             jnp.stack([d["warp_fallback"] for d in dicts]))
+    if "warp_subband" in metrics:
+        # likewise: mean over the 4 scale-warps of the share of their
+        # (row, lane tile) units contracted against their window alone
+        del metrics["warp_subband"]
+        metrics["warp_subband_frac"] = jnp.mean(
+            jnp.stack([d["warp_subband"] for d in dicts]))
     return total, metrics
 
 

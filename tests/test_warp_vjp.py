@@ -6,6 +6,7 @@ compile for TPU (VERDICT round 1 item 3)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from mine_tpu.kernels.warp_vjp import (bilinear_sample_diff,
                                        bilinear_sample_diff_guarded,
@@ -196,5 +197,105 @@ def test_bwd_splat_w_tiled_accumulation(monkeypatch):
     g_ref = jax.grad(lambda s: jnp.sum(warp.bilinear_sample(s, x, y) * cot))(src)
     g_ker = jax.grad(lambda s: jnp.sum(wv.bilinear_sample_diff(
         s, x, y, 24, 8, kernel_test_utils.interpret()) * cot))(src)
+    np.testing.assert_allclose(np.asarray(g_ker), np.asarray(g_ref),
+                               rtol=1e-4, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# windowed contraction (kernels/warp.py subband_plan): the backward splats a
+# block's rows of one lane tile into the band rows and source columns their
+# taps reach, and the whole band where they do not fit
+# ---------------------------------------------------------------------------
+
+def _pair(band):
+    return lambda s, x, y: bilinear_sample_diff(
+        s, x, y, band, 8, kernel_test_utils.interpret())
+
+
+@pytest.mark.parametrize("hw", [(48, 64), (64, 256), (64, 384)])
+def test_windowed_splat_equals_whole_band_bitwise(hw, monkeypatch):
+    """In-domain field, every unit windowed: d_src has the same bits as the
+    whole-band splat of the same units. Integer cotangents and coordinates
+    on a 1/64 grid keep every product and partial sum exact in float32, so
+    the comparison does not hang on the order of the additions."""
+    from tests.test_warp_kernel import all_units_overflow, sheared_field
+    H, W = hw
+    rng = np.random.RandomState(21)
+    src = jnp.zeros((2, 3, H, W), jnp.float32)
+    cot = jnp.asarray(rng.randint(-8, 9, size=(2, 3, H, W)).astype(np.float32))
+    x, y = sheared_field(H, W, [1 / 64] * H, shift=(1.75, -2.25))
+    x, y = jnp.tile(x, (2, 1, 1)), jnp.tile(y, (2, 1, 1))
+
+    def d_src():
+        return np.asarray(jax.grad(
+            lambda s: jnp.sum(_pair(48)(s, x, y) * cot))(src))
+
+    windowed = d_src()
+    all_units_overflow(monkeypatch)
+    whole = d_src()
+    jax.clear_caches()
+    np.testing.assert_array_equal(windowed, whole)
+    ref = jax.grad(lambda s: jnp.sum(warp.bilinear_sample(s, x, y) * cot))(src)
+    np.testing.assert_allclose(windowed, np.asarray(ref), rtol=1e-4,
+                               atol=1e-4)
+
+
+def _mixed_case(seed, C=3):
+    from tests.test_warp_kernel import mixed_field
+    H, W = 64, 256
+    rng = np.random.RandomState(seed)
+    src = jnp.asarray(rng.normal(size=(1, C, H, W)).astype(np.float32))
+    cot = jnp.asarray(rng.normal(size=(1, C, H, W)).astype(np.float32))
+    x, y = mixed_field(H, W)
+    return src, cot, x, y
+
+
+def test_mixed_field_forward_and_grad_match_gather():
+    """Units that overflow their windows beside units that do not (forward:
+    the rows of the upper two blocks; backward: those blocks' lane tiles):
+    value and gradient equal the gather's."""
+    from mine_tpu.kernels.warp import band_plan
+    src, cot, x, y = _mixed_case(22)
+    for unit_rows, share in ((1, 0.75), (8, 0.75)):
+        fits = band_plan(src.shape, x, y, 64, 8, unit_rows)[-1]
+        assert float(jnp.mean(fits.astype(jnp.float32))) == share
+    assert bool(diff_domain_ok(src.shape, y, 64, 8))
+    out = _pair(64)(src, x, y)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(warp.bilinear_sample(src, x, y)),
+                               rtol=1e-4, atol=1e-4)
+    g_ker = jax.grad(lambda s: jnp.sum(_pair(64)(s, x, y) * cot))(src)
+    g_ref = jax.grad(lambda s: jnp.sum(warp.bilinear_sample(s, x, y) * cot))(src)
+    np.testing.assert_allclose(np.asarray(g_ker), np.asarray(g_ref),
+                               rtol=1e-4, atol=1e-4)
+
+
+def test_mixed_field_backward_is_the_adjoint():
+    """<warp(s), g> = <s, warp^T(g)> with both paths in one call."""
+    src, cot, x, y = _mixed_case(23)
+    out, vjp = jax.vjp(lambda s: _pair(64)(s, x, y), src)
+    d_src, = vjp(cot)
+    lhs = float(jnp.sum(out * cot))
+    rhs = float(jnp.sum(src * d_src))
+    np.testing.assert_allclose(lhs, rhs, rtol=1e-5)
+
+
+@pytest.mark.parametrize("edge,shift", [("left", (-9.5, 0.0)),
+                                        ("right", (9.5, 0.0)),
+                                        ("top", (0.0, -5.5)),
+                                        ("bottom", (0.0, 5.5))])
+def test_grad_border_clamping_through_windows(edge, shift):
+    """Border-clamped taps at each edge of the image, every unit windowed:
+    the gradient piles up on the edge texels as the gather's does."""
+    from mine_tpu.kernels.warp import band_plan
+    from tests.test_warp_kernel import sheared_field
+    H, W = 64, 256
+    rng = np.random.RandomState(24)
+    src = jnp.asarray(rng.normal(size=(1, 2, H, W)).astype(np.float32))
+    cot = jnp.asarray(rng.normal(size=(1, 2, H, W)).astype(np.float32))
+    x, y = sheared_field(H, W, [0.015] * H, shift=shift)
+    assert bool(jnp.all(band_plan(src.shape, x, y, 48, 8, 8)[-1]))
+    g_ker = jax.grad(lambda s: jnp.sum(_pair(48)(s, x, y) * cot))(src)
+    g_ref = jax.grad(lambda s: jnp.sum(warp.bilinear_sample(s, x, y) * cot))(src)
     np.testing.assert_allclose(np.asarray(g_ker), np.asarray(g_ref),
                                rtol=1e-4, atol=1e-4)

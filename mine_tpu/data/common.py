@@ -197,8 +197,10 @@ def assemble_batch(get_pair: Callable[[int, np.random.RandomState],
                    batch_index: int,
                    batch_size: int,
                    seed: int,
-                   epoch: int) -> Dict[str, np.ndarray]:
-    """Assemble + collate batch `batch_index` of the shard order.
+                   epoch: int,
+                   collate=None) -> Dict[str, np.ndarray]:
+    """Assemble + collate batch `batch_index` of the shard order (`collate`:
+    the loader's own, for items that are no (src, tgt) pairs).
 
     Pure in (order, batch_index, seed, epoch): any worker can build any
     batch, in any order, and get the same bytes. Item loads go through
@@ -209,7 +211,7 @@ def assemble_batch(get_pair: Callable[[int, np.random.RandomState],
     idxs = order[lo:lo + batch_size]
     pairs = [load_item(get_pair, order, lo + j, seed, epoch)
              for j in range(len(idxs))]
-    return collate_pairs(pairs)
+    return (collate or collate_pairs)(pairs)
 
 
 def iterate_pair_batches(num_items: int,
@@ -223,7 +225,8 @@ def iterate_pair_batches(num_items: int,
                          shard_index: int = 0,
                          num_shards: int = 1,
                          workers: int = 0,
-                         prefetch_batches: int = 2
+                         prefetch_batches: int = 2,
+                         collate=None
                          ) -> Iterator[Dict[str, np.ndarray]]:
     """Yield collated framework batches of (src, tgt) item pairs.
 
@@ -239,12 +242,13 @@ def iterate_pair_batches(num_items: int,
             num_items, get_pair, batch_size, shuffle, seed=seed, epoch=epoch,
             drop_last=drop_last, shard_index=shard_index,
             num_shards=num_shards, workers=workers,
-            prefetch_batches=prefetch_batches)
+            prefetch_batches=prefetch_batches, collate=collate)
         return
     order = shard_order(num_items, shuffle, seed, epoch, shard_index,
                         num_shards)
     for b in range(num_batches(len(order), batch_size, drop_last)):
-        yield assemble_batch(get_pair, order, b, batch_size, seed, epoch)
+        yield assemble_batch(get_pair, order, b, batch_size, seed, epoch,
+                             collate=collate)
 
 
 def collate_pairs(pairs) -> Dict[str, np.ndarray]:

@@ -240,6 +240,13 @@ def get_dataset(config: Dict, logger=None) -> Tuple[LLFFDataset, LLFFDataset]:
     (train.py:69-103). Only the LLFF/COLMAP loader exists upstream; other
     dataset names raise NotImplementedError there too (train.py:100-101)."""
     name = config["data.name"]
+    if name == "packed_tokens":
+        # the looped language model's feed (model.family: looplm); no
+        # validation set
+        from mine_tpu.data.tokens import dataset_from_config
+        return dataset_from_config(config,
+                                   seed=int(config.get("training.seed", 0))
+                                   ), None
     if name == "synthetic":
         # procedural scene, no files needed: smoke-tests the full
         # train/eval/CLI stack (mine_tpu.data.synthetic)

@@ -107,7 +107,8 @@ def threaded_pair_batches(num_items: int,
                           shard_index: int = 0,
                           num_shards: int = 1,
                           workers: int = 2,
-                          prefetch_batches: int = 2
+                          prefetch_batches: int = 2,
+                          collate=None
                           ) -> Iterator[Dict[str, np.ndarray]]:
     """Multi-worker batch assembly, yielded strictly in batch order.
 
@@ -159,7 +160,8 @@ def threaded_pair_batches(num_items: int,
             try:
                 with telemetry.span("data.assemble.batch", batch=b):
                     batch = common.assemble_batch(get_pair, order, b,
-                                                  batch_size, seed, epoch)
+                                                  batch_size, seed, epoch,
+                                                  collate=collate)
             except Exception as e:
                 with cv:
                     errors.append((b, e))

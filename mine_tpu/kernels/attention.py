@@ -1,0 +1,287 @@
+"""Blocked causal attention (flash attention), forward and backward, in Pallas.
+
+One application of plain causal softmax attention over a packed row,
+softmax(q k^T / sqrt(D) + causal) v, without ever holding an [S, S] score
+matrix: unfused, one application's scores at S = 4096 and 16 heads are
+1.07 GB a sequence. Adapted from the idea of the TPU flash attention that
+ships with JAX (`jax.experimental.pallas.ops.tpu.flash_attention`), cut down
+to what the looped language model needs: causal, no bias, no segment ids,
+as many key-value heads as query heads, square blocks.
+
+Layout: q, k, v and the output are [B, S, H*D], the layout the projections
+produce, and a head is a lane-aligned column block of width D, so nothing is
+transposed on the way in or out (on the chip D must be a multiple of 128;
+interpret mode takes any D). The forward also returns the row log-sum-exp,
+lane-replicated as [B, H, S, 128] float32, which the backward reads.
+
+Grid (batch, head, q block, kv block), the last axis sequential. A block
+above the diagonal is skipped: its body does not run, and its index map
+points at the block the row needs anyway, so nothing is fetched for it.
+The backward is two kernels (dK/dV with the q blocks innermost, dQ with the
+kv blocks innermost), each recomputing the probabilities from q, k and the
+log-sum-exp; delta = rowsum(dO * O) is formed inside them.
+
+Precision: the inputs' dtype feeds the MXU (bfloat16 under
+`training.dtype: bfloat16`), every product accumulates in float32, and the
+running max, the normaliser and the accumulators are float32.
+
+`name=` on the three calls (`flash_attention_fwd`, `flash_attention_bwd_dkv`,
+`flash_attention_bwd_dq`) is the instruction's name in a device trace.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128
+_NEG = -1e30   # finite: a masked score never makes inf - inf
+
+
+def block_size(seq_len: int) -> int:
+    """The square block of a sequence: the largest of 512, 256, 128 that
+    divides it, else the whole sequence."""
+    for b in (512, 256, 128):
+        if seq_len % b == 0:
+            return b
+    return seq_len
+
+
+def _dot(a, b, contract):
+    return lax.dot_general(a, b, ((contract[0], contract[1]), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _causal(block):
+    row = lax.broadcasted_iota(jnp.int32, (block, block), 0)
+    col = lax.broadcasted_iota(jnp.int32, (block, block), 1)
+    return col <= row
+
+
+# ---------------- forward ----------------
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
+                scale, block):
+    i, j = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _():
+        m_sc[...] = jnp.full(m_sc.shape, _NEG, jnp.float32)
+        l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    def step(diagonal):
+        q, k, v = q_ref[...], k_ref[...], v_ref[...]
+        s = _dot(q, k, ((1,), (1,))) * scale                 # [bq, bk]
+        if diagonal:
+            s = jnp.where(_causal(block), s, _NEG)
+        m_prev, l_prev = m_sc[...], l_sc[...]                # [bq, 128]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new[:, :1])
+        l_sc[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        acc_sc[...] = acc_sc[...] * alpha[:, :1] + _dot(
+            p.astype(v.dtype), v, ((1,), (0,)))
+        m_sc[...] = m_new
+
+    @pl.when(j < i)
+    def _():
+        step(False)
+
+    @pl.when(j == i)   # the diagonal is the row's last block
+    def _():
+        step(True)
+        l = l_sc[...]
+        o_ref[...] = (acc_sc[...] / l[:, :1]).astype(o_ref.dtype)
+        lse_ref[...] = m_sc[...] + jnp.log(l)
+
+
+def _head_spec(block, d, index):
+    """A [block, D] tile of a [B, S, H*D] array: the head is a column block."""
+    return pl.BlockSpec((None, block, d), index)
+
+
+def _fwd(q, k, v, heads, interpret):
+    B, S, HD = q.shape
+    d = HD // heads
+    block = block_size(S)
+    n = S // block
+    qo = _head_spec(block, d, lambda b, h, i, j: (b, i, h))
+    kv = _head_spec(block, d, lambda b, h, i, j: (b, jnp.minimum(j, i), h))
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=1.0 / math.sqrt(d), block=block),
+        grid=(B, heads, n, n),
+        in_specs=[qo, kv, kv],
+        out_specs=[qo, pl.BlockSpec((None, None, block, LANES),
+                                    lambda b, h, i, j: (b, h, i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((B, S, HD), q.dtype),
+                   jax.ShapeDtypeStruct((B, heads, S, LANES), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block, LANES), jnp.float32),
+                        pltpu.VMEM((block, LANES), jnp.float32),
+                        pltpu.VMEM((block, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "parallel", "arbitrary")),
+        name="flash_attention_fwd",
+        interpret=interpret,
+    )(q, k, v)
+
+
+# ---------------- backward ----------------
+
+def _probabilities(q, k, lse, do, o, v, scale, block, diagonal):
+    """p and ds of one (q block, kv block) pair, float32 [bq, bk]."""
+    s = _dot(q, k, ((1,), (1,))) * scale
+    p = jnp.exp(s - lse[:, :1])
+    if diagonal:
+        p = jnp.where(_causal(block), p, 0.0)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=1,
+                    keepdims=True)
+    dp = _dot(do, v, ((1,), (1,)))
+    return p, p * (dp - delta) * scale
+
+
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                    dk_ref, dv_ref, dk_sc, dv_sc, *, scale, block, n):
+    j, i = pl.program_id(2), pl.program_id(3)   # kv block, q block
+
+    @pl.when(i == 0)
+    def _():
+        dk_sc[...] = jnp.zeros(dk_sc.shape, jnp.float32)
+        dv_sc[...] = jnp.zeros(dv_sc.shape, jnp.float32)
+
+    def step(diagonal):
+        q, do = q_ref[...], do_ref[...]
+        p, ds = _probabilities(q, k_ref[...], lse_ref[...], do, o_ref[...],
+                               v_ref[...], scale, block, diagonal)
+        dv_sc[...] += _dot(p.astype(do.dtype), do, ((0,), (0,)))
+        dk_sc[...] += _dot(ds.astype(q.dtype), q, ((0,), (0,)))
+
+    @pl.when(i == j)
+    def _():
+        step(True)
+
+    @pl.when(i > j)
+    def _():
+        step(False)
+
+    @pl.when(i == n - 1)
+    def _():
+        dk_ref[...] = dk_sc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_sc[...].astype(dv_ref.dtype)
+
+
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, dq_sc,
+                   *, scale, block):
+    i, j = pl.program_id(2), pl.program_id(3)   # q block, kv block
+
+    @pl.when(j == 0)
+    def _():
+        dq_sc[...] = jnp.zeros(dq_sc.shape, jnp.float32)
+
+    def step(diagonal):
+        k = k_ref[...]
+        _, ds = _probabilities(q_ref[...], k, lse_ref[...], do_ref[...],
+                               o_ref[...], v_ref[...], scale, block, diagonal)
+        dq_sc[...] += _dot(ds.astype(k.dtype), k, ((1,), (0,)))
+
+    @pl.when(j < i)
+    def _():
+        step(False)
+
+    @pl.when(j == i)
+    def _():
+        step(True)
+        dq_ref[...] = dq_sc[...].astype(dq_ref.dtype)
+
+
+def _bwd(heads, interpret, residuals, do):
+    q, k, v, o, lse = residuals
+    B, S, HD = q.shape
+    d = HD // heads
+    block = block_size(S)
+    n = S // block
+    scale = 1.0 / math.sqrt(d)
+    semantics = pltpu.CompilerParams(dimension_semantics=(
+        "parallel", "parallel", "parallel", "arbitrary"))
+
+    # dK, dV: kv block j outer, q blocks i >= j inner
+    row = lambda b, h, j, i: (b, jnp.maximum(i, j), h)       # noqa: E731
+    col = lambda b, h, j, i: (b, j, h)                       # noqa: E731
+    dk, dv = pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, scale=scale, block=block, n=n),
+        grid=(B, heads, n, n),
+        in_specs=[_head_spec(block, d, row), _head_spec(block, d, col),
+                  _head_spec(block, d, col), _head_spec(block, d, row),
+                  _head_spec(block, d, row),
+                  pl.BlockSpec((None, None, block, LANES),
+                               lambda b, h, j, i: (b, h, jnp.maximum(i, j),
+                                                   0))],
+        out_specs=[_head_spec(block, d, col), _head_spec(block, d, col)],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((block, d), jnp.float32),
+                        pltpu.VMEM((block, d), jnp.float32)],
+        compiler_params=semantics,
+        name="flash_attention_bwd_dkv",
+        interpret=interpret,
+    )(q, k, v, o, do, lse)
+
+    # dQ: q block i outer, kv blocks j <= i inner
+    row = lambda b, h, i, j: (b, i, h)                       # noqa: E731
+    col = lambda b, h, i, j: (b, jnp.minimum(j, i), h)       # noqa: E731
+    dq = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, scale=scale, block=block),
+        grid=(B, heads, n, n),
+        in_specs=[_head_spec(block, d, row), _head_spec(block, d, col),
+                  _head_spec(block, d, col), _head_spec(block, d, row),
+                  _head_spec(block, d, row),
+                  pl.BlockSpec((None, None, block, LANES),
+                               lambda b, h, i, j: (b, h, i, 0))],
+        out_specs=_head_spec(block, d, row),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((block, d), jnp.float32)],
+        compiler_params=semantics,
+        name="flash_attention_bwd_dq",
+        interpret=interpret,
+    )(q, k, v, o, do, lse)
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _flash(q, k, v, heads, interpret):
+    return _fwd(q, k, v, heads, interpret)[0]
+
+
+def _flash_fwd(q, k, v, heads, interpret):
+    o, lse = _fwd(q, k, v, heads, interpret)
+    return o, (q, k, v, o, lse)
+
+
+_flash.defvjp(_flash_fwd, _bwd)
+
+
+def flash_attention(q, k, v, heads: int, interpret: bool = False):
+    """Causal attention of [B, S, H*D] q, k, v (one dtype) -> [B, S, H*D]."""
+    return _flash(q, k, v, heads, interpret)
+
+
+def plain_attention(q, k, v, heads: int):
+    """The same function as one [S, S] softmax a head, in XLA: what runs off
+    the TPU (interpret mode is orders of magnitude slower than XLA there)
+    and what the kernel's tests compare with."""
+    B, S, HD = q.shape
+    d = HD // heads
+    split = lambda x: x.reshape(B, S, heads, d)              # noqa: E731
+    s = jnp.einsum("bqhd,bkhd->bhqk", split(q), split(k),
+                   preferred_element_type=jnp.float32) / math.sqrt(d)
+    s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, _NEG)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), split(v),
+                   preferred_element_type=jnp.float32)
+    return o.reshape(B, S, HD).astype(q.dtype)

@@ -27,10 +27,14 @@ import threading
 from typing import Callable, Dict, Optional
 
 # scope-name prefix -> layer (a scope `render_src_s2` is matched by
-# `render`). The five layers partition the train step: models/mpi.py opens
+# `render`). The layers partition a train step. MINE's: models/mpi.py opens
 # `encoder` / `decoder`, train/loss.py `loss_pyramid`, `render`,
 # `render_src_s<k>`, `warp_composite_tgt_s<k>`, `ssim_pairs_s<k>`,
-# train/step.py `adam_update` / `nonfinite_guard`.
+# train/trainer.py `adam_update` / `nonfinite_guard`. The looped language
+# model's: models/looplm.py opens `lm_embed`, `lm_attention`, `lm_mlp` (inside
+# the scans' bodies: a scan is one `while` in the HLO, so the map names the
+# ops inside it), train/lm_loss.py and the pass's final norm and gate
+# `lm_head_loss`; Adam and the guard are `optimizer` for both families.
 SCOPE_LAYERS = (
     ("encoder", "encoder"),
     ("decoder", "decoder"),
@@ -40,8 +44,18 @@ SCOPE_LAYERS = (
     ("ssim_pairs_s", "loss_pyramid"),
     ("adam_update", "optimizer"),
     ("nonfinite_guard", "optimizer"),
+    ("lm_embed", "embed"),
+    ("lm_attention", "attention"),
+    ("lm_mlp", "mlp"),
+    ("lm_head_loss", "head_loss"),
 )
-LAYERS = ("encoder", "decoder", "render", "loss_pyramid", "optimizer")
+# the layers that partition each model family's train step
+FAMILY_LAYERS = {
+    "mine": ("encoder", "decoder", "render", "loss_pyramid", "optimizer"),
+    "looplm": ("embed", "attention", "mlp", "head_loss", "optimizer"),
+}
+LAYERS = tuple(dict.fromkeys(
+    layer for layers in FAMILY_LAYERS.values() for layer in layers))
 
 _IDENT = re.compile(r"[A-Za-z_]\w*")
 # one instruction of an HLO module's text that carries an op_name:

@@ -34,12 +34,10 @@ from mine_tpu.testing import faults
 from mine_tpu.train import resilience
 from mine_tpu.train.checkpoint import CheckpointManager
 from mine_tpu.train.state import TrainState, current_lrs
-from mine_tpu.train.step import SynthesisTrainer, sample_disparity
+# SynthesisTrainer is re-exported for callers that took it from here
+from mine_tpu.train.step import SynthesisTrainer, sample_disparity  # noqa: F401
+from mine_tpu.train.trainer import Trainer
 from mine_tpu.utils import AverageMeter, disparity_normalization_vis, metrics_to_float
-
-TRAIN_METER_KEYS = ("loss", "loss_rgb_src", "loss_ssim_src",
-                    "loss_disp_pt3dsrc", "loss_rgb_tgt", "loss_ssim_tgt",
-                    "lpips_tgt", "psnr_tgt", "loss_disp_pt3dtgt")
 
 # host-side step-time breakdown (milliseconds, averaged per log interval):
 #   step       wall-clock per step
@@ -54,7 +52,7 @@ TIME_METER_KEYS = ("step_ms", "host_wait_ms", "device_ms", "h2d_ms")
 
 
 class TrainLoop:
-    def __init__(self, trainer: SynthesisTrainer,
+    def __init__(self, trainer: Trainer,
                  train_dataset, val_dataset,
                  workspace: str,
                  logger=None,
@@ -86,10 +84,11 @@ class TrainLoop:
             if self.resil.guard_nonfinite else 0, logger)
 
         self.is_lead = jax.process_index() == 0
+        # the model family's own meters (Trainer.METER_KEYS)
         self.train_meters = {k: AverageMeter("train_" + k)
-                             for k in TRAIN_METER_KEYS}
+                             for k in trainer.METER_KEYS}
         self.val_meters = {k: AverageMeter("val_" + k)
-                           for k in TRAIN_METER_KEYS}
+                           for k in trainer.METER_KEYS}
         self.time_meters = {k: AverageMeter("time_" + k, ":.1f")
                             for k in TIME_METER_KEYS}
 
@@ -721,17 +720,13 @@ class TrainLoop:
         step_line = telemetry.format_step_line(times,
                                                data_stats["data_errors"],
                                                extra=stage_ms or None)
+        lr_label, lr_group = self.trainer.LOG_LR
         self._log(
             "epoch [%.3d] step [%d] global_step = %d total_loss = %.4f "
-            "encoder_lr = %.7f step_time = %.3fs\n"
-            "        src: rgb = %.4f ssim = %.4f disp_pt3d = %.4f\n"
-            "        tgt: rgb = %.4f ssim = %.4f disp_pt3d = %.4f psnr = %.2f\n"
-            "        %s"
-            % (epoch, step, gstep, m["loss"], lrs["backbone"],
-               times["step_ms"] / 1e3,
-               m["loss_rgb_src"], m["loss_ssim_src"], m["loss_disp_pt3dsrc"],
-               m["loss_rgb_tgt"], m["loss_ssim_tgt"], m["loss_disp_pt3dtgt"],
-               m["psnr_tgt"], step_line))
+            "%s = %.7f step_time = %.3fs\n%s        %s"
+            % (epoch, step, gstep, m["loss"], lr_label, lrs[lr_group],
+               times["step_ms"] / 1e3, self.trainer.log_summary(m),
+               step_line))
         diag = " ".join("%s = %.6g" % (k, m[k]) for k in (
             "skipped_steps", "guard_consecutive", "warp_fallback_frac",
             "warp_subband_frac") if k in m)
@@ -751,6 +746,8 @@ class TrainLoop:
                     ("warp_subband_frac", "train.warp_subband_frac")):
                 if src_key in m:
                     telemetry.gauge(gauge_name).set(m[src_key])
+            for gauge_name, value in self.trainer.log_gauges(m, times).items():
+                telemetry.gauge(gauge_name).set(value)
             telemetry.emit(
                 "train.step", gstep=gstep, epoch=epoch,
                 loss=round(float(m["loss"]), 6),

@@ -2,9 +2,12 @@
 
 Optimizer semantics match the reference (synthesis_task.py:83-87,116-118):
 Adam with L2 weight decay folded into the gradient *before* the moment
-updates (torch.optim.Adam's weight_decay), two parameter groups with separate
-learning rates (backbone vs decoder), and a MultiStepLR schedule that decays
-both by gamma at epoch milestones.
+updates (torch.optim.Adam's weight_decay), one parameter group per top-level
+key of the parameter tree, each with its own learning rate `lr.<group>_lr`
+(MINE: backbone and decoder; the looped language model: lm), and a
+MultiStepLR schedule that decays them all by gamma at epoch milestones. A
+family whose optimizer is another hands `Trainer` its own chain
+(train/lm_step.py: clipped AdamW).
 
 Unlike the reference's checkpoints — which drop step/epoch and RNG
 (synthesis_task.py:629-631,650-652; SURVEY.md section 5) — the state carries
@@ -24,7 +27,7 @@ import optax
 @flax.struct.dataclass
 class TrainState:
     step: jnp.ndarray          # int32 scalar
-    params: Any                # {'backbone': ..., 'decoder': ...}
+    params: Any                # {group: subtree}; a group has its own lr
     batch_stats: Any
     opt_state: Any
     rng: jax.Array             # folded with step per training step
@@ -63,9 +66,20 @@ def multistep_lr(base_lr: float, decay_epochs, gamma: float,
     return optax.piecewise_constant_schedule(base_lr, boundaries)
 
 
+def lr_groups(config: Dict[str, Any]):
+    """The parameter groups a configuration trains: every `lr.<group>_lr`
+    key that holds a rate (params_default.yaml lists the key space; a
+    configuration leaves the groups it does not have at null). They are
+    the top-level keys of the parameter tree."""
+    return tuple(k[len("lr."):-len("_lr")] for k in config
+                 if k.startswith("lr.") and k.endswith("_lr")
+                 and config[k] is not None)
+
+
 def make_optimizer(config: Dict[str, Any], steps_per_epoch: int) -> optax.GradientTransformation:
-    """Two-group Adam(+L2) with MultiStepLR, matching the reference groups
-    {backbone: lr.backbone_lr, decoder: lr.decoder_lr} and lr.weight_decay.
+    """Per-group Adam(+L2) with MultiStepLR: one group per `lr.<group>_lr`
+    key (`lr_groups`), matching the reference's {backbone: lr.backbone_lr,
+    decoder: lr.decoder_lr} and lr.weight_decay.
 
     training.grad_accum_steps > 1 wraps the whole thing in optax.MultiSteps
     (no reference equivalent — SURVEY.md section 2c "Gradient accumulation:
@@ -94,15 +108,24 @@ def make_optimizer(config: Dict[str, Any], steps_per_epoch: int) -> optax.Gradie
         )
 
     def label_fn(params):
-        return {k: k for k in params}  # top-level keys: backbone / decoder
+        return {k: k for k in params}  # top-level keys are the groups
 
     tx = optax.multi_transform(
-        {"backbone": group(float(config["lr.backbone_lr"])),
-         "decoder": group(float(config["lr.decoder_lr"]))},
+        {g: group(float(config["lr.%s_lr" % g])) for g in lr_groups(config)},
         label_fn)
     if accum > 1:
         tx = optax.MultiSteps(tx, every_k_schedule=accum)
     return tx
+
+
+def new_train_state(params, batch_stats, tx, state_key) -> TrainState:
+    """Step 0 of any model family: fresh optimizer state and guard."""
+    return TrainState(step=jnp.zeros((), jnp.int32),
+                      params=params,
+                      batch_stats=batch_stats,
+                      opt_state=tx.init(params),
+                      rng=state_key,
+                      guard=make_guard_buffer())
 
 
 def create_train_state(model, config: Dict[str, Any], steps_per_epoch: int,
@@ -112,14 +135,8 @@ def create_train_state(model, config: Dict[str, Any], steps_per_epoch: int,
     variables = model.init(init_key, sample_img, sample_disparity, train=False)
     params = variables["params"]
     batch_stats = variables.get("batch_stats", {})
-    tx = make_optimizer(config, steps_per_epoch)
-    opt_state = tx.init(params)
-    return TrainState(step=jnp.zeros((), jnp.int32),
-                      params=params,
-                      batch_stats=batch_stats,
-                      opt_state=opt_state,
-                      rng=state_key,
-                      guard=make_guard_buffer())
+    return new_train_state(params, batch_stats,
+                           make_optimizer(config, steps_per_epoch), state_key)
 
 
 def current_lrs(config: Dict[str, Any], steps_per_epoch: int, step: int):
@@ -132,8 +149,8 @@ def current_lrs(config: Dict[str, Any], steps_per_epoch: int, step: int):
     decay_epochs = config.get("lr.decay_steps", [])
     accum = int(config.get("training.grad_accum_steps", 1))
     lrs = {}
-    for name, key in (("backbone", "lr.backbone_lr"), ("decoder", "lr.decoder_lr")):
-        lr = float(config[key])
+    for name in lr_groups(config):
+        lr = float(config["lr.%s_lr" % name])
         for e in decay_epochs:
             # piecewise_constant_schedule applies the scale for counts >=
             # boundary (empirically: sched(boundary) is already decayed);

@@ -14,29 +14,23 @@ step counter — reproducible and resumable by construction.
 
 from __future__ import annotations
 
-import functools
-import weakref
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 
-from mine_tpu import geometry, telemetry
+from mine_tpu import geometry
 from mine_tpu.config import (MPIConfig, mpi_config_from_dict,
                              pipeline_config_from_dict,
                              validate_model_shapes)
 from mine_tpu.models.mpi import MPIPredictor
 from mine_tpu.ops import rendering, sampling
 from mine_tpu.parallel import mesh as mesh_lib
-from mine_tpu.testing import faults
-from mine_tpu.train import resilience
 from mine_tpu.train.loss import (compute_losses, loss_from_rendered,
                                  render_all_scales)
-from mine_tpu.train.state import (GUARD_CONSEC, GUARD_LAST_BAD, GUARD_SKIPPED,
-                                  TrainState, create_train_state,
-                                  make_optimizer)
+from mine_tpu.train.state import TrainState, create_train_state
+from mine_tpu.train.trainer import Trainer
 
 
 def _remat_policy(value):
@@ -82,23 +76,29 @@ def sample_disparity(key: jax.Array, batch_size: int, cfg: MPIConfig) -> jnp.nda
         key, batch_size, S, cfg.disparity_start, cfg.disparity_end)
 
 
-class SynthesisTrainer:
-    """Owns the model + optimizer and builds the jitted step functions.
+class SynthesisTrainer(Trainer):
+    """Owns the MINE model and builds the jitted step functions.
 
     The reference's SynthesisTask god-object (synthesis_task.py:63-670) is
     split: this class is the step compiler; the host loop (logging, eval
-    cadence, checkpointing) lives in mine_tpu.train.loop.
+    cadence, checkpointing) lives in mine_tpu.train.loop; the optimizer
+    update, the guard and the step's wiring, which no model owns, live in
+    train/trainer.py `Trainer`.
     """
+
+    METER_KEYS = ("loss", "loss_rgb_src", "loss_ssim_src",
+                  "loss_disp_pt3dsrc", "loss_rgb_tgt", "loss_ssim_tgt",
+                  "lpips_tgt", "psnr_tgt", "loss_disp_pt3dtgt")
+    LOG_LR = ("encoder_lr", "backbone")
 
     def __init__(self, config: Dict[str, Any],
                  mesh=None,
                  steps_per_epoch: int = 1000,
                  lpips_params=None,
                  compiler_options: Optional[Dict[str, Any]] = None):
-        self.config = config
+        super().__init__(config, mesh=mesh, steps_per_epoch=steps_per_epoch,
+                         compiler_options=compiler_options)
         self.cfg = mpi_config_from_dict(config)
-        self.mesh = mesh
-        self.steps_per_epoch = steps_per_epoch
         validate_model_shapes(self.cfg)
 
         # Pallas backends compose with multi-device meshes via shard_map
@@ -135,53 +135,13 @@ class SynthesisTrainer:
                     f"B*S block still shards over ('data','plane')")
         self.remat, self.remat_policy = _remat_policy(
             config.get("training.remat", False))
-        self.grad_accum_steps = int(config.get("training.grad_accum_steps", 1))
-        assert self.grad_accum_steps >= 1, self.grad_accum_steps
-        self.tx = make_optimizer(config, steps_per_epoch)
         self.lpips_params = lpips_params
-        # Non-finite step guard (training.guard_nonfinite, default on): the
-        # all-finite check and zero-update swap are traced INTO the step —
-        # no extra host sync, guard counters ride in TrainState.guard and
-        # surface through the (already log-cadence-synced) metrics.
-        self.guard_nonfinite = bool(config.get("training.guard_nonfinite",
-                                               True))
-        # Per-layer-group training telemetry (training.layer_stats, default
-        # off): per-group grad norms, update-to-weight ratios, and plane
-        # alpha distribution summaries, computed INSIDE the jitted step as
-        # scalar metrics. They ride the existing log-cadence metrics
-        # readback — zero additional host syncs (the transfer_guard audit
-        # pass runs with this enabled), and no new dot_generals (norms and
-        # moments are elementwise + reductions), so dot budgets are
-        # unchanged.
-        self.layer_stats = bool(config.get("training.layer_stats", False))
-        # Fault injection is resolved at TRACE time (set the plan before
-        # constructing the trainer): None in production, so the injected
-        # jnp.where never enters the compiled program.
-        self._nan_grad_window = faults.nan_grad_window()
 
-        # compiler_options reach every jitted step — the multichip dry run
-        # certifies CORRECTNESS of the sharded programs on a single-core
-        # CPU host and passes xla_backend_optimization_level=0 there (the
-        # SPMD partitioner and numerics are unaffected; only backend
-        # codegen effort drops, ~2.3x faster compiles). None for training.
-        jit = functools.partial(jax.jit, compiler_options=compiler_options) \
-            if compiler_options else jax.jit
-        # training.donate_batch: also donate the BATCH buffers to the train
-        # step, so XLA reuses the staged input memory instead of holding
-        # both the live batch and the step's workspace. Valid only when
-        # every step gets a freshly staged batch (the async input pipeline,
-        # train/loop.py + data/pipeline.py); callers that re-feed one
-        # resident batch (bench.py's device-step variants, overfit tests)
-        # must leave it off or the second call hits deleted buffers.
-        donate_train = (0, 1) if bool(
-            config.get("training.donate_batch", False)) else (0,)
+        jit = self._jit
+        self._train_step = self._jit_train_step()
         if mesh is not None:
             batch_s = mesh_lib.batch_sharding(mesh)
             repl = mesh_lib.replicated(mesh)
-            self._train_step = jit(self._train_step_impl,
-                                   in_shardings=(repl, batch_s),
-                                   out_shardings=(repl, repl),
-                                   donate_argnums=donate_train)
             self._eval_step = jit(self._eval_step_impl,
                                   in_shardings=(repl, batch_s, repl),
                                   out_shardings=repl)
@@ -194,8 +154,6 @@ class SynthesisTrainer:
                 in_shardings=(repl, batch_s, repl, batch_s),
                 out_shardings=repl)
         else:
-            self._train_step = jit(self._train_step_impl,
-                                   donate_argnums=donate_train)
             self._eval_step = jit(self._eval_step_impl)
             self._eval_step_masked = jit(self._eval_step_masked_impl)
         # Encode-once eval (serve.eval_encode_once, train/loop.py run_eval):
@@ -216,55 +174,19 @@ class SynthesisTrainer:
         # constructed and the fused jitted step above runs untouched —
         # bitwise-identical outputs, same-compiled program.
         self.pipeline_cfg = pipeline_config_from_dict(config)
-        self._step_registered = False  # telemetry.programs has the step
-        self._pipeline = None
         if self.pipeline_cfg.enabled:
             from mine_tpu.parallel.pipeline import PipelineExecutor
             self._pipeline = PipelineExecutor(self, self.pipeline_cfg)
 
-    # ---------------- batch geometry ----------------
-
-    def global_batch_size(self) -> int:
-        """data.per_gpu_batch_size is per *device on the data axis* (the
-        reference's per-GPU batch, train.py:84); the jitted step sees the
-        global batch."""
-        per_device = int(self.config.get("data.per_gpu_batch_size", 2))
-        data_size = self.mesh.shape[mesh_lib.DATA_AXIS] if self.mesh else 1
-        return per_device * data_size
-
-    def local_batch_size(self) -> int:
-        """Examples each host must feed per step."""
-        assert self.global_batch_size() % jax.process_count() == 0
-        return self.global_batch_size() // jax.process_count()
-
-    def put_batch(self, np_batch):
-        """Host batch -> (possibly multi-host global) device batch, committed
-        under the mesh's input sharding (parallel/mesh.put_batch) so the
-        jitted step consumes it without a reshard. Called by the train
-        loop's DeviceStager from a background thread — keep it free of
-        trainer state mutation."""
-        return mesh_lib.put_batch(np_batch, self.mesh)
-
     # ---------------- state ----------------
 
-    def init_state(self, batch_size: int, seed: Optional[int] = None) -> TrainState:
-        if seed is None:
-            seed = int(self.config.get("training.seed", 0))
-        H, W = self.cfg.img_h, self.cfg.img_w
-
-        def init():
-            img = jnp.zeros((batch_size, H, W, 3), jnp.float32)
-            disp = jnp.full((batch_size, self.cfg.num_bins_total), 0.5,
-                            jnp.float32)
-            return create_train_state(self.model, self.config,
-                                      self.steps_per_epoch, img, disp,
-                                      seed=seed)
-
-        # ONE compiled program, placed where the step wants its state. Run
-        # op by op, a ResNet-50 init is ~700 small programs and the TPU's
-        # compiler takes about a second for each (chip run, PR 24: 337 s).
-        out = mesh_lib.replicated(self.mesh) if self.mesh is not None else None
-        return jax.jit(init, out_shardings=out)()
+    def _init_state_impl(self, batch_size: int, seed) -> TrainState:
+        img = jnp.zeros((batch_size, self.cfg.img_h, self.cfg.img_w, 3),
+                        jnp.float32)
+        disp = jnp.full((batch_size, self.cfg.num_bins_total), 0.5,
+                        jnp.float32)
+        return create_train_state(self.model, self.config,
+                                  self.steps_per_epoch, img, disp, seed=seed)
 
     # ---------------- forward ----------------
 
@@ -403,90 +325,6 @@ class SynthesisTrainer:
         grads, metrics, new_stats = self._grads_and_metrics(state, batch, key)
         return self._apply_update(state, grads, metrics, new_stats)
 
-    def _apply_update(self, state: TrainState, grads, metrics,
-                      new_stats) -> Tuple[TrainState, Dict]:
-        """Optimizer update + non-finite guard + layer telemetry over
-        already-computed (possibly pipeline-accumulated) gradients. The
-        fused step traces this inline; the pipeline executor jits it as its
-        own update program — one body, so both paths apply the identical
-        update/guard/metrics semantics."""
-        if self._nan_grad_window is not None:
-            # chaos-test seam: poison the gradients at the planned step(s);
-            # absent a plan this branch is not traced at all
-            at_step, from_step = self._nan_grad_window
-            poison = jnp.zeros((), bool)
-            if at_step >= 0:
-                poison |= state.step == at_step
-            if from_step >= 0:
-                poison |= state.step >= from_step
-            grads = jax.tree_util.tree_map(
-                lambda g: jnp.where(poison, jnp.asarray(jnp.nan, g.dtype), g),
-                grads)
-        with jax.named_scope("adam_update"):
-            updates, new_opt_state = self.tx.update(grads, state.opt_state,
-                                                    state.params)
-            new_params = optax.apply_updates(state.params, updates)
-        guard = state.guard
-        if self.guard_nonfinite:
-            with jax.named_scope("nonfinite_guard"):
-                gnorm = optax.global_norm(grads)
-                ok = jnp.isfinite(metrics["loss"]) & jnp.isfinite(gnorm)
-                # poisoned step -> zero-update: keep the old params /
-                # opt_state / batch_stats (step still advances, so the RNG
-                # stream and cadences stay aligned with an unpoisoned run)
-                new_params = resilience.select_tree(ok, new_params,
-                                                    state.params)
-                new_opt_state = resilience.select_tree(ok, new_opt_state,
-                                                       state.opt_state)
-                new_stats = resilience.select_tree(ok, new_stats,
-                                                   state.batch_stats)
-                bad = (~ok).astype(jnp.int32)
-                skipped = state.guard[GUARD_SKIPPED] + bad
-                consec = (state.guard[GUARD_CONSEC] + bad) * bad
-                last_bad = jnp.where(ok, state.guard[GUARD_LAST_BAD],
-                                     state.step.astype(jnp.int32))
-                guard = jnp.stack([skipped, consec, last_bad])
-                metrics = dict(metrics,
-                               grad_norm=gnorm,
-                               skipped_steps=skipped,
-                               guard_consecutive=consec,
-                               guard_last_bad_step=last_bad)
-        if self.layer_stats:
-            # per-top-level-group (backbone / decoder) optimization health:
-            # grad norm, and the update-to-weight ratio that flags a group
-            # whose effective learning rate has gone degenerate. Scalars
-            # only — they merge into the metrics dict and reach the host
-            # exclusively through the log-cadence readback. Placement is
-            # deliberate: the numeric step must be bitwise-identical with
-            # layer_stats on or off, so the norms only touch values that
-            # are materialized either way — grads (whose per-leaf square
-            # sums CSE with the nonfinite guard's global norm), the input
-            # params, and the POST-guard new_params that the step returns.
-            # Consuming the optax `updates` tree (or the pre-guard
-            # new_params) re-fuses the adam update and drifts a leaf, so
-            # the applied-update norm is taken as ||new - old|| instead —
-            # which also truthfully reads 0 on a guard-skipped step.
-            with jax.named_scope("layer_stats_groups"):
-                layer_metrics = {}
-                for group in state.params:
-                    gn = optax.global_norm(grads[group])
-                    un = optax.global_norm(jax.tree_util.tree_map(
-                        lambda n, o: n - o, new_params[group],
-                        state.params[group]))
-                    wn = optax.global_norm(state.params[group])
-                    layer_metrics[f"layers/{group}.grad_norm"] = gn
-                    layer_metrics[f"layers/{group}.param_norm"] = wn
-                    layer_metrics[f"layers/{group}.update_ratio"] = \
-                        un / (wn + 1e-12)
-                metrics = dict(metrics, **layer_metrics)
-        new_state = TrainState(step=state.step + 1,
-                               params=new_params,
-                               batch_stats=new_stats,
-                               opt_state=new_opt_state,
-                               rng=state.rng,
-                               guard=guard)
-        return new_state, metrics
-
     def _eval_step_impl(self, state: TrainState, batch, eval_key,
                         example_weight=None):
         """Validation step: eval-mode BN, LPIPS at scale 0 when weights are
@@ -562,41 +400,14 @@ class SynthesisTrainer:
 
     # ---------------- public API ----------------
 
-    def train_step(self, state: TrainState, batch) -> Tuple[TrainState, Dict]:
-        with telemetry.span("train.step.dispatch"):
-            if self._pipeline is not None:
-                return self._pipeline.step(state, batch)
-            if not self._step_registered:
-                self._register_step_program(state, batch)
-            return self._train_step(state, batch)
-
-    def _register_step_program(self, state: TrainState, batch) -> None:
-        """Remember the first call's avals and shardings, and tell
-        telemetry/programs.py how to get the step's optimized HLO text from
-        them (instruction name -> layer, for readers of a device trace).
-        Lazy: nothing is lowered unless `programs.layers` is asked, after
-        the run; the compile it then makes is the one this call makes, so
-        the compile caches have it."""
-        self._step_registered = True
-
-        def aval(x):
-            # a sharding only where the array is committed to it: an aval
-            # that commits an uncommitted argument lowers to another
-            # module, and compiles again (chip run, PR 28: 116 s)
-            sharding = x.sharding if getattr(x, "committed", False) else None
-            return jax.ShapeDtypeStruct(jnp.shape(x), jnp.result_type(x),
-                                        sharding=sharding)
-
-        avals = jax.tree_util.tree_map(aval, (state, batch))
-        trainer = weakref.ref(self)
-
-        def text_fn() -> str:
-            me = trainer()
-            if me is None:
-                return ""
-            return me._train_step.lower(*avals).compile().as_text()
-
-        telemetry.programs.register(self._train_step_impl.__name__, text_fn)
+    def log_summary(self, m) -> str:
+        return ("        src: rgb = %.4f ssim = %.4f disp_pt3d = %.4f\n"
+                "        tgt: rgb = %.4f ssim = %.4f disp_pt3d = %.4f "
+                "psnr = %.2f\n" % (
+                    m["loss_rgb_src"], m["loss_ssim_src"],
+                    m["loss_disp_pt3dsrc"], m["loss_rgb_tgt"],
+                    m["loss_ssim_tgt"], m["loss_disp_pt3dtgt"],
+                    m["psnr_tgt"]))
 
     def eval_step(self, state: TrainState, batch, eval_key):
         return self._eval_step(state, batch, eval_key)

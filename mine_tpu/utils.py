@@ -131,4 +131,13 @@ def make_logger(log_file: Optional[str] = None,
 
 
 def metrics_to_float(metrics: Dict) -> Dict[str, float]:
-    return {k: float(v) for k, v in metrics.items()}
+    """Step metrics as floats; a vector metric `k` of n entries becomes
+    `k.1` .. `k.n` (the looped model's per-pass terms)."""
+    out = {}
+    for k, v in metrics.items():
+        if np.ndim(v) == 0:
+            out[k] = float(v)
+        else:
+            for i, x in enumerate(np.asarray(v).ravel(), 1):
+                out["%s.%d" % (k, i)] = float(x)
+    return out

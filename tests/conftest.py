@@ -48,6 +48,7 @@ QUICK = {
     "test_kernels.py::test_fused_volume_render_z_mask",
     "test_kitti.py::test_calib_parsing_and_geometry",
     "test_loop.py::test_average_meter",
+    "test_looplm.py::test_exit_distribution_sums_to_one_and_one_pass_is_plain_ce",
     "test_loss_aggregation.py::test_compute_scale_factor_formula",
     "test_fused_loss.py::test_ssim_pairs_matches_separate_calls",
     "test_step_breakdown.py::test_parse_extracts_all_buckets",

@@ -536,4 +536,4 @@ def test_train_step_dispatch_span_and_lazy_program_map():
         logger.setLevel(level)
         programs.reset()
     assert not [m for m in compiles if "_train_step_impl" in m], compiles
-    assert set(found.values()) == set(programs.LAYERS)
+    assert set(found.values()) == set(programs.FAMILY_LAYERS["mine"])

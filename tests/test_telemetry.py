@@ -296,6 +296,7 @@ def test_log_training_emits_st1_line_and_registry(tmp_path, clean_sink):
     from types import SimpleNamespace
 
     from mine_tpu.train.loop import TIME_METER_KEYS, TrainLoop
+    from mine_tpu.train.step import SynthesisTrainer
     from mine_tpu.utils import AverageMeter
     from tests.test_train import tiny_config
 
@@ -305,7 +306,10 @@ def test_log_training_emits_st1_line_and_registry(tmp_path, clean_sink):
     from collections import deque
     stub = SimpleNamespace(
         config=tiny_config(),
-        trainer=SimpleNamespace(steps_per_epoch=10),
+        trainer=SimpleNamespace(
+            steps_per_epoch=10, LOG_LR=SynthesisTrainer.LOG_LR,
+            log_summary=lambda m: SynthesisTrainer.log_summary(None, m),
+            log_gauges=lambda m, times: {}),
         telem=SimpleNamespace(enabled=True),
         time_meters={k: AverageMeter("time_" + k, ":.1f")
                      for k in TIME_METER_KEYS},

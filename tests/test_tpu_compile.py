@@ -29,6 +29,8 @@ SMALLEST = (48, 64)      # the 4-scale loss pyramid's last level
 BAND = 48                # training.warp_band default; all of a 48-row image
 SERVE_BAND = 32          # infer/video.py WARP_BAND
 SERVE_POSES = 8          # serve.max_bucket default
+# params_ouro_2p6b.yaml / benchmark/traffic/packed_docs_4k.json
+LM_ROWS, LM_SEQ = 2, 4096
 
 
 @pytest.fixture(scope="module")
@@ -131,7 +133,17 @@ def _megakernel_case():
              ((1, S, H, W), jnp.float32), ((1, S, H, W), jnp.float32)])
 
 
+def _attention_case():
+    """kernels/attention.py forward + both backward kernels at the looped
+    language model's cell: 2 rows of 4096 tokens, 16 heads x 128, bf16."""
+    from mine_tpu.kernels.attention import flash_attention
+    shapes = [((LM_ROWS, LM_SEQ, 16 * 128), jnp.bfloat16)] * 3
+    return jax.grad(lambda q, k, v: _sq(flash_attention(
+        q, k, v, 16, interpret=False)), argnums=(0, 1, 2)), shapes
+
+
 CASES = {
+    "attention_vjp-4096x16x128": _attention_case,
     "warp_diff_vjp-384x512": _warp_case("pallas_diff", FULL),
     "warp_diff_vjp-48x64": _warp_case("pallas_diff", SMALLEST),
     "composite_vjp-384x512": _composite_case(FULL),
@@ -159,3 +171,44 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_compile_cache):
         compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), (
         f"{case}: compiled, but no Pallas kernel is in the program")
+
+
+def test_looplm_step_compiles_and_fits_v5e(one_chip, no_compile_cache,
+                                           monkeypatch):
+    """The looped language model's whole train step at its cell's sizes
+    (params_ouro_2p6b.yaml: 8 layers x 4 passes at the published widths, 2
+    rows of 4096 tokens, float32 state + Adam): the chip's compiler takes
+    it, the attention kernels are in it, and its peak fits the chip."""
+    import os
+
+    from mine_tpu.config import CONFIG_DIR, load_config
+    from mine_tpu.models import looplm
+    from mine_tpu.train.lm_step import LoopLMTrainer
+
+    # `default_attention` asks for the running backend, which is the CPU here
+    monkeypatch.setattr(looplm, "on_tpu_backend", lambda: True)
+    config = load_config(os.path.join(CONFIG_DIR, "params_ouro_2p6b.yaml"))
+    assert (config["data.per_gpu_batch_size"], config["data.seq_len"]) == (
+        LM_ROWS, LM_SEQ)
+    trainer = LoopLMTrainer(config, steps_per_epoch=32)
+    put = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    state = put(jax.eval_shape(
+        lambda seed: trainer._init_state_impl(LM_ROWS, seed), jnp.int32(0)))
+    batch = put({"tokens": jax.ShapeDtypeStruct((LM_ROWS, LM_SEQ), jnp.int32),
+                 "labels": jax.ShapeDtypeStruct((LM_ROWS, LM_SEQ), jnp.int32),
+                 "mask": jax.ShapeDtypeStruct((LM_ROWS, LM_SEQ),
+                                              jnp.float32)})
+    with jax.default_matmul_precision("default"):
+        compiled = trainer._train_step.lower(state, batch).compile()
+    text = compiled.as_text()
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dkv",
+                   "flash_attention_bwd_dq"):
+        assert kernel in text, kernel
+    analysis = compiled.memory_analysis()
+    state_bytes = 12 * 612_438_017   # float32 parameters + Adam's two moments
+    assert analysis.argument_size_in_bytes >= state_bytes
+    # a v5e has 16 GB of HBM, 15.75 GiB of them usable
+    assert analysis.peak_memory_in_bytes <= 15.75 * 2**30, (
+        analysis.peak_memory_in_bytes / 2**30)

@@ -42,10 +42,9 @@ def main():
 
     from mine_tpu.config import CONFIG_DIR, load_config, save_config
     from mine_tpu.data.llff import get_dataset
-    from mine_tpu.losses import lpips as lpips_mod
     from mine_tpu.parallel.mesh import make_mesh
     from mine_tpu.train.loop import TrainLoop
-    from mine_tpu.train.step import SynthesisTrainer
+    from mine_tpu.train.trainer import make_trainer
     from mine_tpu.utils import describe_runtime, make_logger
 
     config_path = args.config_path or os.path.join(CONFIG_DIR,
@@ -96,10 +95,16 @@ def main():
 
     train_ds, val_ds = get_dataset(config, logger)
 
-    lpips_params = lpips_mod.load_params(lpips_mod.default_weights_path())
-    if lpips_params is None:
-        logger.info("LPIPS weights not found (%s); lpips metric disabled",
-                    lpips_mod.default_weights_path())
+    # model.family selects the trainer (and, through data.name, the dataset
+    # above); each family's modules are imported only on its own path
+    family_kwargs = {}
+    if config.get("model.family", "mine") == "mine":
+        from mine_tpu.losses import lpips as lpips_mod
+        lpips_params = lpips_mod.load_params(lpips_mod.default_weights_path())
+        if lpips_params is None:
+            logger.info("LPIPS weights not found (%s); lpips metric disabled",
+                        lpips_mod.default_weights_path())
+        family_kwargs["lpips_params"] = lpips_params
 
     # steps_per_epoch drives the LR schedule AND the loop's epoch accounting —
     # computed once from the global batch geometry (per-device batch x data
@@ -108,11 +113,12 @@ def main():
     data_size = mesh.shape[DATA_AXIS] if mesh is not None else 1
     global_batch = int(config["data.per_gpu_batch_size"]) * data_size
     steps_per_epoch = max(1, len(train_ds) // global_batch)
-    trainer = SynthesisTrainer(config, mesh=mesh,
-                               steps_per_epoch=steps_per_epoch,
-                               lpips_params=lpips_params)
-    logger.info("Backends: warp=%s composite=%s", trainer.cfg.warp_backend,
-                trainer.cfg.composite_backend)
+    trainer = make_trainer(config, mesh=mesh, steps_per_epoch=steps_per_epoch,
+                           **family_kwargs)
+    logger.info("Trainer: %s", type(trainer).__name__)
+    if hasattr(trainer.cfg, "warp_backend"):
+        logger.info("Backends: warp=%s composite=%s",
+                    trainer.cfg.warp_backend, trainer.cfg.composite_backend)
 
     state = trainer.init_state(trainer.global_batch_size())
     pretrained = config.get("model.pretrained_weights_path") or \

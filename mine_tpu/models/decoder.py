@@ -3,9 +3,11 @@
 Reference: network/monodepth2/depth_decoder.py. Semantics preserved:
   * each of the S disparities is positionally encoded (21-dim for multires=10)
     and appended as constant channel maps to every skip feature
-  * features are replicated S times — the effective batch through the decoder
-    is B*S (depth_decoder.py:105-116); this axis is the natural sharding axis
-    for data*plane parallelism on a TPU mesh
+  * the effective batch through the decoder is B*S (the reference replicates
+    every feature S times, depth_decoder.py:105-116); this axis is the
+    natural sharding axis for data*plane parallelism on a TPU mesh. The
+    replication itself is never built: a conv over [x, skip, embedding]
+    takes the skip once at batch B (the comment above the stage loop)
   * a downsample-conv-upsample "receptive-field extension" neck on the last
     encoder feature (depth_decoder.py:56-61,97-101)
   * 5 up-stages with skip connections, 4-channel output heads at scales 0-3
@@ -125,22 +127,19 @@ class MPIDecoder(nn.Module):
             the chunking lines up with [B/data, S/plane] blocks per device)."""
             return constrain(t, self.mesh, (DATA_AXIS, PLANE_AXIS))
 
-        def expand(feat):
-            """[B,h,w,C] -> [B*S,h,w,C] (plane-major per example)."""
-            _, h, w, C = feat.shape
-            f = jnp.broadcast_to(feat[:, None], (B, S, h, w, C))
-            return shard_bs(f.reshape(B * S, h, w, C))
-
-        # The plane embedding is spatially CONSTANT, so every conv that
-        # consumes an [..., E]-suffixed concat instead receives the E
-        # values as a const_tail (layers.Conv): identical parameters and
-        # math (reflect padding preserves constants — the conv's E-channel
-        # contribution is exactly a per-plane bias), but the [B*S, h, w, E]
-        # broadcasts are never materialized, convolved, or differentiated.
-        # The kernel channel order stays [x, skip, emb] / [neck, emb], so
-        # converted reference checkpoints drop in unchanged.
-        x = expand(x)  # replaces features[-1] as the decoder stem
-        tail = emb     # pending const-tail for the NEXT ConvBlock
+        # Every conv of the reference that consumes a concat
+        # [x, expand(skip), broadcast(emb)] (or [expand(neck), emb] at the
+        # stem) receives the three parts apart (layers.Conv): the per-plane
+        # x at batch B*S, the skip or neck feature ONCE at batch B as the
+        # `shared` part, and the E spatially constant embedding values as
+        # the `const_tail`. Same parameters and the same sum of products
+        # (the kernel's channel order stays [x, skip, emb] / [neck, emb],
+        # so converted reference checkpoints drop in unchanged), but a
+        # feature all S planes of an image share is padded, convolved and
+        # differentiated once, not S times, and no [B*S, h, w, C_skip + E]
+        # broadcast is ever materialized.
+        shared, tail = x, emb  # parts pending for the NEXT ConvBlock
+        x = None               # the stem has no per-plane part
 
         outputs = {}
         for i in range(4, -1, -1):
@@ -148,8 +147,8 @@ class MPIDecoder(nn.Module):
             width = NUM_CH_DEC[i] * (4 if packed else 1)
             x = ConvBlock(width, dtype=self.dtype,
                           name=f"upconv_{i}_0{'p' if packed else ''}")(
-                              x, train, const_tail=tail)
-            tail = None
+                              x, train, shared=shared, const_tail=tail)
+            shared = tail = None
             if not packed:  # packed stage 0 stays at stride 2 until its head
                 x = shard_bs(upsample_nearest_2x(x))
             else:
@@ -159,13 +158,11 @@ class MPIDecoder(nn.Module):
                 # infer stage 0's layout on multi-device meshes
                 x = shard_bs(x)
             if self.use_skips and i > 0:
-                x = jnp.concatenate(
-                    [x, expand(features[i - 1].astype(dd))], axis=-1)
-                tail = emb
+                shared, tail = features[i - 1].astype(dd), emb
             x = ConvBlock(width, dtype=self.dtype,
                           name=f"upconv_{i}_1{'p' if packed else ''}")(
-                              x, train, const_tail=tail)
-            tail = None
+                              x, train, shared=shared, const_tail=tail)
+            shared = tail = None
             if i in self.scales:
                 out = Conv(self.num_output_channels * (4 if packed else 1),
                            3, pad_mode="reflect", dtype=self.dtype,

@@ -30,23 +30,30 @@ def torch_bias_init(key, shape, dtype, fan_in: int):
     return jax.random.uniform(key, shape, dtype, -bound, bound)
 
 
-class _SplitTailConv(nn.Module):
-    """Conv whose last `tail` input channels are spatially CONSTANT.
+class _PartsConv(nn.Module):
+    """Conv over concat([x, expand(shared), broadcast(tail)], -1) that never
+    forms the concat.
 
-    Holds the FULL [k, k, C+E, F] kernel (checkpoint-identical to the
-    plain conv over the concatenated input) but receives only the first C
-    channels as a tensor plus the E constant values per batch element.
-    Because a constant map stays constant under reflect padding, the conv's
-    contribution from those channels is exactly a per-example bias:
-    values @ sum_kl W[k, l, C:, :]. Skipping them saves materializing,
-    convolving, and differentiating a [B, H, W, E] broadcast — the
-    positional-encoding channels of the MPI decoder's skip concats
-    (models/decoder.py, the const-tail block above its stage loop;
-    measured r5, BENCH_NOTES_r05.md).
+    Holds the FULL [k, k, Cx+Cs+E, F] kernel (checkpoint-identical to the
+    plain conv over the concatenated input, channel order [x, shared, tail])
+    and sums three parts, each with its own slice of that kernel:
+
+      x       [N, h, w, Cx]  per-plane, N = B*S: the only conv at batch N
+      shared  [B, h, w, Cs]  one image for all S planes of an example: ONE
+                             conv at batch B, broadcast over S
+      tail    [N, E]         spatially constant per plane: a constant map
+                             stays constant under reflect padding, so its
+                             contribution is values @ sum_kl W[k, l, tail]
+
+    The inputs arrive padded (padding acts per channel, so padding the
+    parts is padding the concat). Any part may be absent. The shared conv,
+    the tail term and the bias are formed and summed in float32 and added
+    once to the per-plane conv's result, so y is rounded to `dtype` once
+    after the conv. Autodiff transposes the broadcast to a sum over S: the
+    shared part's input and weight gradients are convs at batch B too.
     """
     features: int
     kernel_size: int
-    full_in: int           # C + E — the checkpoint kernel's fan-in
     strides: int
     padding: Tuple          # lax-style ((t, b), (l, r)) spatial padding
     use_bias: bool
@@ -55,36 +62,60 @@ class _SplitTailConv(nn.Module):
     dtype: Optional[Dtype]
 
     @nn.compact
-    def __call__(self, x, tail_values):
+    def __call__(self, x, shared, tail):
         k = self.kernel_size
+        Cx, Cs, E = (0 if t is None else t.shape[-1]
+                     for t in (x, shared, tail))
         kernel = self.param("kernel", self.kernel_init,
-                            (k, k, self.full_in, self.features), jnp.float32)
+                            (k, k, Cx + Cs + E, self.features), jnp.float32)
         bias = self.param("bias", self.bias_init, (self.features,),
                           jnp.float32) if self.use_bias else None
-        C = x.shape[-1]
-        assert C + tail_values.shape[-1] == self.full_in, \
-            (C, tail_values.shape, self.full_in)
-        dt = self.dtype or jnp.promote_types(x.dtype, jnp.float32)
-        y = jax.lax.conv_general_dilated(
-            x.astype(dt), kernel[:, :, :C, :].astype(dt),
-            window_strides=(self.strides, self.strides),
-            padding=self.padding,
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        w_tail = jnp.sum(kernel[:, :, C:, :], axis=(0, 1))  # [E, F]
-        y = y + (tail_values.astype(dt) @ w_tail.astype(dt))[:, None, None, :]
-        if bias is not None:
-            y = y + bias.astype(dt)
-        return y
+        # output batch N = B*S; B is the shared part's batch when there is one
+        N = next(t for t in (x, tail, shared) if t is not None).shape[0]
+        B = N if shared is None else shared.shape[0]
+        dt = self.dtype or jnp.promote_types(
+            (shared if x is None else x).dtype, jnp.float32)
+
+        def conv(inp, w):
+            return jax.lax.conv_general_dilated(
+                inp, w, window_strides=(self.strides, self.strides),
+                padding=self.padding,
+                dimension_numbers=("NHWC", "HWIO", "NHWC"))
+
+        def f32(t):
+            """Operand of a float32 side product: rounded to `dt` first, so
+            the products are those of the conv over the concat (on the TPU
+            a float32 product at default precision is one `dt`-wide pass
+            either way); the accumulation and the result stay float32."""
+            return t.astype(dt).astype(jnp.float32)
+
+        # float32 side term: [F] + [B, 1, h, w, F] + [B, S, 1, 1, F]
+        side = jnp.zeros((), jnp.float32) if bias is None else bias
+        if shared is not None:
+            side = side + conv(f32(shared),
+                               f32(kernel[:, :, Cx:Cx + Cs]))[:, None]
+        if tail is not None:
+            w_tail = jnp.sum(kernel[:, :, Cx + Cs:], axis=(0, 1))  # [E, F]
+            side = side + (f32(tail) @ f32(w_tail)).reshape(
+                B, N // B, 1, 1, self.features)
+        if x is None:
+            y = side
+        else:
+            y = conv(x.astype(dt), kernel[:, :, :Cx].astype(dt))
+            y = y.reshape((B, N // B) + y.shape[1:]) + side
+        return y.reshape((N,) + y.shape[2:]).astype(dt)
 
 
 class Conv(nn.Module):
     """NHWC conv with torch-style symmetric padding and init.
 
-    `const_tail` ([B, E], optional call arg): the conv behaves as if the
-    input were concat([x, broadcast(const_tail)], -1) — same parameter
-    shapes/paths as that conv — without the broadcast ever existing (see
-    _SplitTailConv). Only valid with reflect padding (or none): zero
-    padding breaks the constant-map identity at borders.
+    `shared` ([B, h, w, Cs]) and `const_tail` ([N, E]) are optional call
+    args: the conv behaves as if the input were
+    concat([x, expand(shared), broadcast(const_tail)], -1), with the same
+    parameter shapes/paths as that conv, without the expand or the
+    broadcast ever existing (see _PartsConv). `x` may then be None (the
+    whole input is shared + tail). Only valid with reflect padding (or
+    none): zero padding breaks the constant-map identity at borders.
     """
     features: int
     kernel_size: int = 3
@@ -96,28 +127,28 @@ class Conv(nn.Module):
     dtype: Optional[Dtype] = None
 
     @nn.compact
-    def __call__(self, x, const_tail=None):
+    def __call__(self, x, shared=None, const_tail=None):
         k = self.kernel_size
         p = (k - 1) // 2 if self.padding is None else self.padding
+        pad = ((p, p), (p, p))
         if p > 0 and self.pad_mode == "reflect":
-            x = jnp.pad(x, ((0, 0), (p, p), (p, p), (0, 0)), mode="reflect")
+            x, shared = (t if t is None else jnp.pad(
+                t, ((0, 0), (p, p), (p, p), (0, 0)), mode="reflect")
+                for t in (x, shared))
             pad = ((0, 0), (0, 0))
-        else:
-            pad = ((p, p), (p, p))
-        tail = 0 if const_tail is None else const_tail.shape[-1]
-        fan_in = k * k * (x.shape[-1] + tail)
+        fan_in = k * k * sum(t.shape[-1] for t in (x, shared, const_tail)
+                             if t is not None)
         bias_init = lambda key, shape, dtype=jnp.float32: torch_bias_init(  # noqa: E731
             key, shape, dtype, fan_in)
-        if const_tail is not None:
+        if shared is not None or const_tail is not None:
             assert self.pad_mode == "reflect" or p == 0, \
-                "const_tail needs reflect (or no) padding"
-            return _SplitTailConv(
+                "shared / const_tail need reflect (or no) padding"
+            return _PartsConv(
                 features=self.features, kernel_size=k,
-                full_in=x.shape[-1] + tail,
                 strides=self.strides, padding=pad,
                 use_bias=self.use_bias, kernel_init=self.kernel_init,
                 bias_init=bias_init, dtype=self.dtype,
-                name="conv")(x, const_tail)
+                name="conv")(x, shared, const_tail)
         conv = nn.Conv(
             features=self.features,
             kernel_size=(k, k),
@@ -182,15 +213,16 @@ class ConvBlock(nn.Module):
     """Reflect-pad 3x3 conv (with bias) + BN + ELU.
 
     Reference: monodepth2/layers.py:106-120 (ConvBlock = Conv3x3 + BN + ELU,
-    Conv3x3 uses ReflectionPad2d).
+    Conv3x3 uses ReflectionPad2d). `shared` / `const_tail` are Conv's: BN
+    and ELU act on the summed conv output at the full batch either way.
     """
     features: int
     dtype: Optional[Dtype] = None
 
     @nn.compact
-    def __call__(self, x, train: bool, const_tail=None):
+    def __call__(self, x, train: bool, shared=None, const_tail=None):
         x = Conv(self.features, 3, pad_mode="reflect", dtype=self.dtype,
-                 name="conv3x3")(x, const_tail=const_tail)
+                 name="conv3x3")(x, shared=shared, const_tail=const_tail)
         x = BatchNorm(use_running_average=not train, dtype=self.dtype,
                       name="bn")(x)
         return nn.elu(x)

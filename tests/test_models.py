@@ -180,3 +180,172 @@ def test_bfloat16_forward_finite():
     outs = model.apply(variables, img, disparity, train=False)
     assert outs[0].dtype == jnp.float32  # rendering path gets fp32
     assert np.all(np.isfinite(np.asarray(outs[0])))
+
+
+# --- the three-part conv (layers._PartsConv) ------------------------------
+
+def _bf16_exact(tree):
+    """Round every leaf to a bfloat16-representable float32, so that a
+    bfloat16 run's only errors are the roundings of its results."""
+    return jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16).astype(jnp.float32), tree)
+
+
+def _parts_case(case):
+    B, S, h, w, Cx, Cs, E = 2, 3, 6, 5, 4, 5, 3
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    parts = {
+        "x": jax.random.normal(ks[0], (B * S, h, w, Cx)),
+        "shared": jax.random.normal(ks[1], (B, h, w, Cs)),
+        "const_tail": jax.random.normal(ks[2], (B * S, E)),
+    }
+    if case == "shared_tail":   # the decoder's stem: no per-plane part
+        parts["x"] = None
+    elif case == "x_tail":      # the const-tail path the parent had
+        parts["shared"] = None
+    cot = jax.random.normal(ks[3], (B * S, h, w, 6))
+    return _bf16_exact(parts), _bf16_exact(cot), (B, S, h, w)
+
+
+def _concat_of(parts, dims):
+    """What the reference decoder builds: [x, expand(shared), emb maps]."""
+    B, S, h, w = dims
+    cols = []
+    if parts["x"] is not None:
+        cols.append(parts["x"])
+    if parts["shared"] is not None:
+        s = parts["shared"]
+        cols.append(jnp.broadcast_to(s[:, None], (B, S) + s.shape[1:])
+                    .reshape((B * S,) + s.shape[1:]))
+    t = parts["const_tail"]
+    cols.append(jnp.broadcast_to(t[:, None, None, :], (B * S, h, w,
+                                                       t.shape[-1])))
+    return jnp.concatenate(cols, axis=-1)
+
+
+def _parent_const_tail_conv(params, parts, dims, dt):
+    """The parent's path (its _SplitTailConv): the skip expanded into the
+    conv's input, the tail term and the bias added in `dt` one by one."""
+    kernel, bias = params["conv"]["kernel"], params["conv"]["bias"]
+    E = parts["const_tail"].shape[-1]
+    xin = _concat_of(parts, dims)[..., :-E]
+    xin = jnp.pad(xin, ((0, 0), (1, 1), (1, 1), (0, 0)), mode="reflect")
+    y = jax.lax.conv_general_dilated(
+        xin.astype(dt), kernel[:, :, :-E].astype(dt), (1, 1),
+        ((0, 0), (0, 0)), dimension_numbers=("NHWC", "HWIO", "NHWC"))
+    w_tail = jnp.sum(kernel[:, :, -E:], axis=(0, 1))
+    y = y + (parts["const_tail"].astype(dt)
+             @ w_tail.astype(dt))[:, None, None, :]
+    return y + bias.astype(dt)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ["x_shared_tail", "shared_tail", "x_tail"])
+def test_parts_conv_equals_conv_over_concat(case, dtype):
+    """Conv(x, shared=, const_tail=) is the explicit concat -> reflect pad ->
+    conv from the SAME parameters: forward, and gradients to x, the shared
+    input, the tail, the kernel and the bias."""
+    from mine_tpu.models.layers import Conv
+    dt = jnp.dtype(dtype)
+    parts, cot, dims = _parts_case(case)
+    split = Conv(6, 3, pad_mode="reflect", dtype=dt)
+    plain = Conv(6, 3, pad_mode="reflect")  # float32, over the concat
+    params = _bf16_exact(split.init(jax.random.PRNGKey(3), **parts)["params"])
+    full_in = sum(t.shape[-1] for t in parts.values() if t is not None)
+    assert params["conv"]["kernel"].shape == (3, 3, full_in, 6)
+    live = {k: v for k, v in parts.items() if v is not None}
+
+    def run(fn, in_dtype):
+        """Inputs arrive in the conv's dtype, as the decoder hands them."""
+        def loss(p, live):
+            y = fn(p, {**parts, **live}).astype(jnp.float32)
+            return jnp.sum(y * cot), y
+        (_, y), g = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(
+            params, {k: v.astype(in_dtype) for k, v in live.items()})
+        return {"y": y, "kernel": g[0]["conv"]["kernel"],
+                "bias": g[0]["conv"]["bias"],
+                **{k: v.astype(jnp.float32) for k, v in g[1].items()}}
+
+    want = run(lambda p, q: plain.apply({"params": p}, _concat_of(q, dims)),
+               jnp.float32)
+    got = run(lambda p, q: split.apply({"params": p}, **q), dt)
+
+    def err(a, name):
+        return float(jnp.max(jnp.abs(a[name] - want[name]))
+                     / jnp.max(jnp.abs(want[name])))
+
+    if dtype == "float32":
+        for name in want:
+            assert err(got, name) < 1e-5, (name, err(got, name))
+        return
+    # bfloat16: two roundings (the per-plane conv's result, then the sum)
+    # of at most half a unit in the last place, 2**-8 of the scale, each
+    parent = run(lambda p, q: _parent_const_tail_conv(p, q, dims, dt), dt)
+    for name in want:
+        assert err(got, name) <= 2 * 2.0 ** -8, (name, err(got, name))
+        assert err(got, name) <= 1.05 * err(parent, name) + 1e-6, (
+            name, err(got, name), err(parent, name))
+
+
+# --- what the decoder lowers to, and what its checkpoints hold -------------
+
+def _lowered_convs(text):
+    """(batch, C_in, out height) of every stablehlo.convolution in NHWC x
+    HWIO form in a lowered module's text."""
+    import re
+    pat = re.compile(
+        r"stablehlo\.convolution.*?\[b, 0, 1, f\]x\[0, 1, i, o\]->"
+        r"\[b, 0, 1, f\].*?: \(tensor<(\d+)x\d+x\d+x(\d+)x\w+>, "
+        r"tensor<[^>]+>\) -> tensor<\d+x(\d+)x")
+    return [tuple(int(g) for g in m.groups()) for m in pat.finditer(text)]
+
+
+def test_decoder_convolves_each_skip_once_per_image():
+    """The structural counter of the split: in the lowered forward no
+    convolution at batch B*S reads more than its level's decoder width
+    (the skip and neck channels never ride the B*S batch), and beside the
+    neck's four, exactly five convolutions run at batch B: upconv_4_0's
+    over the neck output and the four upconv_{4..1}_1's over the skips."""
+    from mine_tpu.models.decoder import NUM_CH_DEC
+    B, S, H, W = 2, 4, 64, 96
+    chans = num_ch_enc(50)
+    dec = MPIDecoder(num_ch_enc=chans)
+    feats = [jnp.zeros((B, H // 2 ** (i + 1), W // 2 ** (i + 1), c))
+             for i, c in enumerate(chans)]
+    disp = jnp.full((B, S), 0.5)
+    variables = jax.eval_shape(
+        lambda: dec.init(jax.random.PRNGKey(0), feats, disp, False))
+
+    def fwd(v, feats, disp):
+        return dec.apply(v, feats, disp, True, mutable=["batch_stats"])[0]
+    convs = _lowered_convs(jax.jit(fwd).lower(variables, feats, disp)
+                           .as_text())
+    assert len(convs) == 4 + 5 + 13, convs  # neck, shared, per-plane
+    per_plane = [c for c in convs if c[0] == B * S]
+    assert len(per_plane) == 13  # every upconv but the stem's, 4 dispconvs
+    for _, c_in, h_out in per_plane:
+        level = {H // 2 ** k: k for k in range(5)}[h_out]
+        assert c_in <= 2 * NUM_CH_DEC[level] and c_in <= 256, (c_in, h_out)
+    at_b = sorted((c_in, h_out) for n, c_in, h_out in convs if n == B)
+    neck = sorted([(2048, 1), (512, 1), (256, 2), (256, 4)])
+    shared = sorted([(2048, 2), (1024, 4), (512, 8), (256, 16), (64, 32)])
+    assert at_b == sorted(neck + shared), at_b
+
+
+def test_predictor_parameter_tree_is_the_parents():
+    """Paths, shapes and dtypes of MPIPredictor(num_layers=50)'s variables
+    are, entry for entry, what the commit before the split conv created
+    (tests/mpi_predictor_r50_tree.json, written from that commit): its
+    checkpoints and converted reference checkpoints load unchanged."""
+    import json
+    import os
+    model = MPIPredictor(num_layers=50)
+    variables = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 64, 64, 3)),
+                           jnp.ones((1, 2)), train=False))
+    flat = jax.tree_util.tree_flatten_with_path(variables)[0]
+    got = [["/".join(k.key for k in path), list(leaf.shape), str(leaf.dtype)]
+           for path, leaf in flat]
+    with open(os.path.join(os.path.dirname(__file__),
+                           "mpi_predictor_r50_tree.json")) as f:
+        assert got == json.load(f)

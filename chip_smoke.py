@@ -40,7 +40,7 @@ SEED = 0
 TRAIN_EXTRA = {"data.name": "synthetic", "training.epochs": 4,
                "training.log_interval": 1, "training.seed": SEED}
 N_IMAGES = 2            # distinct images; the first is requested twice
-PALLAS_BACKENDS = ("pallas", "pallas_diff", "pallas_sep", "pallas_fused")
+PALLAS_BACKENDS = ("pallas", "pallas_diff")
 KERNEL_CALL = "tpu_custom_call"   # what a compiled Pallas kernel lowers to
 TIMEOUT_S = {"train": 700, "serve": 420, "mesh": 900, "one_chip": 900}
 # --chips 4: first-step loss of the 2x2 mesh against one chip, same seed

@@ -16,7 +16,7 @@ framework with checked-in baselines and a loud CI gate
                 acquisition order for the serve/telemetry threads, plus
                 thread-leak helpers (stdlib-only; no jax, no mine_tpu)
   programs.py   the registry of core jitted programs at canonical CPU
-                shapes (train step, fused loss fwd/bwd, five warp
+                shapes (train step, fused loss fwd/bwd, two warp
                 backends, serve render single-device + mesh, eval_encode)
   framework.py  AuditPass / PassResult / run_audit + baseline file IO
                 (tools/analysis_baseline.json)

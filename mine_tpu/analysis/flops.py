@@ -1,8 +1,7 @@
 """Jaxpr walkers for contraction budgets: dot counts, dot FLOPs, blur dots.
 
-These used to live as private helpers inside tests/test_fused_loss.py and
-tests/test_warp_separable.py; the FLOP-budget pass (passes.py) and those
-tests now share this single implementation, and the numeric gates live in
+The FLOP-budget pass (passes.py) and tests/test_fused_loss.py share this
+single implementation; the numeric gates live in
 tools/analysis_baseline.json instead of inline test constants.
 
 All walkers recurse into sub-jaxprs found in eqn params (pjit bodies, cond

@@ -1,7 +1,7 @@
 """Registry of the core jitted programs at canonical (tiny, CPU) shapes.
 
 Every pass in passes.py runs over these Programs: the train step, the fused
-loss forward and backward, all five warp backends, the serve render engine
+loss forward and backward, the two warp backends, the serve render engine
 (single-device and mesh), and the eval encode. Shapes are the smallest ones
 that exercise the real program structure (the same 64x64 / 4-plane /
 resnet18 family the test suite's tiny_setup uses), so the full audit gate
@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from mine_tpu.analysis import dtype as _dtype
+from mine_tpu.config import SERVE_WARP_BACKENDS
 
 # canonical tiny-trainer shape (tools/dtype_audit.py --small): 64x64,
 # 4 coarse planes, resnet18, batch 1
@@ -40,8 +41,8 @@ TINY = dict(height=64, width=64, planes=4, layers=18, batch=1)
 # P poses. S=2 divides the mesh "model" axis; H=W=16 keeps compiles sub-s.
 SERVE = dict(R=1, S=2, H=16, W=16, P=2)
 
-WARP_IMPLS = ("xla", "xla_banded", "separable", "pallas_diff", "pallas_sep",
-              "pallas_fused")
+# one warp program for each backend a config may name
+WARP_IMPLS = SERVE_WARP_BACKENDS
 
 
 @dataclasses.dataclass
@@ -342,14 +343,11 @@ def _serve_scene(quant: str):
 
 def serve_render_program(quant: str = "bf16",
                          mesh: Optional[Tuple[int, int]] = None,
-                         name: Optional[str] = None,
-                         warp_impl: str = "xla") -> Program:
+                         name: Optional[str] = None) -> Program:
     """Build the serve render Program for one cache quant mode ("float32",
-    "bf16", "int8"), optionally over a (mesh_batch, mesh_model) CPU mesh,
-    with the given warp backend ("pallas_fused" audits the render
-    megakernel reading the quantized cache in-kernel). Exposed so tests can
-    sweep quant modes; the registry registers the default-quant
-    single-device and 2x2 mesh variants plus the fused int8 program."""
+    "bf16", "int8"), optionally over a (mesh_batch, mesh_model) CPU mesh.
+    Exposed so tests can sweep quant modes; the registry registers the
+    default-quant single-device and 2x2 mesh variants."""
     from mine_tpu import geometry
     from mine_tpu.serve.engine import RenderEngine
     from mine_tpu.serve.shardmap import MeshRenderEngine
@@ -365,15 +363,12 @@ def serve_render_program(quant: str = "bf16",
         out_shardings = engine._shardings["out"]
         name = name or f"serve_render_mesh[{quant},{mesh[0]}x{mesh[1]}]"
         tags = ("serve", "mesh")
-    if warp_impl.startswith("pallas"):
-        tags += ("pallas",)
-
     planes, scales, disp, K, idx, G = _serve_scene(quant)
     K_inv = np.asarray(geometry.inverse_intrinsics(jnp.asarray(K)))
 
     def render(planes, scales, disp, K, K_inv, idx, G):
         return engine._render_impl(planes, scales, disp, K, K_inv, idx, G,
-                                   warp_impl)
+                                   "xla")
 
     jit_fn = (jax.jit(render) if out_shardings is None else
               jax.jit(render, out_shardings=(out_shardings, out_shardings)))
@@ -420,12 +415,6 @@ _register("serve_render",
 _register("serve_render_mesh",
           functools.partial(serve_render_program, "bf16", (2, 2),
                             "serve_render_mesh"))
-# the fused megakernel serving the int8 cache: the quantized planes cross
-# into the kernel (in-register dequant) — dot_budget pins the one-kernel
-# structure (a deliberately unfused build trips it, tests/test_analysis)
-_register("serve_render_fused",
-          functools.partial(serve_render_program, "int8", None,
-                            "serve_render_fused", "pallas_fused"))
 _register("eval_encode", _build_eval_encode)
 # the staged train step's four sub-programs (parallel/pipeline.py): their
 # cost rows are the planner's input (tools/pipeline_plan.py) and their dot

@@ -18,6 +18,12 @@ import yaml
 # Directory with our shipped configs (same key space as reference configs/).
 CONFIG_DIR = os.path.join(os.path.dirname(__file__), "configs")
 
+# What a config may name as the homography warp (ops/warp.homography_warp):
+# "xla" = the gather, "pallas_diff" = the banded MXU kernels fwd+bwd with a
+# runtime gather fallback (kernels/warp_vjp.py). "auto" picks by platform.
+TRAINING_WARP_BACKENDS = ("auto", "xla", "pallas_diff")
+SERVE_WARP_BACKENDS = ("xla", "pallas_diff")
+
 
 def load_config(config_path: str,
                 extra_config: Optional[str] = None,
@@ -109,17 +115,7 @@ class MPIConfig:
     # platform); the shipped YAML default is "auto", resolved by
     # mpi_config_from_dict to pallas_diff on TPU / xla elsewhere
     composite_backend: str = "xla"
-    # "xla" | "xla_banded" | "pallas_diff" | "separable" | "pallas_sep" |
-    # "pallas_fused": training-path homography warp ("xla_banded" = banded
-    # one-hot-matmul in pure XLA, ops/warp_banded.py; "pallas_diff" =
-    # banded MXU kernel fwd+bwd, kernels/warp_vjp.py; "separable" =
-    # row-then-column 1D one-hot matmuls in pure XLA,
-    # ops/warp_separable.py; "pallas_sep" = Pallas fwd+bwd pair of the
-    # separable form, kernels/warp_sep.py; "pallas_fused" = the
-    # warp+dequant+composite render megakernel, kernels/render_fused.py —
-    # in the render path it replaces the composite backend too; all five
-    # guarded backends carry a runtime gather fallback for out-of-domain
-    # poses)
+    # a resolved TRAINING_WARP_BACKENDS value: training-path homography warp
     warp_backend: str = "xla"
     # fwd AND bwd band: since the round-4 transposed-splat backward the
     # Pallas VJP mirrors the forward's band placement, so one knob covers
@@ -128,15 +124,10 @@ class MPIConfig:
     # the transposed form has no such constraint)
     warp_band: int = 48
     # warp value dtype ("float32" | "bfloat16"): matmul operands in the
-    # banded backends (bf16 doubles MXU rate) AND gather storage on the
-    # default xla backend (bf16 halves the volume's HBM traffic); either
+    # pallas_diff kernels (bf16 doubles MXU rate) AND gather storage on the
+    # xla backend (bf16 halves the volume's HBM traffic); either
     # way ~2^-8 relative value rounding, accumulation/lerp stays f32
     warp_dtype: str = "float32"
-    # separable backends only: max admitted per-row anchor deviation in
-    # source rows (value error is bounded by sep_tol * the image's vertical
-    # Lipschitz constant; ops/warp_separable.py docstring). Poses above it
-    # take the runtime gather fallback.
-    warp_sep_tol: float = 0.5
     # SSIM Toeplitz-einsum matmul precision ("highest" | "default"):
     # "highest" forces f32 MXU passes for the 11x11 Gaussian blur —
     # matches the reference's conv2d numerics exactly; "default" lets the
@@ -362,12 +353,8 @@ class ServeConfig:
     # serve.session.keyframe_tier: priority of keyframe encodes (default
     # critical — under admission pressure interpolation sheds first)
     session_keyframe_tier: int = 2
-    # serve.warp_backend: warp/render backend of the serving engine (same
-    # value space as training.warp_backend minus "auto"); "pallas_fused"
-    # selects the one-pass render megakernel (kernels/render_fused.py) —
-    # the engine skips the pre-dequant and the kernel reads the quantized
-    # cache directly. "xla" (default) is byte-identical to the
-    # pre-megakernel engine.
+    # serve.warp_backend: warp backend of the serving engine, one of
+    # SERVE_WARP_BACKENDS
     warp_backend: str = "xla"
     # serve.ring.*: multi-host elastic ring (serve/ring.py, serve/hostnet.py)
     # — a front tier routes requests by content-hash key range to owner
@@ -530,11 +517,10 @@ def serve_config_from_dict(config: Dict[str, Any]) -> ServeConfig:
         raise ValueError(
             f"serve.scheduler must be continuous|micro, "
             f"got {out.scheduler!r}")
-    if out.warp_backend not in ("xla", "xla_banded", "pallas_diff",
-                                "separable", "pallas_sep", "pallas_fused"):
+    if out.warp_backend not in SERVE_WARP_BACKENDS:
         raise ValueError(
-            f"serve.warp_backend must be xla|xla_banded|pallas_diff|"
-            f"separable|pallas_sep|pallas_fused, got {out.warp_backend!r}")
+            f"serve.warp_backend must be {'|'.join(SERVE_WARP_BACKENDS)}, "
+            f"got {out.warp_backend!r}")
     if not 0 <= out.ops_port <= 65535:
         raise ValueError(
             f"serve.ops_port must be in [0, 65535], got {out.ops_port}")
@@ -834,10 +820,9 @@ def validate_model_shapes(cfg: "MPIConfig") -> None:
 
 
 def _resolve_auto_backend(value: str) -> str:
-    """"auto" -> the measured-best backend for the RUNNING platform: the
-    Pallas custom-VJP pair on TPU (13.4x the gather path on v5e, round-4
-    measurement), plain XLA elsewhere (on CPU the Pallas kernels would run
-    in interpret mode — orders of magnitude slower than XLA)."""
+    """"auto" -> the backend for the RUNNING platform: the Pallas
+    custom-VJP pair on TPU, plain XLA elsewhere (on CPU the Pallas kernels
+    would run in interpret mode — orders of magnitude slower than XLA)."""
     if value != "auto":
         return value
     from mine_tpu.kernels import on_tpu_backend
@@ -855,16 +840,12 @@ def mpi_config_from_dict(config: Dict[str, Any]) -> MPIConfig:
         raise ValueError(
             f"training.composite_backend must be auto|xla|pallas_diff|"
             f"plane_scan, got {backend!r}")
-    warp_backend = _resolve_auto_backend(g("training.warp_backend", "auto"))
-    if warp_backend not in ("xla", "xla_banded", "pallas_diff",
-                            "separable", "pallas_sep", "pallas_fused"):
+    warp_backend = g("training.warp_backend", "auto")
+    if warp_backend not in TRAINING_WARP_BACKENDS:
         raise ValueError(
-            f"training.warp_backend must be auto|xla|xla_banded|pallas_diff|"
-            f"separable|pallas_sep|pallas_fused, got {warp_backend!r}")
-    warp_sep_tol = float(g("training.warp_sep_tol", 0.5))
-    if warp_sep_tol < 0.0:
-        raise ValueError(
-            f"training.warp_sep_tol must be >= 0, got {warp_sep_tol!r}")
+            f"training.warp_backend must be "
+            f"{'|'.join(TRAINING_WARP_BACKENDS)}, got {warp_backend!r}")
+    warp_backend = _resolve_auto_backend(warp_backend)
     warp_dtype = g("training.warp_dtype", "float32")
     if warp_dtype not in ("float32", "bfloat16"):
         raise ValueError(
@@ -897,7 +878,6 @@ def mpi_config_from_dict(config: Dict[str, Any]) -> MPIConfig:
         warp_backend=warp_backend,
         warp_band=int(g("training.warp_band", 48)),
         warp_dtype=warp_dtype,
-        warp_sep_tol=warp_sep_tol,
         ssim_precision=ssim_precision,
         # visible_point_count == 0 also disables the sparse-point terms —
         # datasets with no SfM points (public RealEstate10K) train scale-free

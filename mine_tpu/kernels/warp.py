@@ -128,9 +128,9 @@ def _warp_kernel(C: int, BAND: int, SUB: int, RT: int, TILE: int, KW: int,
     def band_dma(step, slot):
         # y0 comes in as the FULL [B', NB] table in SMEM (a (1,1) block
         # would violate the Mosaic last-two-dims tiling rule); index it by
-        # grid step. band_start aligns it to the sublane tile; multiple_of
-        # carries that fact to Mosaic, which must PROVE dynamic HBM slice
-        # offsets aligned. src arrives as the FULL array in HBM (ANY-space
+        # grid step. _aligned_band_start aligns it to the sublane tile;
+        # multiple_of carries that fact to Mosaic, which must PROVE dynamic
+        # HBM slice offsets aligned. src arrives as the FULL array in HBM (ANY-space
         # blocks must equal the array shape); the batch index is applied
         # here, the band via dynamic DMA
         b, nb = jax.lax.div(step, NB), jax.lax.rem(step, NB)
@@ -240,7 +240,7 @@ def pallas_bilinear_sample(src: jnp.ndarray,
     NB = H_t // RT
     xc, yc, band, pad_h, pad_w, y0, plan, _ = band_plan(
         src.shape, coords_x, coords_y, band, RT)
-    # Pad the SOURCE to the Mosaic geometry (mosaic_band_geometry
+    # Pad the SOURCE to the Mosaic geometry (_mosaic_band_geometry
     # docstring): padded columns get exactly-zero tent weights (xc is
     # clipped to the true W_s-1, so |xs - sx| >= 1 there), and padded rows
     # likewise sit >= 1 row beyond the yc clip range — numerics unchanged.
@@ -303,9 +303,9 @@ def _align_slack(window: int, extent: int) -> int:
     return 0 if window >= extent else SUBLANE_ALIGN - 1
 
 
-def mosaic_band_geometry(band: int, extent: int, lane_extent: int):
-    """THE Mosaic alignment recipe, shared by the forward wrapper and the
-    VJP's backward wrapper so their domains can never desynchronize:
+def _mosaic_band_geometry(band: int, extent: int, lane_extent: int):
+    """THE Mosaic alignment recipe (band_plan hands it to the forward and
+    the backward wrapper alike, so their domains can never desynchronize):
 
       * ceil the band to the sublane tile (slice SIZE must be aligned),
       * pad the banded (row) extent so the band-start clip bound
@@ -322,36 +322,22 @@ def mosaic_band_geometry(band: int, extent: int, lane_extent: int):
     return band, pad_rows, pad_lanes
 
 
-def band_start(coords_y_clipped: jnp.ndarray, H_s: int, band: int,
-               rows_per_block: int = 8) -> jnp.ndarray:
-    """Band start row per (plane, row-block): floor of the block's min
-    source row, clipped so the band stays inside the image. [B', NB] i32.
-
-    THE band placement rule — shared by the Pallas forward kernel and the
-    pure-XLA banded warp. The Pallas wrapper additionally sublane-aligns
-    the result (after padding H so the clip bound is itself aligned); the
-    XLA path needs no alignment. Both compute exact bilinear values inside
-    their band, so the backends agree wherever the shared domain guard
-    (fwd_domain_ok, which budgets the Pallas alignment slack) passes.
-    """
+def _aligned_band_start(coords_y_clipped: jnp.ndarray, H_pad: int, band: int,
+                        rows_per_block: int = 8) -> jnp.ndarray:
+    """Band start row per (plane, row-block), [B', NB] i32: floor of the
+    block's min source row, clipped so the band stays inside the (padded)
+    image, then floored to the sublane tile — the kernels' DMA and VMEM
+    slice starts (Mosaic must prove divisibility; see pl.multiple_of in the
+    kernels). The floor only moves the start UP the image — ≤7 rows of
+    headroom, accounted by fwd_domain_ok's slack — and the clip bound
+    (H_pad - band, _mosaic_band_geometry) is itself aligned, so the bottom
+    of the image stays covered. Band placement does not change values as
+    long as every needed source row stays in-band."""
     Bp, H_t, W_t = coords_y_clipped.shape
     NB = H_t // rows_per_block
     y_blocks = coords_y_clipped.reshape(Bp, NB, rows_per_block * W_t)
     y0 = jnp.floor(jnp.min(y_blocks, axis=2)).astype(jnp.int32)
-    return jnp.clip(y0, 0, max(H_s - band, 0))
-
-
-def aligned_band_start(coords_y_clipped: jnp.ndarray, H_pad: int, band: int,
-                       rows_per_block: int = 8) -> jnp.ndarray:
-    """band_start, floored to the sublane tile: the Pallas kernels' DMA and
-    VMEM slice starts (Mosaic must prove divisibility; see pl.multiple_of in
-    the kernels). Floor only moves the start UP the image — ≤7 rows of
-    headroom, accounted by fwd_domain_ok's slack — and the clip bound
-    (H_pad - band, mosaic_band_geometry) is itself aligned, so the bottom of
-    the image stays covered. The XLA banded backend keeps the unaligned
-    band_start (no Mosaic constraint); values agree wherever both bands
-    cover, which the shared domain guard guarantees."""
-    y0 = band_start(coords_y_clipped, H_pad, band, rows_per_block)
+    y0 = jnp.clip(y0, 0, max(H_pad - band, 0))
     return (y0 // SUBLANE_ALIGN) * SUBLANE_ALIGN
 
 
@@ -390,7 +376,7 @@ def subband_plan(xc: jnp.ndarray, yc: jnp.ndarray, y0: jnp.ndarray,
 
     xc, yc: border-clipped coords [B', H_t, W_t]; y0: the blocks' aligned
     band starts [B', NB]; band, W_src: the kernel's band rows and source
-    columns (after mosaic_band_geometry). Returns
+    columns (after _mosaic_band_geometry). Returns
 
       plan [B' * NB, 1, 3 * U + 1] int32, U = units a block: per block the
         units' sub-band starts (band-relative, sublane-aligned),
@@ -453,8 +439,8 @@ def band_plan(src_shape, coords_x: jnp.ndarray, coords_y: jnp.ndarray,
     # Mosaic constraints (hit on silicon, round-4 window): HBM slices of
     # the (8,128)-tiled source must have 128-aligned lane width AND
     # 8-aligned sublane offset/size.
-    band, pad_h, pad_w = mosaic_band_geometry(band, H_s, W_s)
-    y0 = aligned_band_start(yc, H_s + pad_h, band, rows_per_block)
+    band, pad_h, pad_w = _mosaic_band_geometry(band, H_s, W_s)
+    y0 = _aligned_band_start(yc, H_s + pad_h, band, rows_per_block)
     plan, fits = subband_plan(xc, yc, y0, band, W_s + pad_w, rows_per_block,
                               unit_rows)
     return xc, yc, band, pad_h, pad_w, y0, plan, fits
@@ -474,25 +460,17 @@ def subband_frac(src_shape, coords_x: jnp.ndarray, coords_y: jnp.ndarray,
 
 
 def fwd_domain_ok(coords_y: jnp.ndarray, H_s: int, band: int,
-                  rows_per_block: int = 8,
-                  aligned: bool = True) -> jnp.ndarray:
+                  rows_per_block: int = 8) -> jnp.ndarray:
     """Scalar bool (jit-safe): every row-block's source span fits the band.
 
-    THE definition of the banded forward's correctness domain (span + 2
-    rows of bilinear support + the sublane-alignment slack must fit the
-    band, clamped to the image) — shared by the Pallas VJP guard
-    (kernels/warp_vjp.py) and the pure-XLA banded warp (ops/warp_banded.py)
-    so the two backends can never diverge on which poses count as in-band.
+    THE definition of the banded pair's correctness domain (span + 2 rows
+    of bilinear support + the sublane-alignment slack must fit the band,
+    clamped to the image); the VJP guard (kernels/warp_vjp.py) is this.
     coords_y must be border-clipped.
-
-    `aligned=False` drops the sublane-alignment slack from the budget: the
-    pure-XLA banded path keeps unaligned band starts (band_start docstring),
-    so it covers poses within SUBLANE_ALIGN-1 rows of the band limit that
-    the Pallas wrapper must send to the fallback.
     """
     eff = min(band, H_s)
-    slack = _align_slack(eff, H_s) if aligned else 0
-    return band_span(coords_y, H_s, rows_per_block) + 2.0 <= eff - slack
+    return (band_span(coords_y, H_s, rows_per_block) + 2.0
+            <= eff - _align_slack(eff, H_s))
 
 
 def band_span(coords_y: jnp.ndarray, H_s: int,

@@ -324,10 +324,9 @@ def bilinear_sample_diff_guarded(src, coords_x, coords_y,
 
     # the gather fallback honors the same reduced-precision knob as the
     # kernel (mxu_dtype) via the f32-accumulating gather path, so fallback
-    # steps keep the HBM-traffic benefit (parity with ops/warp_banded.py's
-    # guard). Under the cond it is ALWAYS the custom-VJP form, float32
-    # included: its residuals are the coordinates, as the kernel branch's
-    # are. The autodiffed gather would carry its four [B',H,W,2] int32 index
+    # steps keep the HBM-traffic benefit. Under the cond it is ALWAYS the
+    # custom-VJP form, float32 included: its residuals are the coordinates,
+    # as the kernel branch's are. The autodiffed gather would carry its four [B',H,W,2] int32 index
     # arrays out of the cond as residuals, and XLA is free to lay those out
     # with the 2 on the lane axis (64x padding: 6 GB each at 64x384x512,
     # hit when the plan table joined the kernel branch). Coordinates get

@@ -131,86 +131,6 @@ def _warn_backend_fallback(backend: str, why: str) -> None:
             f"composite backend {backend!r} falling back to 'xla': {why}")
 
 
-def _render_fused(mpi_rgb_src, mpi_sigma_src, planes_q, planes_scales,
-                  mpi_depth_src, xyz_tgt_BS3HW, G_tgt_src, K_src_inv, K_tgt,
-                  is_bg_depth_inf, warp_band, mesh) -> "TgtRender":
-    """warp_impl="pallas_fused": the warp -> dequant -> composite -> blend
-    megakernel (kernels/render_fused.py). Never materializes the 7-channel
-    float volume — the planes enter the kernel in CACHE form (planes_q) or
-    as the predictor's float rgb+sigma, and only the composited rgb/depth
-    come back. Guarded the house way: out-of-band poses take the XLA
-    dequant+gather+composite inside the kernel's lax.cond, reported through
-    warp_in_domain like every guarded backend."""
-    from mine_tpu.kernels import on_tpu_backend
-    from mine_tpu.kernels import render_fused as rf
-
-    if planes_q is not None:
-        vol4, scales = planes_q, planes_scales
-    else:
-        # training path: the predictor's float planes, no dequant step
-        vol4 = jnp.concatenate([mpi_rgb_src, mpi_sigma_src], axis=2)
-        scales = None
-    B, S, _, H, W = vol4.shape
-
-    grid = geometry.cached_pixel_grid(H, W)
-
-    def expand(x):
-        return jnp.repeat(x, S, axis=0)
-
-    x, y, valid = warp.warp_coords(
-        mpi_depth_src.reshape(B * S), expand(G_tgt_src), expand(K_src_inv),
-        expand(K_tgt), grid, (H, W))
-    xs = jax.lax.stop_gradient(x).reshape(B, S, H, W)
-    ys = jax.lax.stop_gradient(y).reshape(B, S, H, W)
-    xyz = xyz_tgt_BS3HW.astype(jnp.float32)
-
-    rpb = next(r for r in (8, 4, 2, 1) if H % r == 0)
-    interp = not on_tpu_backend()
-
-    def call(v, sc, xz, cx, cy):
-        return rf.fused_plane_render_guarded(
-            v, sc, xz, cx, cy, band=warp_band, rows_per_block=rpb,
-            is_bg_depth_inf=is_bg_depth_inf, interpret=interp)
-
-    if mesh is not None and mesh.size > 1:
-        # GSPMD meshes: batch over the mesh's leading axis — "data" on the
-        # training mesh, "batch" on the serve mesh — with the plane axis
-        # local to each device (the transparency chain reduces over S).
-        batch_axis = mesh.axis_names[0]
-        if B % mesh.shape[batch_axis] == 0:
-            from jax.sharding import PartitionSpec as P
-
-            from mine_tpu.parallel.mesh import shard_map
-
-            def sharded(v, sc, xz, cx, cy):
-                rgb, depth, ok = call(v, sc, xz, cx, cy)
-                # per-shard cond, pmean'd to the fraction on the fast path
-                okf = ok.astype(jnp.float32)
-                for ax in mesh.axis_names:
-                    okf = jax.lax.pmean(okf, ax)
-                return rgb, depth, okf
-
-            spec = P(batch_axis)
-            fn = shard_map(sharded, mesh=mesh,
-                           in_specs=(spec, spec, spec, spec, spec),
-                           out_specs=(spec, spec, P()))
-            rgb_syn, depth_syn, in_domain = fn(vol4, scales, xyz, xs, ys)
-        else:
-            _warn_backend_fallback(
-                "pallas_fused", "batch not divisible by the mesh batch axis")
-            rgb_syn, depth_syn = rf.xla_reference_render(
-                vol4, scales, xyz, xs, ys, is_bg_depth_inf)
-            in_domain = jnp.zeros((), jnp.float32)
-    else:
-        rgb_syn, depth_syn, ok = call(vol4, scales, xyz, xs, ys)
-        in_domain = ok.astype(jnp.float32)
-
-    mask = jnp.sum(valid.reshape(B, S, H, W).astype(jnp.float32),
-                   axis=1, keepdims=True)
-    return TgtRender(rgb=rgb_syn, depth=depth_syn, mask=mask,
-                     warp_in_domain=in_domain)
-
-
 class TgtRender(NamedTuple):
     rgb: jnp.ndarray    # [B,3,H,W]
     depth: jnp.ndarray  # [B,1,H,W]
@@ -238,10 +158,7 @@ def render_tgt_rgb_depth(mpi_rgb_src: jnp.ndarray,
                          warp_impl: str = "xla",
                          warp_band: int = 16,
                          warp_dtype: str = "float32",
-                         warp_sep_tol: float = 0.5,
-                         mesh=None,
-                         planes_q: jnp.ndarray = None,
-                         planes_scales: jnp.ndarray = None) -> TgtRender:
+                         mesh=None) -> TgtRender:
     """Render the MPI into a target camera.
 
     Concatenates [rgb, sigma, xyz_tgt] into a 7-channel plane volume, warps all
@@ -257,42 +174,10 @@ def render_tgt_rgb_depth(mpi_rgb_src: jnp.ndarray,
       mesh: ("data","plane") Mesh — on multi-device meshes the Pallas
         backends run under shard_map (warp: B*S split over data*plane;
         composite: batch over "data" with the plane axis gathered locally,
-        since the transparency chain reduces over S). warp_impl=
-        "pallas_fused" accepts the serve ("batch","model") mesh too —
-        it shards over whichever axis is first.
-      planes_q: warp_impl="pallas_fused" only — the [B,S,4,H,W] rgb+sigma
-        planes in CACHE form (float32/bfloat16/int8). The serve engine
-        passes its quantized cache slice here INSTEAD of pre-dequantizing;
-        the megakernel widens/dequantizes in registers. When given,
-        mpi_rgb_src/mpi_sigma_src are shape/dtype carriers only.
-      planes_scales: [B,S,4,1,1] f32 int8 dequant scales (None for
-        float32/bfloat16 caches — the cast is exact, no multiply runs).
-
-    With warp_impl="pallas_fused" (and sigma mode) the `backend` composite
-    arg is bypassed entirely: warp, dequant, z-mask, composite and blend
-    are one Pallas program (kernels/render_fused.py) and the 7-channel
-    float volume is never materialized.
+        since the transparency chain reduces over S).
     """
     B, S, _, H, W = mpi_rgb_src.shape
     mpi_depth_src = 1.0 / mpi_disparity_src  # [B,S]
-
-    if warp_impl == "pallas_fused" and use_alpha:
-        # the megakernel implements the sigma-density composite only
-        _warn_backend_fallback("pallas_fused", "mpi.use_alpha uses the XLA "
-                               "alpha-compositing path")
-        if planes_q is not None:
-            xq = planes_q.astype(jnp.float32)
-            if planes_scales is not None:
-                xq = xq * planes_scales
-            mpi_rgb_src, mpi_sigma_src = xq[:, :, 0:3], xq[:, :, 3:4]
-            planes_q = planes_scales = None
-        warp_impl = "xla"
-
-    if warp_impl == "pallas_fused":
-        return _render_fused(mpi_rgb_src, mpi_sigma_src, planes_q,
-                             planes_scales, mpi_depth_src, xyz_tgt_BS3HW,
-                             G_tgt_src, K_src_inv, K_tgt, is_bg_depth_inf,
-                             warp_band, mesh)
 
     volume = jnp.concatenate([mpi_rgb_src, mpi_sigma_src, xyz_tgt_BS3HW], axis=2)
     volume_bs = volume.reshape(B * S, 7, H, W)
@@ -313,7 +198,6 @@ def render_tgt_rgb_depth(mpi_rgb_src: jnp.ndarray,
         mesh=mesh,
         mxu_dtype=jnp.bfloat16 if warp_dtype == "bfloat16" else jnp.float32,
         with_domain_flag=True,
-        sep_tol=warp_sep_tol,
         with_subband_frac=True,
     )
 

@@ -18,8 +18,6 @@ coordinates with border clamping. We implement that directly, skipping the
 from __future__ import annotations
 
 import functools
-from typing import Tuple
-
 import jax
 import jax.numpy as jnp
 
@@ -122,36 +120,8 @@ def _bsc_bwd(gather_dtype, residuals, g):
 _bilinear_sample_cast.defvjp(_bsc_fwd, _bsc_bwd)
 
 
-def warp_coords(d_src: jnp.ndarray,
-                G_tgt_src: jnp.ndarray,
-                K_src_inv: jnp.ndarray,
-                K_tgt: jnp.ndarray,
-                meshgrid_tgt: jnp.ndarray,
-                src_hw: Tuple[int, int]):
-    """Source-pixel sampling coords for the inverse-homography warp.
-
-    The shared front half of `homography_warp`, factored out so the fused
-    render path (ops/rendering.py warp_impl="pallas_fused") computes coords
-    through the SAME ops as every other backend — one graph, one rounding
-    behavior.
-
-    Args: as homography_warp; src_hw = (H, W) of the source planes.
-    Returns: (x [B',Ht,Wt], y [B',Ht,Wt], valid [B',Ht,Wt] bool)
-    """
-    H, W = src_hw
-    Bp = d_src.shape[0]
-    _, Ht, Wt = meshgrid_tgt.shape
-    H_tgt_src = geometry.homography_tgt_src(K_tgt, K_src_inv, G_tgt_src, d_src)
-    H_src_tgt = jax.lax.stop_gradient(geometry.inverse_3x3(H_tgt_src))
-
-    grid = meshgrid_tgt.reshape(3, Ht * Wt)
-    src_homo = jnp.einsum("bij,jn->bin", H_src_tgt, grid)  # [B',3,HtWt]
-    src_xy = src_homo[:, 0:2, :] / src_homo[:, 2:3, :]
-    x = src_xy[:, 0, :].reshape(Bp, Ht, Wt)
-    y = src_xy[:, 1, :].reshape(Bp, Ht, Wt)
-
-    valid = ((x > -1.0) & (x < float(W)) & (y > -1.0) & (y < float(H)))
-    return x, y, valid
+# every implementation homography_warp can run, the one place they are named
+WARP_IMPLS = ("xla", "pallas", "pallas_diff")
 
 
 def homography_warp(src_BCHW: jnp.ndarray,
@@ -165,7 +135,6 @@ def homography_warp(src_BCHW: jnp.ndarray,
                     mesh=None,
                     mxu_dtype=jnp.float32,
                     with_domain_flag: bool = False,
-                    sep_tol: float = 0.5,
                     with_subband_frac: bool = False):
     """Warp source-plane images into the target camera via inverse homography.
 
@@ -183,58 +152,56 @@ def homography_warp(src_BCHW: jnp.ndarray,
       G_tgt_src: [B', 4, 4]
       K_src_inv, K_tgt: [B', 3, 3]
       meshgrid_tgt: [3, Ht, Wt] homogeneous target pixel grid
-      impl: "xla" (gather; autodiffed), "xla_banded" (banded one-hot-matmul
-        in pure XLA with a runtime gather fallback — autodiffed, trainable,
-        GSPMD-partitionable; ops/warp_banded.py), "separable" (row-then-
-        column 1D one-hot matmuls in pure XLA — ~(band+W)/(band*W) the
-        banded dot FLOPs, anchor-banded so the guard drops the within-row
-        span term; autodiffed, GSPMD-partitionable; ops/warp_separable.py),
-        "pallas" (banded MXU gather kernel, forward-only; caller must
-        validate the band via kernels.warp.band_span), "pallas_diff"
-        (banded fwd+bwd kernels with a built-in runtime gather fallback —
-        the Pallas training backend), "pallas_sep" (Pallas fwd+bwd pair
-        of the separable form; kernels/warp_sep.py), or "pallas_fused"
-        (under THIS warp-only contract: identical to pallas_diff; inside
-        render_tgt_rgb_depth it selects the warp+dequant+composite
-        megakernel, kernels/render_fused.py)
-      mesh: ("data","plane") jax Mesh. With impl="pallas_diff"/"pallas_sep"
-        on a multi-device mesh the kernel runs under shard_map with the
+      impl: one of WARP_IMPLS. "xla" (gather; autodiffed; the reference and
+        the guarded fallback), "pallas" (banded MXU gather kernel,
+        forward-only; caller must validate the band via
+        kernels.warp.band_span), or "pallas_diff" (banded fwd+bwd kernels
+        with a built-in runtime gather fallback — the Pallas training
+        backend). Anything else raises ValueError.
+      mesh: ("data","plane") jax Mesh. With impl="pallas_diff" on a
+        multi-device mesh the kernel runs under shard_map with the
         flat B' axis split over data*plane (matching the decoder's B*S
         layout, models/decoder.py shard_bs) — each device warps its local
         planes, no cross-device traffic.
       with_domain_flag: also return `in_domain`, a scalar f32 diagnostic —
-        the FRACTION of this call that took the guarded banded backends'
-        (pallas_diff / pallas_sep / xla_banded / separable) fast path:
+        the FRACTION of this call that took pallas_diff's fast path:
         1.0 all-fast, 0.0 all on the runtime gather fallback, NaN for
         backends with no guard (plain xla / forward-only pallas). Under a
         sharded Pallas mesh the cond decides per shard, and the flag is
         the pmean of the per-shard guards over data*plane — e.g. 0.75 when
-        one of four shards drew an out-of-band pose (the pre-r6
-        global-coords flag reported 0.0 for that step). Powers the
-        `warp_fallback_frac` training metric (VERDICT r4 weak item 5).
-      sep_tol: separable backends only (training.warp_sep_tol) — max
-        admitted per-row anchor deviation in source rows; poses above it
-        take the gather fallback (ops/warp_separable.py error bound).
+        one of four shards drew an out-of-band pose. Powers the
+        `warp_fallback_frac` training metric.
       with_subband_frac: also return `subband_frac`, a scalar f32 — the
-        share of this call's (output row, lane tile) units that the banded
-        Pallas training kernels (pallas_diff / pallas_fused) contracted
-        against their window alone (kernels/warp.subband_frac), 0.0 for
-        the part of the call on the gather fallback, NaN for the other
-        backends (the forward-only `pallas` among them: serving reads no
-        metric, and every render program would pay the plan's trace a
-        second time). Sharded like `in_domain`. Powers `warp_subband_frac`.
+        share of this call's (output row, lane tile) units that the
+        pallas_diff kernels contracted against their window alone
+        (kernels/warp.subband_frac), 0.0 for the part of the call on the
+        gather fallback, NaN for the other backends (the forward-only
+        `pallas` among them: serving reads no metric, and every render
+        program would pay the plan's trace a second time). Sharded like
+        `in_domain`. Powers `warp_subband_frac`.
     Returns:
       tgt [B', C, Ht, Wt], valid_mask [B', Ht, Wt] (bool)
       [, in_domain scalar f32 — only when with_domain_flag]
       [, subband_frac scalar f32 — only when with_subband_frac]
     """
+    if impl not in WARP_IMPLS:
+        raise ValueError(
+            f"homography_warp impl={impl!r}: must be one of {WARP_IMPLS}")
     Bp, C, H, W = src_BCHW.shape
     _, Ht, Wt = meshgrid_tgt.shape
 
-    x, y, valid = warp_coords(d_src, G_tgt_src, K_src_inv, K_tgt,
-                              meshgrid_tgt, (H, W))
+    H_tgt_src = geometry.homography_tgt_src(K_tgt, K_src_inv, G_tgt_src, d_src)
+    H_src_tgt = jax.lax.stop_gradient(geometry.inverse_3x3(H_tgt_src))
 
-    # diagnostic only — mirrors each guarded backend's fallback decision
+    grid = meshgrid_tgt.reshape(3, Ht * Wt)
+    src_homo = jnp.einsum("bij,jn->bin", H_src_tgt, grid)  # [B',3,HtWt]
+    src_xy = src_homo[:, 0:2, :] / src_homo[:, 2:3, :]
+    x = src_xy[:, 0, :].reshape(Bp, Ht, Wt)
+    y = src_xy[:, 1, :].reshape(Bp, Ht, Wt)
+
+    valid = ((x > -1.0) & (x < float(W)) & (y > -1.0) & (y < float(H)))
+
+    # diagnostic only — mirrors pallas_diff's fallback decision
     # (NaN = backend has no runtime guard to measure)
     in_domain = jnp.full((), jnp.nan, jnp.float32)
     subband = jnp.full((), jnp.nan, jnp.float32)
@@ -248,63 +215,23 @@ def homography_warp(src_BCHW: jnp.ndarray,
         from mine_tpu.kernels.warp import pallas_bilinear_sample
         tgt = pallas_bilinear_sample(src_BCHW, x, y, band=band,
                                      interpret=not on_tpu_backend())
-    elif impl in ("xla_banded", "separable"):
-        # banded / separable one-hot-matmul warps in pure XLA: both are
-        # differentiable by autodiff and GSPMD-partitionable directly, so
-        # no shard_map wrapper or mesh-divisibility guard is needed
-        xs = jax.lax.stop_gradient(x)
-        ys = jax.lax.stop_gradient(y)
-        if impl == "xla_banded":
-            from mine_tpu.ops import warp_banded
-            in_domain = warp_banded.guard_ok(
-                src_BCHW.shape, ys, band).astype(jnp.float32)
-            tgt = warp_banded.banded_bilinear_sample_guarded(
-                src_BCHW, xs, ys, band=band, mxu_dtype=mxu_dtype)
-        else:
-            from mine_tpu.ops import warp_separable
-            in_domain = warp_separable.guard_ok(
-                src_BCHW.shape, ys, band, sep_tol=sep_tol).astype(
-                    jnp.float32)
-            tgt = warp_separable.separable_bilinear_sample_guarded(
-                src_BCHW, xs, ys, band=band, mxu_dtype=mxu_dtype,
-                sep_tol=sep_tol)
-    elif impl in ("pallas_diff", "pallas_sep", "pallas_fused"):
-        # training paths: Pallas fwd+bwd with runtime gather fallback
-        # outside each backend's domain (kernels/warp_vjp.py — 2D band;
-        # kernels/warp_sep.py — anchor band + separability). Coords are
+    elif impl == "pallas_diff":
+        # training path: Pallas fwd+bwd with runtime gather fallback
+        # outside the band's domain (kernels/warp_vjp.py). Coords are
         # non-learnable (no-grad inverse above), so stop_gradient keeps the
         # two branches' autodiff structurally identical.
         from mine_tpu.kernels import on_tpu_backend
-
-        counts_windows = with_subband_frac and impl != "pallas_sep"
+        from mine_tpu.kernels.warp_vjp import (
+            bilinear_sample_diff_guarded, guard_ok, guarded_subband_frac)
 
         def _subband(src_shape, cx, cy):
-            if not counts_windows:
-                return subband  # NaN: no windows here, or nobody asked
-            from mine_tpu.kernels.warp_vjp import guarded_subband_frac
+            if not with_subband_frac:
+                return subband  # NaN: nobody asked
             return guarded_subband_frac(src_shape, cx, cy, band)
-        if impl in ("pallas_diff", "pallas_fused"):
-            # "pallas_fused" fuses warp+dequant+composite inside
-            # render_tgt_rgb_depth (kernels/render_fused.py); under the
-            # warp-only contract here it is the banded pallas_diff warp —
-            # same band geometry, same guard, same VJP
-            from mine_tpu.kernels.warp_vjp import (
-                bilinear_sample_diff_guarded, guard_ok)
-            fn = functools.partial(bilinear_sample_diff_guarded,
-                                   band=band,
-                                   interpret=not on_tpu_backend(),
-                                   mxu_dtype=mxu_dtype)
-            _diff_guard_ok = functools.partial(guard_ok, band=band)
-        else:
-            from mine_tpu.kernels.warp_sep import (
-                guard_ok, separable_sample_diff_guarded)
-            fn = functools.partial(separable_sample_diff_guarded,
-                                   band=band,
-                                   interpret=not on_tpu_backend(),
-                                   mxu_dtype=mxu_dtype,
-                                   sep_tol=sep_tol)
-            _diff_guard_ok = functools.partial(guard_ok, band=band,
-                                               sep_tol=sep_tol)
+        fn = functools.partial(bilinear_sample_diff_guarded,
+                               band=band,
+                               interpret=not on_tpu_backend(),
+                               mxu_dtype=mxu_dtype)
         xs = jax.lax.stop_gradient(x)
         ys = jax.lax.stop_gradient(y)
         if mesh is not None and mesh.size > 1:
@@ -319,23 +246,21 @@ def homography_warp(src_BCHW: jnp.ndarray,
                                                     shard_map)
                 bs_axes = (DATA_AXIS, PLANE_AXIS)
 
-                def sharded(kernel_fn, s, cx, cy):
+                def sharded(s, cx, cy):
                     # the guard runs on the LOCAL shard's coords — exactly
                     # the cond each device's kernel takes — and pmean over
                     # both mesh axes yields the FRACTION of shards on the
-                    # fast path (the old global-coords flag collapsed any
-                    # single out-of-band shard to fallback=1.0 for the whole
-                    # step, VERDICT r5: per-shard accounting)
-                    # (the windows' share likewise: each shard's own)
+                    # fast path (the windows' share likewise: each shard's
+                    # own)
                     def over_shards(v):
                         return jax.lax.pmean(jax.lax.pmean(v, DATA_AXIS),
                                              PLANE_AXIS)
-                    ok = _diff_guard_ok(s.shape, cy).astype(jnp.float32)
-                    return (kernel_fn(s, cx, cy), over_shards(ok),
+                    ok = guard_ok(s.shape, cy, band).astype(jnp.float32)
+                    return (fn(s, cx, cy), over_shards(ok),
                             over_shards(_subband(s.shape, cx, cy)))
 
                 sharded = shard_map(
-                    functools.partial(sharded, fn), mesh=mesh,
+                    sharded, mesh=mesh,
                     in_specs=(P(bs_axes), P(bs_axes), P(bs_axes)),
                     out_specs=(P(bs_axes), P(), P()))
                 tgt, in_domain, subband = sharded(src_BCHW, xs, ys)
@@ -347,14 +272,14 @@ def homography_warp(src_BCHW: jnp.ndarray,
             fn = functools.partial(bilinear_sample,
                                    gather_dtype=mxu_dtype)
             in_domain = jnp.zeros((), jnp.float32)
-            if counts_windows:
+            if with_subband_frac:
                 subband = jnp.zeros((), jnp.float32)
         else:
-            in_domain = _diff_guard_ok(src_BCHW.shape,
-                                       ys).astype(jnp.float32)
+            in_domain = guard_ok(src_BCHW.shape, ys,
+                                 band).astype(jnp.float32)
             subband = _subband(src_BCHW.shape, xs, ys)
         tgt = fn(src_BCHW, xs, ys)
-    else:
+    else:  # "xla"
         # training.warp_dtype reaches the gather too: bf16 storage halves
         # the volume's HBM traffic, lerp stays f32 (f32 is a no-op knob)
         tgt = bilinear_sample(src_BCHW, x, y, gather_dtype=mxu_dtype)

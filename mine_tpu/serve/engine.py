@@ -97,7 +97,6 @@ class RenderEngine:
                  warp_impl: str = "xla",
                  warp_band: int = 48,
                  warp_dtype: str = "float32",
-                 warp_sep_tol: float = 0.5,
                  max_bucket: int = 8,
                  cache: Optional[MPICache] = None,
                  encode_fn: Optional[Callable] = None,
@@ -114,7 +113,6 @@ class RenderEngine:
         self.warp_impl = warp_impl
         self.warp_band = warp_band
         self.warp_dtype = warp_dtype
-        self.warp_sep_tol = warp_sep_tol
         self.max_bucket = max_bucket
         self.cache = cache if cache is not None else MPICache()
         # encode_fn(img_hwc) -> (mpi_rgb [S,3,H,W], mpi_sigma [S,1,H,W],
@@ -233,30 +231,6 @@ class RenderEngine:
                      warp_impl: str):
         """planes [R,S,4,H,W] (quantized) + request gather idx [P] +
         poses G [P,4,4] -> (rgb [P,3,H,W], depth [P,1,H,W])."""
-        if warp_impl == "pallas_fused":
-            # no pre-dequant: the render megakernel reads the quantized
-            # cache entries directly (scales in SMEM, dequant in registers,
-            # kernels/render_fused.py) — the float volume never hits HBM.
-            # Only the cheap [P]-gather of the cache slice happens here.
-            H, W = planes.shape[-2], planes.shape[-1]
-            grid = geometry.cached_pixel_grid(H, W)
-            xyz_src = geometry.plane_xyz_src(grid, disp, K_inv)
-            xyz_tgt = geometry.plane_xyz_tgt(xyz_src[idx], G)
-            pq = planes[idx]
-            psc = scales[idx] if planes.dtype == jnp.int8 else None
-            res = rendering.render_tgt_rgb_depth(
-                pq[:, :, 0:3], pq[:, :, 3:4], disp[idx], xyz_tgt, G,
-                K_inv[idx], K[idx],
-                use_alpha=self.use_alpha,
-                is_bg_depth_inf=self.is_bg_depth_inf,
-                backend=self.backend,
-                warp_impl=warp_impl,
-                warp_band=self.warp_band,
-                warp_dtype=self.warp_dtype,
-                warp_sep_tol=self.warp_sep_tol,
-                mesh=self._render_mesh(),
-                planes_q=pq, planes_scales=psc)
-            return res.rgb, res.depth
         x = planes.astype(jnp.float32)
         if planes.dtype == jnp.int8:
             x = x * scales  # fused dequant: int8 never leaves this program
@@ -274,8 +248,7 @@ class RenderEngine:
             backend=self.backend,
             warp_impl=warp_impl,
             warp_band=self.warp_band,
-            warp_dtype=self.warp_dtype,
-            warp_sep_tol=self.warp_sep_tol)
+            warp_dtype=self.warp_dtype)
         return res.rgb, res.depth
 
     def _place(self, planes, scales, disp, K, K_inv, idx, poses):
@@ -284,14 +257,6 @@ class RenderEngine:
         (serve/shardmap.py) overrides this to device_put each operand under
         its NamedSharding so the jitted program spans the serving mesh."""
         return planes, scales, disp, K, K_inv, idx, poses
-
-    def _render_mesh(self):
-        """Serving mesh for the fused render path (warp_impl=
-        "pallas_fused"): None on the single-device engine; the mesh engine
-        (serve/shardmap.py) returns its Mesh so the megakernel runs under
-        shard_map, batch-split over the mesh's leading axis. The other
-        warp backends partition via GSPMD and never consult this."""
-        return None
 
     def _render_span_fields(self) -> dict:
         """Extra fields for a request trace's "render" span; the mesh
@@ -331,7 +296,6 @@ class RenderEngine:
                 "backend": self.backend,
                 "warp_band": self.warp_band,
                 "warp_dtype": self.warp_dtype,
-                "warp_sep_tol": self.warp_sep_tol,
             },
             "fingerprint": _aot.env_fingerprint(),
         }

@@ -115,12 +115,6 @@ class MeshRenderEngine(RenderEngine):
     def num_devices(self) -> int:
         return self.mesh.size
 
-    def _render_mesh(self):
-        """warp_impl="pallas_fused" runs the render megakernel under
-        shard_map over this mesh (pose rows over "batch"); the pose-bucket
-        floor at mesh_batch keeps every bucket divisible."""
-        return self.mesh
-
     def _mesh_desc(self) -> str:
         """AOT program-key component (engine._program_key): executables are
         compiled against committed NamedSharding inputs, so a 2x1 artifact

@@ -171,13 +171,6 @@ def _disp_loss(disp_syn_at_pts: jnp.ndarray, pt3d_disp: jnp.ndarray,
                     axis=(1, 2))
 
 
-# warp backends with a runtime band-fit guard: render results carry a
-# warp_in_domain diagnostic that loss_terms_per_scale surfaces as the
-# warp_fallback metric (key absent on unguarded backends)
-GUARDED_WARP_BACKENDS = ("pallas_diff", "xla_banded", "separable",
-                         "pallas_sep")
-
-
 @jax.named_scope("render")  # layer `render` (telemetry/programs.py)
 def render_per_scale(scale: int,
                      plan_s: ScaleInputs,
@@ -255,7 +248,7 @@ def render_per_scale(scale: int,
             use_alpha=cfg.use_alpha, is_bg_depth_inf=cfg.is_bg_depth_inf,
             backend=cfg.composite_backend,
             warp_impl=cfg.warp_backend, warp_band=cfg.warp_band,
-            warp_dtype=cfg.warp_dtype, warp_sep_tol=cfg.warp_sep_tol,
+            warp_dtype=cfg.warp_dtype,
             mesh=mesh if (mesh is not None and mesh.size > 1) else None)
     tgt_syn, tgt_mask = res.rgb, res.mask
     tgt_disp_syn = _safe_reciprocal_depth(res.depth)
@@ -271,9 +264,11 @@ def render_per_scale(scale: int,
     if cfg.use_disparity_loss:
         rendered["src_pt_disp"] = src_pt_disp
         rendered["src_pt_disp_syn"] = src_pt_disp_syn
-    if cfg.warp_backend in GUARDED_WARP_BACKENDS:
-        rendered["warp_in_domain"] = res.warp_in_domain
     if cfg.warp_backend == "pallas_diff":
+        # the one backend with a runtime band-fit guard: its diagnostics
+        # become the warp_fallback / warp_subband metrics (keys absent
+        # elsewhere)
+        rendered["warp_in_domain"] = res.warp_in_domain
         rendered["warp_subband"] = res.warp_subband
     return rendered
 

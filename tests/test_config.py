@@ -3,7 +3,7 @@ import os
 import pytest
 
 from mine_tpu.config import (CONFIG_DIR, load_config, mpi_config_from_dict,
-                             postprocess)
+                             postprocess, serve_config_from_dict)
 
 
 def test_load_llff_config_merges_defaults():
@@ -68,3 +68,64 @@ def test_mpi_config_static():
         os.path.join(CONFIG_DIR, "params_llff.yaml")))
     assert llff.use_disparity_loss is True
     assert llff.num_bins_total == 32
+
+
+# --- the warp backends PR 33 removed: an old name must fail, and say what is
+# valid, wherever a user or a caller can still write one ---------------------
+
+REMOVED_WARP_BACKENDS = ("xla_banded", "separable", "pallas_sep",
+                         "pallas_fused")
+
+
+def _train_key(name):
+    with pytest.raises(ValueError, match=r"auto\|xla\|pallas_diff"):
+        mpi_config_from_dict({"training.warp_backend": name})
+
+
+def _serve_key(name):
+    with pytest.raises(ValueError, match=r"must be xla\|pallas_diff"):
+        serve_config_from_dict({"serve.warp_backend": name})
+
+
+def _warp_impl(name):
+    import jax.numpy as jnp
+
+    from mine_tpu import geometry
+    from mine_tpu.ops import warp
+    K = jnp.asarray([[[8.0, 0, 4.0], [0, 8.0, 4.0], [0, 0, 1]]])
+    with pytest.raises(ValueError, match="'xla', 'pallas', 'pallas_diff'"):
+        warp.homography_warp(
+            jnp.zeros((1, 1, 8, 8)), jnp.ones((1,)), jnp.eye(4)[None],
+            geometry.inverse_intrinsics(K), K,
+            geometry.pixel_grid_homogeneous(8, 8), impl=name)
+
+
+def _sep_tol_key(_):
+    with pytest.raises(KeyError, match="training.warp_sep_tol"):
+        load_config(os.path.join(CONFIG_DIR, "params_llff.yaml"),
+                    extra_config='{"training.warp_sep_tol": 0.5}')
+
+
+def _one_list_of_names(_):
+    from mine_tpu import config
+    from mine_tpu.analysis import programs
+    from mine_tpu.ops import warp
+    from tests import test_serve
+    assert config.TRAINING_WARP_BACKENDS == ("auto",) + \
+        config.SERVE_WARP_BACKENDS
+    assert programs.WARP_IMPLS is config.SERVE_WARP_BACKENDS
+    assert test_serve.ENGINE_WARP_IMPLS == \
+        config.SERVE_WARP_BACKENDS + ("pallas",)
+    assert set(test_serve.ENGINE_WARP_IMPLS) == set(warp.WARP_IMPLS)
+    assert not set(REMOVED_WARP_BACKENDS) & set(warp.WARP_IMPLS)
+
+
+@pytest.mark.parametrize("check,name", [
+    *[(c, n) for c in (_train_key, _serve_key, _warp_impl)
+      for n in REMOVED_WARP_BACKENDS],
+    (_warp_impl, "palas_diff"),          # a misspelling, not an old name
+    (_sep_tol_key, "training.warp_sep_tol"),
+    (_one_list_of_names, "tuples"),
+], ids=lambda v: v if isinstance(v, str) else v.__name__.lstrip("_"))
+def test_removed_warp_backends_fail_loudly(check, name):
+    check(name)

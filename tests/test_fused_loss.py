@@ -211,7 +211,7 @@ def _ref_loss_per_scale(scale, mpi, disparity, batch, G_tgt_src, cfg,
         "psnr_tgt": psnr_tgt,
         "loss_disp_pt3dtgt": loss_disp_tgt,
     }
-    if cfg.warp_backend in ("pallas_diff", "xla_banded"):
+    if cfg.warp_backend == "pallas_diff":
         loss_dict["warp_fallback"] = jax.lax.stop_gradient(
             1.0 - res.warp_in_domain)
     visuals = {

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from mine_tpu import geometry
-from mine_tpu.config import serve_config_from_dict
+from mine_tpu.config import SERVE_WARP_BACKENDS, serve_config_from_dict
 from mine_tpu.data.synthetic import SyntheticMPIDataset
 from mine_tpu.ops import rendering
 from mine_tpu.serve import (MicroBatcher, MPICache, PyramidCache,
@@ -31,8 +31,9 @@ from mine_tpu.serve import (MicroBatcher, MPICache, PyramidCache,
 H = W = 64
 S = 4
 
-ENGINE_WARP_IMPLS = ("xla", "xla_banded", "pallas_diff", "separable",
-                     "pallas_sep")
+# what serve.warp_backend may name, plus the forward-only kernel the video
+# generator and the benchmark's serve cell hand the engine directly
+ENGINE_WARP_IMPLS = SERVE_WARP_BACKENDS + ("pallas",)
 
 
 @pytest.fixture(scope="module")
@@ -191,7 +192,7 @@ def _reference_render(planes_S4HW, disp_S, K_33, G_44, warp_impl):
     res = rendering.render_tgt_rgb_depth(
         rgb, sigma, disp, xyz_tgt, G_44[None], K_inv, K,
         use_alpha=False, is_bg_depth_inf=False, backend="xla",
-        warp_impl=warp_impl, warp_band=48, warp_sep_tol=1e6)
+        warp_impl=warp_impl, warp_band=48)
     return res.rgb[0], res.depth[0]
 
 
@@ -199,11 +200,8 @@ def _reference_render(planes_S4HW, disp_S, K_33, G_44, warp_impl):
 def test_engine_matches_reference_bitwise_per_backend(scene, impl):
     """bf16 cache + fused in-jit dequant + pose batching + pow2 padding ==
     per-pose reference on host-dequantized planes, bitwise, for every warp
-    backend (CPU: Pallas in interpret mode). sep_tol is uncapped like the
-    warppass bench row — speed paths, not the fallback, are what parity
-    must cover."""
-    engine = _engine_for(scene, "bf16", warp_band=48, warp_sep_tol=1e6,
-                         max_bucket=4)
+    backend (CPU: Pallas in interpret mode)."""
+    engine = _engine_for(scene, "bf16", warp_band=48, max_bucket=4)
     deq = engine.cache.get("img").dequantized()
     rgb, depth = engine.render("img", scene["poses"], warp_impl=impl)
     for j, pose in enumerate(scene["poses"]):
@@ -212,6 +210,23 @@ def test_engine_matches_reference_bitwise_per_backend(scene, impl):
             jnp.asarray(pose), impl)
         np.testing.assert_array_equal(rgb[j], np.asarray(ref_rgb))
         np.testing.assert_array_equal(depth[j], np.asarray(ref_depth))
+
+
+@pytest.mark.parametrize("quant", ["float32", "bf16", "int8"])
+def test_engine_pallas_matches_xla_backend(scene, quant):
+    """The serve cell's engine (benchmark/traffic/gallery_steady.json:
+    warp_impl "pallas", band 32 = infer/video.py WARP_BAND) against the
+    default gather engine, per cache quant mode, on in-band poses; 5 poses
+    pad to an 8-bucket. Two XLA programs around the same math: the banded
+    kernel sums tent-weighted taps where the gather lerps."""
+    from mine_tpu.infer.video import WARP_BAND
+    rgb_x, dep_x = _engine_for(scene, quant, max_bucket=8).render(
+        "img", scene["poses"])
+    rgb_p, dep_p = _engine_for(scene, quant, max_bucket=8, warp_impl="pallas",
+                               warp_band=WARP_BAND).render(
+        "img", scene["poses"])
+    np.testing.assert_allclose(rgb_p, rgb_x, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(dep_p, dep_x, rtol=1e-4, atol=1e-5)
 
 
 @pytest.mark.parametrize("quant", ["float32", "int8"])

@@ -411,7 +411,6 @@ def test_serve_config_rejects_bad_fleet_keys():
     cfg = serve_config_from_dict({})
     assert cfg.mesh_batch == 1 and cfg.mesh_model == 1
     assert cfg.cache_shards == 1 and cfg.scheduler == "continuous"
-    # default "xla" keeps the engine byte-identical to pre-megakernel
     assert cfg.warp_backend == "xla"
-    fused = serve_config_from_dict({"serve.warp_backend": "pallas_fused"})
-    assert fused.warp_backend == "pallas_fused"
+    guarded = serve_config_from_dict({"serve.warp_backend": "pallas_diff"})
+    assert guarded.warp_backend == "pallas_diff"

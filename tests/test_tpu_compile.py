@@ -1,5 +1,5 @@
-"""The main path's Pallas kernels, handed to the chip's compiler at the
-widths params_llff.yaml ships — without a chip.
+"""The main path's Pallas kernels, handed to the chip's compiler at every
+shape the benchmark's MINE cells run — without a chip.
 
 libtpu compiles for a *described* v5e here on the CPU host (the
 on-chip-measurement guide, section 2): what Mosaic refuses — a band slice
@@ -22,10 +22,13 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-# params_llff.yaml: 384x512, N=32 planes, per-chip batch 2 -> B*S = 64
-B, S = 2, 32
-FULL = (384, 512)
-SMALLEST = (48, 64)      # the 4-scale loss pyramid's last level
+S = 32                   # mpi.num_bins_coarse of both configurations
+# the 4-scale loss pyramid of each train cell -> its per-chip batch:
+# params_llff.yaml 384x512 at B = 2 (B*S = 64), params_realestate.yaml
+# 256x384 at B = 4 (B*S = 128; W = 192 is one and a half lane tiles)
+LLFF = [(384, 512), (192, 256), (96, 128), (48, 64)]
+RE10K = [(256, 384), (128, 192), (64, 96), (32, 48)]
+BATCH = {**{hw: 2 for hw in LLFF}, **{hw: 4 for hw in RE10K}}
 BAND = 48                # training.warp_band default; all of a 48-row image
 SERVE_BAND = 32          # infer/video.py WARP_BAND
 SERVE_POSES = 8          # serve.max_bucket default
@@ -67,30 +70,36 @@ def _sq(out):
                for o in jax.tree_util.tree_leaves(out))
 
 
-def _warp_case(kernel_name, hw):
-    """fwd + VJP of a guarded training warp over all B*S planes (7 channels:
-    rgb + sigma + xyz, ops/rendering.py)."""
+def _warp_case(hw):
+    """fwd + VJP of the guarded training warp over all B*S planes (7
+    channels: rgb + sigma + xyz, ops/rendering.py)."""
     def build():
-        from mine_tpu.kernels import warp_sep, warp_vjp
-        kernel = {"pallas_diff": warp_vjp.bilinear_sample_diff_guarded,
-                  "pallas_sep": warp_sep.separable_sample_diff_guarded
-                  }[kernel_name]
-        fn = functools.partial(kernel, band=BAND, interpret=False)
+        from mine_tpu.kernels.warp_vjp import bilinear_sample_diff_guarded
+        fn = functools.partial(bilinear_sample_diff_guarded, band=BAND,
+                               interpret=False)
         H, W = hw
-        shapes = [((B * S, 7, H, W), jnp.float32),
-                  ((B * S, H, W), jnp.float32), ((B * S, H, W), jnp.float32)]
+        n = BATCH[hw] * S
+        shapes = [((n, 7, H, W), jnp.float32),
+                  ((n, H, W), jnp.float32), ((n, H, W), jnp.float32)]
         return jax.grad(lambda s, x, y: _sq(fn(s, x, y))), shapes
     return build
 
 
 def _composite_case(hw):
+    """fwd + VJP of the training composite, called as ops/rendering.py calls
+    it: rgb, sigma and xyz are slices of the warped 7-channel volume. (With
+    three entry parameters and this file's sum-of-squares loss the compiler
+    places the loss's fused cotangents beside the kernel and refuses 32x48:
+    18.41 MiB of scoped VMEM against 16. The step never builds that program;
+    re10k_n32's whole step compiles with this kernel in it.)"""
     def build():
         from mine_tpu.kernels.composite_vjp import fused_volume_render_diff
         H, W = hw
-        shapes = [((B, S, c, H, W), jnp.float32) for c in (3, 1, 3)]
+        shapes = [((BATCH[hw], S, 7, H, W), jnp.float32)]
         return jax.grad(
-            lambda r, s, z: _sq(fused_volume_render_diff(
-                r, s, z, interpret=False)), argnums=(0, 1)), shapes
+            lambda v: _sq(fused_volume_render_diff(
+                v[:, :, 0:3], v[:, :, 3:4], v[:, :, 4:7], True, False,
+                False))), shapes
     return build
 
 
@@ -106,31 +115,29 @@ def _src_blend_case(hw):
     return build
 
 
-def _serve_warp_case():
-    from mine_tpu.kernels.warp import pallas_bilinear_sample
-    H, W = FULL
-    n = SERVE_POSES * S
-    return (functools.partial(pallas_bilinear_sample, band=SERVE_BAND,
-                              interpret=False),
-            [((n, 4, H, W), jnp.float32), ((n, H, W), jnp.float32),
-             ((n, H, W), jnp.float32)])
+def _serve_warp_case(hw, views=SERVE_POSES):
+    """The render engine's forward warp of one pose bucket: views x S
+    planes of the 7-channel volume (the ledger's `warp_bilinear_sample_fwd`
+    f32[32|64|128|256, 7, 384, 512] in llff_serve_steady)."""
+    def build():
+        from mine_tpu.kernels.warp import pallas_bilinear_sample
+        H, W = hw
+        n = views * S
+        return (functools.partial(pallas_bilinear_sample, band=SERVE_BAND,
+                                  interpret=False),
+                [((n, 7, H, W), jnp.float32), ((n, H, W), jnp.float32),
+                 ((n, H, W), jnp.float32)])
+    return build
 
 
-def _serve_composite_case():
-    from mine_tpu.kernels.composite import fused_volume_render
-    H, W = FULL
-    return (functools.partial(fused_volume_render, interpret=False),
-            [((SERVE_POSES, S, c, H, W), jnp.float32) for c in (3, 1, 3)])
-
-
-def _megakernel_case():
-    """kernels/render_fused.py reading the default bf16 cache directly."""
-    from mine_tpu.kernels.render_fused import fused_plane_render
-    H, W = FULL
-    return (lambda v, xyz, cx, cy: fused_plane_render(
-                v, None, xyz, cx, cy, band=SERVE_BAND, interpret=False),
-            [((1, S, 4, H, W), jnp.bfloat16), ((1, S, 3, H, W), jnp.float32),
-             ((1, S, H, W), jnp.float32), ((1, S, H, W), jnp.float32)])
+def _serve_composite_case(hw):
+    def build():
+        from mine_tpu.kernels.composite import fused_volume_render
+        H, W = hw
+        return (functools.partial(fused_volume_render, interpret=False),
+                [((SERVE_POSES, S, c, H, W), jnp.float32)
+                 for c in (3, 1, 3)])
+    return build
 
 
 def _attention_case():
@@ -142,20 +149,17 @@ def _attention_case():
         q, k, v, 16, interpret=False)), argnums=(0, 1, 2)), shapes
 
 
-CASES = {
-    "attention_vjp-4096x16x128": _attention_case,
-    "warp_diff_vjp-384x512": _warp_case("pallas_diff", FULL),
-    "warp_diff_vjp-48x64": _warp_case("pallas_diff", SMALLEST),
-    "composite_vjp-384x512": _composite_case(FULL),
-    "composite_vjp-48x64": _composite_case(SMALLEST),
-    "warp_sep_vjp-384x512": _warp_case("pallas_sep", FULL),
-    "warp_sep_vjp-48x64": _warp_case("pallas_sep", SMALLEST),
-    "src_render_blend-384x512": _src_blend_case(FULL),
-    "src_render_blend-48x64": _src_blend_case(SMALLEST),
-    "serve_warp_fwd-384x512": _serve_warp_case,
-    "serve_composite_fwd-384x512": _serve_composite_case,
-    "megakernel_bf16-384x512": _megakernel_case,
-}
+CASES = {"attention_vjp-4096x16x128": _attention_case}
+for _hw in LLFF + RE10K:
+    CASES["warp_diff_vjp-%dx%d" % _hw] = _warp_case(_hw)
+    CASES["composite_vjp-%dx%d" % _hw] = _composite_case(_hw)
+    CASES["src_render_blend-%dx%d" % _hw] = _src_blend_case(_hw)
+for _hw in (LLFF[0], RE10K[0]):
+    CASES["serve_warp_fwd-%dx%d" % _hw] = _serve_warp_case(_hw)
+    CASES["serve_composite_fwd-%dx%d" % _hw] = _serve_composite_case(_hw)
+for _views in (1, 2, 4):   # the smaller pow2 pose buckets of the serve cell
+    CASES["serve_warp_fwd-384x512-views%d" % _views] = _serve_warp_case(
+        LLFF[0], _views)
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
@@ -164,9 +168,7 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_compile_cache):
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
             for shape, dtype in shapes]
     # conftest.py raises the matmul precision to "highest" for the CPU
-    # numerics tests; the CLIs run at JAX's default, and so does this (at
-    # "highest" the megakernel's f32 tent matmul unrolls into six bf16
-    # passes and takes 184 s to compile instead of 40)
+    # numerics tests; the CLIs run at JAX's default, and so does this
     with jax.default_matmul_precision("default"):
         compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), (

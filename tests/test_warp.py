@@ -43,7 +43,21 @@ def test_bilinear_sample_matches_torch_grid_sample(seed):
     np.testing.assert_allclose(ours, ref, rtol=1e-4, atol=1e-5)
 
 
-def test_homography_warp_identity():
+def _assert_is_the_gather(impl, out, valid, *warp_args):
+    """The Pallas implementations against the gather, bitwise: at whole
+    source pixels a tent weight is one-hot, and 1.0 * v plus zeros is exact
+    in float32 whichever way the taps are summed."""
+    if impl == "xla":
+        return
+    ref, ref_valid = warp.homography_warp(*warp_args, impl="xla")
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    np.testing.assert_array_equal(np.asarray(valid), np.asarray(ref_valid))
+
+
+# heights below are whole row blocks of 8, so that the Pallas
+# implementations run their kernel and not a fallback
+@pytest.mark.parametrize("impl", warp.WARP_IMPLS)
+def test_homography_warp_identity(impl):
     """Identity pose + equal intrinsics must reproduce the source exactly."""
     rng = np.random.RandomState(3)
     B, C, H, W = 2, 4, 8, 10
@@ -52,18 +66,20 @@ def test_homography_warp_identity():
     G = jnp.tile(jnp.eye(4), (B, 1, 1))
     d = jnp.full((B,), 3.0)
     grid = geometry.pixel_grid_homogeneous(H, W)
+    args = (src, d, G, geometry.inverse_intrinsics(K), K, grid)
 
-    out, valid = warp.homography_warp(src, d, G, geometry.inverse_intrinsics(K),
-                                      K, grid)
+    out, valid = warp.homography_warp(*args, impl=impl)
     np.testing.assert_allclose(np.asarray(out), np.asarray(src), rtol=1e-4,
                                atol=1e-4)
     assert bool(jnp.all(valid))
+    _assert_is_the_gather(impl, out, valid, *args)
 
 
-def test_homography_warp_integer_translation():
+@pytest.mark.parametrize("impl", warp.WARP_IMPLS)
+def test_homography_warp_integer_translation(impl):
     """Camera shift of exactly fx*tx/d = 2 pixels: warped image is the source
     shifted by 2 pixels, and pixels that sampled outside are invalid."""
-    B, C, H, W = 1, 1, 6, 12
+    B, C, H, W = 1, 1, 8, 12
     fx, d = 10.0, 5.0
     tx = 1.0  # pixel shift = fx*tx/d = 2
     img = np.zeros((B, C, H, W), dtype=np.float32)
@@ -72,8 +88,10 @@ def test_homography_warp_integer_translation():
     G = jnp.eye(4)[None].at[0, 0, 3].set(-tx)
     grid = geometry.pixel_grid_homogeneous(H, W)
 
-    out, valid = warp.homography_warp(jnp.asarray(img), jnp.asarray([d]), G,
-                                      geometry.inverse_intrinsics(K), K, grid)
+    args = (jnp.asarray(img), jnp.asarray([d]), G,
+            geometry.inverse_intrinsics(K), K, grid)
+    out, valid = warp.homography_warp(*args, impl=impl)
+    _assert_is_the_gather(impl, out, valid, *args)
     out = np.asarray(out)
     # target pixel x sees source pixel x + 2 -> the column lights up at x=2
     np.testing.assert_allclose(out[0, 0, :, 2], 1.0, atol=1e-5)
@@ -84,13 +102,14 @@ def test_homography_warp_integer_translation():
     assert v[0, :, : W - 2].all()
 
 
-def test_warp_gradients_flow_through_values():
+@pytest.mark.parametrize("impl", ["xla", "pallas_diff"])
+def test_warp_gradients_flow_through_values(impl):
     """Gradients flow through the sampled *values* (the MPI planes produced by
     the network). The warp grid itself is deliberately no-grad, matching the
     reference's no_grad homography inverse (homography_sampler.py:112-113)."""
     import jax
 
-    B, C, H, W = 1, 2, 5, 5
+    B, C, H, W = 1, 2, 8, 5
     rng = np.random.RandomState(4)
     src0 = jnp.asarray(rng.normal(size=(B, C, H, W)).astype(np.float32))
     K = jnp.asarray([[[10.0, 0, 2.0], [0, 10.0, 2.0], [0, 0, 1]]])
@@ -99,7 +118,8 @@ def test_warp_gradients_flow_through_values():
 
     def loss(src):
         out, _ = warp.homography_warp(src, jnp.asarray([2.0]), G,
-                                      geometry.inverse_intrinsics(K), K, grid)
+                                      geometry.inverse_intrinsics(K), K, grid,
+                                      impl=impl)
         return jnp.sum(out ** 2)
 
     g = jax.grad(loss)(src0)
@@ -109,7 +129,8 @@ def test_warp_gradients_flow_through_values():
     def loss_t(t):
         G2 = jnp.eye(4)[None].at[0, 0, 3].set(t)
         out, _ = warp.homography_warp(src0, jnp.asarray([2.0]), G2,
-                                      geometry.inverse_intrinsics(K), K, grid)
+                                      geometry.inverse_intrinsics(K), K, grid,
+                                      impl=impl)
         return jnp.sum(out ** 2)
 
     # pose gradient via the grid is intentionally blocked

@@ -1,15 +1,16 @@
-"""Guard-domain property tests across ALL guarded warp backends + meshes.
+"""Guard-domain property tests of the guarded warp backend across meshes.
 
 The `warp_fallback_frac` training metric is only trustworthy if the
-with_domain_flag plumbing reports each backend's ACTUAL lax.cond decision —
-not a lookalike recomputation. Property: for every guarded backend
-(xla_banded / separable / pallas_diff / pallas_sep) and every mesh shape
-(single device, 2- and 4-device data meshes), the flag equals EXACTLY the
+with_domain_flag plumbing reports pallas_diff's ACTUAL lax.cond decision —
+not a lookalike recomputation. Property: on every mesh shape (single
+device, 2- and 4-device data meshes), the flag equals EXACTLY the
 fraction of shards whose own guard_ok passes — 1.0 on randomized
 translation-dominated poses, 0.0 on an adversarial rotation-heavy one,
 with the expectation derived by replaying the homography math and calling
-the backend's exported guard_ok directly (ops/warp.py builds the flag from
-that same function, so a drift between cond and flag is what this catches).
+the exported guard_ok directly (ops/warp.py builds the flag from that same
+function, so a drift between cond and flag is what this catches). The
+`warp_subband_frac` diagnostic is sharded the same way and held the same
+way, against kernels.warp.subband_frac per shard.
 """
 
 import functools
@@ -20,27 +21,17 @@ import numpy as np
 import pytest
 
 from mine_tpu import geometry
-from mine_tpu.kernels import warp_sep as kernels_warp_sep
+from mine_tpu.kernels import warp as kernels_warp
 from mine_tpu.kernels import warp_vjp
-from mine_tpu.ops import warp_banded, warp_separable
 from mine_tpu.ops.warp import homography_warp
 from mine_tpu.parallel import mesh as mesh_lib
 
 B, C, H, W = 8, 3, 32, 32
 
-# (impl, band, guard_ok(src_shape, coords_y)); bands: 16 for the pure-XLA
-# guards, 24 for the Pallas ones (their aligned=True domain budgets the
-# SUBLANE_ALIGN-1 slack)
-BACKENDS = [
-    ("xla_banded", 16,
-     functools.partial(warp_banded.guard_ok, band=16)),
-    ("separable", 16,
-     functools.partial(warp_separable.guard_ok, band=16, sep_tol=0.5)),
-    ("pallas_diff", 24,
-     functools.partial(warp_vjp.guard_ok, band=24)),
-    ("pallas_sep", 24,
-     functools.partial(kernels_warp_sep.guard_ok, band=24, sep_tol=0.5)),
-]
+# the guarded backend, its band (24: the kernels' domain budgets the
+# SUBLANE_ALIGN-1 slack) and its exported guard_ok(src_shape, coords_y)
+IMPL, BAND = "pallas_diff", 24
+GUARD = functools.partial(warp_vjp.guard_ok, band=BAND)
 
 
 def _setup(seed=7):
@@ -71,27 +62,25 @@ def _adversarial_pose():
     return jnp.broadcast_to(R, (B, 4, 4))
 
 
-def _source_rows(d, G, K_inv, K, grid):
-    """Replay homography_warp's coordinate derivation (ops/warp.py) to feed
-    the guard the exact same source-y field the backend sees."""
+def _source_coords(d, G, K_inv, K, grid):
+    """Replay homography_warp's coordinate derivation (ops/warp.py): the
+    exact source (x, y) fields the backend sees."""
     H_tgt_src = geometry.homography_tgt_src(K, K_inv, G, d)
     H_src_tgt = geometry.inverse_3x3(H_tgt_src)
     g = grid.reshape(3, H * W)
     src_homo = jnp.einsum("bij,jn->bin", H_src_tgt, g)
     src_xy = src_homo[:, 0:2, :] / src_homo[:, 2:3, :]
-    return src_xy[:, 1, :].reshape(B, H, W)
+    return (src_xy[:, 0, :].reshape(B, H, W),
+            src_xy[:, 1, :].reshape(B, H, W))
 
 
-def _expected_flag(impl, guard, cy, mesh):
-    """The flag contract: Pallas backends on a multi-device mesh decide the
-    cond PER SHARD and pmean the guards; everything else decides globally."""
-    if impl in ("pallas_diff", "pallas_sep") and mesh is not None \
-            and mesh.size > 1:
-        shards = np.split(np.asarray(cy), mesh.size, axis=0)
-        per = [float(guard((B // mesh.size, C, H, W), jnp.asarray(s)))
-               for s in shards]
-        return float(np.mean(per))
-    return float(guard((B, C, H, W), cy))
+def _expected_flag(guard, cy, mesh):
+    """The flag contract: on a multi-device mesh the cond decides PER SHARD
+    and the guards are pmean'd; otherwise it decides globally."""
+    n = 1 if mesh is None else mesh.size
+    shards = np.split(np.asarray(cy), n, axis=0)
+    per = [float(guard((B // n, C, H, W), jnp.asarray(s))) for s in shards]
+    return float(np.mean(per))
 
 
 def _mesh(n):
@@ -100,10 +89,8 @@ def _mesh(n):
     return mesh_lib.make_mesh(data=n, plane=1, devices=jax.devices()[:n])
 
 
-@pytest.mark.parametrize("impl,band,guard",
-                         BACKENDS, ids=[b[0] for b in BACKENDS])
 @pytest.mark.parametrize("mesh_n", [None, 2, 4])
-def test_flag_matches_guard(impl, band, guard, mesh_n):
+def test_flag_matches_guard(mesh_n):
     src, d, K, K_inv, grid = _setup()
     mesh = _mesh(mesh_n)
     # seed sweep only single-device: the mesh cases re-check the SAME guard
@@ -113,36 +100,29 @@ def test_flag_matches_guard(impl, band, guard, mesh_n):
     poses = [("trans%d" % s, _translation_pose(s), 1.0) for s in seeds]
     poses.append(("rot", _adversarial_pose(), 0.0))
     for name, G, want in poses:
-        cy = _source_rows(d, G, K_inv, K, grid)
-        expected = _expected_flag(impl, guard, cy, mesh)
+        _, cy = _source_coords(d, G, K_inv, K, grid)
+        expected = _expected_flag(GUARD, cy, mesh)
         # the constructed poses are unambiguous: fully in-band or fully out
-        assert expected == want, (impl, mesh_n, name, expected)
-        _, _, flag = homography_warp(src, d, G, K_inv, K, grid, impl=impl,
-                                     band=band, mesh=mesh,
+        assert expected == want, (mesh_n, name, expected)
+        _, _, flag = homography_warp(src, d, G, K_inv, K, grid, impl=IMPL,
+                                     band=BAND, mesh=mesh,
                                      with_domain_flag=True)
-        assert float(flag) == expected, (impl, mesh_n, name, float(flag))
+        assert float(flag) == expected, (mesh_n, name, float(flag))
 
 
 def test_flag_partial_fallback_on_mixed_shards():
     """A mesh where ONE of two shards draws an out-of-band pose must report
-    the fraction (0.5), not collapse to all-or-nothing — the per-shard
-    accounting the r6 flag rework introduced, now pinned for the separable
-    Pallas backend too."""
+    the fraction (0.5), not collapse to all-or-nothing."""
     src, d, K, K_inv, grid = _setup()
     mesh = _mesh(2)
     G = _translation_pose(0)
     # second half of the batch (shard 1 under P(("data","plane"))): rotation
     G = G.at[B // 2:].set(_adversarial_pose()[B // 2:])
-    for impl, band, guard in BACKENDS:
-        if impl in ("xla_banded", "separable"):
-            continue  # no shard_map path: the guard is global by design
-        cy = _source_rows(d, G, K_inv, K, grid)
-        expected = _expected_flag(impl, guard, cy, mesh)
-        assert expected == 0.5, (impl, expected)
-        _, _, flag = homography_warp(src, d, G, K_inv, K, grid, impl=impl,
-                                     band=band, mesh=mesh,
-                                     with_domain_flag=True)
-        assert float(flag) == 0.5, (impl, float(flag))
+    _, cy = _source_coords(d, G, K_inv, K, grid)
+    assert _expected_flag(GUARD, cy, mesh) == 0.5
+    _, _, flag = homography_warp(src, d, G, K_inv, K, grid, impl=IMPL,
+                                 band=BAND, mesh=mesh, with_domain_flag=True)
+    assert float(flag) == 0.5, float(flag)
 
 
 def test_flag_nan_for_unguarded_backend():
@@ -152,3 +132,52 @@ def test_flag_nan_for_unguarded_backend():
     _, _, flag = homography_warp(src, d, _translation_pose(0), K_inv, K, grid,
                                  impl="xla", with_domain_flag=True)
     assert np.isnan(float(flag))
+
+
+@pytest.mark.parametrize("mesh_n", [None, 2, 4])
+def test_subband_frac_is_mean_over_shards(mesh_n):
+    """with_subband_frac under shard_map: each shard counts its OWN windows
+    (kernels.warp.subband_frac on its planes) and the pmean is their mean:
+    a share taken from one shard's coordinates would not match."""
+    src, d, K, K_inv, grid = _setup()
+    mesh = _mesh(mesh_n)
+    band = H  # the whole image: every pose is in-band, rotation included
+    # rotation about the optical axis growing along the batch: a row's
+    # taps slope across the width, more units overflow their 16-row
+    # sub-band on the later planes, and the shards' shares differ
+    a = np.linspace(0.2, 0.4, B)
+    rot = np.stack([np.stack([np.cos(a), -np.sin(a)], -1),
+                    np.stack([np.sin(a), np.cos(a)], -1)], -2)
+    G = _translation_pose(3).at[:, 0:2, 0:2].set(
+        jnp.asarray(rot, jnp.float32))
+    cx, cy = _source_coords(d, G, K_inv, K, grid)
+    n = 1 if mesh is None else mesh.size
+    assert _expected_flag(functools.partial(warp_vjp.guard_ok, band=band),
+                          cy, mesh) == 1.0
+    per = [float(kernels_warp.subband_frac((B // n, C, H, W),
+                                           jnp.asarray(sx), jnp.asarray(sy),
+                                           band))
+           for sx, sy in zip(np.split(np.asarray(cx), n),
+                             np.split(np.asarray(cy), n))]
+    *_, flag, frac = homography_warp(
+        src, d, G, K_inv, K, grid, impl=IMPL, band=band, mesh=mesh,
+        with_domain_flag=True, with_subband_frac=True)
+    assert float(flag) == 1.0
+    np.testing.assert_allclose(float(frac), np.mean(per), rtol=0, atol=1e-6)
+    assert 0.0 < float(frac) < 1.0 and len(set(per)) == len(per), per
+
+
+def test_indivisible_flat_batch_takes_the_gather():
+    """A flat batch the mesh does not divide (a remainder eval example)
+    cannot run the kernel under shard_map: the values are the gather's,
+    bitwise, and both diagnostics say so (0.0, not NaN and not 1.0)."""
+    src, d, K, K_inv, grid = _setup()
+    G = _translation_pose(0)
+    src, d, G, K, K_inv = (a[:B - 1] for a in (src, d, G, K, K_inv))
+    out, valid, flag, frac = homography_warp(
+        src, d, G, K_inv, K, grid, impl=IMPL, band=BAND, mesh=_mesh(2),
+        with_domain_flag=True, with_subband_frac=True)
+    ref, ref_valid = homography_warp(src, d, G, K_inv, K, grid, impl="xla")
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    np.testing.assert_array_equal(np.asarray(valid), np.asarray(ref_valid))
+    assert float(flag) == 0.0 and float(frac) == 0.0

@@ -131,7 +131,10 @@ def all_units_overflow(monkeypatch):
     jax.clear_caches()  # the wrappers are jitted: drop their traces
 
 
-@pytest.mark.parametrize("hw", [(48, 64), (64, 256), (64, 384)])
+# widths 192 and 96: re10k_train's pyramid levels that are no whole lane
+# tiles (one tile of the full width, the source padded to 256 / 128)
+@pytest.mark.parametrize("hw", [(48, 64), (64, 256), (64, 384), (64, 192),
+                                (64, 96)])
 def test_windowed_equals_whole_band_bitwise(hw, monkeypatch):
     """In-domain field, every unit on the windowed path: the same bits as
     the whole-band contraction of the same units (the skipped rows and

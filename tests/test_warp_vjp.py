@@ -212,7 +212,10 @@ def _pair(band):
         s, x, y, band, 8, kernel_test_utils.interpret())
 
 
-@pytest.mark.parametrize("hw", [(48, 64), (64, 256), (64, 384)])
+# widths 192 and 96: re10k_train's pyramid levels that are no whole lane
+# tiles (one tile of the full width, the source padded to 256 / 128)
+@pytest.mark.parametrize("hw", [(48, 64), (64, 256), (64, 384), (64, 192),
+                                (64, 96)])
 def test_windowed_splat_equals_whole_band_bitwise(hw, monkeypatch):
     """In-domain field, every unit windowed: d_src has the same bits as the
     whole-band splat of the same units. Integer cotangents and coordinates

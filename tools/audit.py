@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """Program auditor: run the static-analysis pass suite over every core
-jitted program (train step, fused loss fwd/bwd, the five warp backends,
+jitted program (train step, fused loss fwd/bwd, the two warp backends,
 the serve render engine single-device and mesh, eval encode).
 
 Passes (mine_tpu/analysis/passes.py):
@@ -130,9 +130,6 @@ def _cmd_update_baseline(path, program_names):
         # vs 80 in the per-scale reference pyramid (>=4x reduction)
         "fused_loss.blur_dots": 8,
         "fused_loss.blur_dots_reference": 80,
-        # separable warp must stay under 2*band/W of banded's dot FLOPs
-        # at the flagship shape (band=48, W=384)
-        "warp.separable_vs_banded_max_flop_ratio": 0.25,
     }
     for k, v in defaults.items():
         baseline["budgets"].setdefault(k, v)

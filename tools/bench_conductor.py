@@ -9,7 +9,7 @@ take the conductor down with it:
 
   realloop_b4        async-pipeline-fed end-to-end step (donate_batch)
   losspass_b4        loss-graph-only (fused pyramid vs elementwise tail)
-  warppass_b4        all five warp backends (promote separable/pallas_sep?)
+  warppass_b4        both warp backends
   ssim_precision_ab  highest-vs-default SSIM matmul precision A/B
   renderpass_b4      render-only serving forward
   serve_amortize     encode-amortization curve, --mesh fleet sweep
@@ -77,11 +77,6 @@ LEVERS = [
     {"name": "serve_slo", "mesh": True, "trace_sample": "0.05"},
     {"name": "aot_coldstart", "variant": "serve_coldstart"},
     {"name": "stream_session"},
-    # megakernel lever: renderpass_b4 already sweeps every warp backend
-    # including pallas_fused — this alias keys the fused reading under its
-    # own conductor record so promote/regress tracks the megakernel
-    # against the r05 serve prior directly
-    {"name": "render_fused", "variant": "renderpass_b4"},
     # staged-pipeline lever: the GPipe-style executor's stages x
     # microbatches sweep (bench.py pipepass_b4); the keyed ips is the
     # 1-stage x 1-microbatch point, so promote/regress reads the staged

@@ -20,17 +20,39 @@ TPU-first: NHWC compute (bfloat16-able); outputs are returned as float32
 
 from __future__ import annotations
 
+import math
 from typing import Any, Optional, Sequence, Tuple
 
 import flax.linen as nn
 import jax.numpy as jnp
 
+from mine_tpu import telemetry
 from mine_tpu.models import embedder
 from mine_tpu.models.layers import (Conv, ConvBlock, ConvBNLeaky,
                                     max_pool_3x3_s2, upsample_nearest_2x)
 from mine_tpu.parallel.mesh import DATA_AXIS, PLANE_AXIS, constrain
 
 NUM_CH_DEC = (16, 32, 64, 128, 256)
+LANES = 128  # a TPU vector register's minor dimension
+
+
+def fold_strips(planes_per_device: int, rows: int) -> int:
+    """Row strips k an image of `rows` rows is cut into, and folded into the
+    batch as k entries, through the decoder stages narrower than LANES.
+
+    The TPU compiler lays a [N, h, w, C] tensor of those stages (C = 64,
+    32, 16) out batch-minor: the batch fills the 128 lanes, the channels
+    lie on sublanes. A device's plane count that is not a multiple of 128
+    is padded up to one, in every vector op and every byte to and from HBM
+    (B*S = 64 at 384x512: half of each). Nothing in a 3x3 conv, a BatchNorm
+    over (batch, H, W), an ELU or a nearest upsample cares whether a plane
+    is one image or k strips with a halo row between them
+    (layers.reflect_pad_strips), so k is the least that makes N*k a multiple of
+    128. Past 4, or under 8 rows a strip, the halo rows outweigh the
+    padding: 1, which is the plain decoder.
+    """
+    k = LANES // math.gcd(planes_per_device, LANES)
+    return k if k <= 4 and rows % k == 0 and rows // k >= 8 else 1
 
 
 def depth_to_space_2x(x):
@@ -66,6 +88,9 @@ class MPIDecoder(nn.Module):
     #   upconv_0_0/upconv_0_1/dispconv_0 weights map EXACTLY onto the
     #   packed kernels (phase-replicated BN params; interior-exact —
     #   reflect padding at stride 2 differs from stride 1 in a 2px border).
+    #   (The premise did not hold on the chip: the compiler lays these
+    #   stages out batch-minor, the lanes hold the batch and not the
+    #   channels — fold_strips, PERF.md section 5.)
     variant: str = "reference"
     dtype: Optional[jnp.dtype] = None
     # jax.sharding.Mesh (hashable): when set, the B*S decoder batch is
@@ -141,13 +166,27 @@ class MPIDecoder(nn.Module):
         shared, tail = x, emb  # parts pending for the NEXT ConvBlock
         x = None               # the stem has no per-plane part
 
+        # From the first stage narrower than the lanes down to the heads
+        # the per-plane tensors are [B*S*k, h/k, w, C]: k row strips an
+        # image (fold_strips), image-major and strip-minor, so a device's
+        # shard of planes stays one contiguous block. Folded once, by a
+        # reshape; the heads' transposes below unfold.
+        k = 1
+        entry = max(i for i, c in enumerate(NUM_CH_DEC) if c < LANES)
+        shards = 1 if self.mesh is None else (
+            self.mesh.shape[DATA_AXIS] * self.mesh.shape[PLANE_AXIS])
+
         outputs = {}
         for i in range(4, -1, -1):
             packed = self.variant == "packed" and i == 0
             width = NUM_CH_DEC[i] * (4 if packed else 1)
+            if i == entry:
+                k = fold_strips(B * S // shards, x.shape[1])
+                x = x.reshape((B * S * k, x.shape[1] // k) + x.shape[2:])
             x = ConvBlock(width, dtype=self.dtype,
                           name=f"upconv_{i}_0{'p' if packed else ''}")(
-                              x, train, shared=shared, const_tail=tail)
+                              x, train, shared=shared, const_tail=tail,
+                              strips=k)
             shared = tail = None
             if not packed:  # packed stage 0 stays at stride 2 until its head
                 x = shard_bs(upsample_nearest_2x(x))
@@ -161,12 +200,14 @@ class MPIDecoder(nn.Module):
                 shared, tail = features[i - 1].astype(dd), emb
             x = ConvBlock(width, dtype=self.dtype,
                           name=f"upconv_{i}_1{'p' if packed else ''}")(
-                              x, train, shared=shared, const_tail=tail)
+                              x, train, shared=shared, const_tail=tail,
+                              strips=k)
             shared = tail = None
             if i in self.scales:
                 out = Conv(self.num_output_channels * (4 if packed else 1),
                            3, pad_mode="reflect", dtype=self.dtype,
-                           name=f"dispconv_{i}{'p' if packed else ''}")(x)
+                           name=f"dispconv_{i}{'p' if packed else ''}")(
+                               x, strips=k)
                 if packed:
                     out = depth_to_space_2x(out)
                 out = out.astype(jnp.float32)  # rendering happens in fp32
@@ -175,15 +216,20 @@ class MPIDecoder(nn.Module):
                     sigma = nn.sigmoid(out[..., 3:4])
                 else:
                     sigma = jnp.abs(out[..., 3:4]) + 1e-4
+                fold = (k,) if k > 1 else ()
                 if self.sigma_dropout_rate > 0.0 and train:
-                    # whole-plane dropout (reference F.dropout2d on sigma)
+                    # whole-plane dropout (reference F.dropout2d on sigma):
+                    # one draw a plane, not one a strip
+                    planes = sigma.reshape((B * S,) + fold + sigma.shape[1:])
                     sigma = nn.Dropout(
                         rate=self.sigma_dropout_rate,
-                        broadcast_dims=(1, 2, 3),
-                        deterministic=not train)(sigma)
+                        broadcast_dims=tuple(range(1, planes.ndim)),
+                        deterministic=not train)(planes).reshape(sigma.shape)
                 mpi = jnp.concatenate([rgb, sigma], axis=-1)  # [B*S,h,w,4]
-                h, w = mpi.shape[1], mpi.shape[2]
-                # -> [B,S,4,h,w] for the rendering ops
-                outputs[i] = jnp.transpose(
-                    mpi.reshape(B, S, h, w, 4), (0, 1, 4, 2, 3))
+                # -> [B,S,4,h,w] for the rendering ops; with strips
+                # [B,S,k,h/k,w,4] -> [B,S,4,k,h/k,w], the same one pass
+                mpi = mpi.reshape((B, S) + fold + mpi.shape[1:])
+                mpi = jnp.moveaxis(mpi, -1, 2)
+                outputs[i] = mpi.reshape(mpi.shape[:3] + (-1, mpi.shape[-1]))
+        telemetry.gauge("model.decoder.fold_strips").set(k)
         return outputs

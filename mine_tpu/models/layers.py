@@ -30,6 +30,33 @@ def torch_bias_init(key, shape, dtype, fan_in: int):
     return jax.random.uniform(key, shape, dtype, -bound, bound)
 
 
+def reflect_pad_strips(x, strips: int):
+    """torch ReflectionPad2d(1) of an NHWC batch of images that arrive cut
+    into `strips` row strips folded into the batch, image-major and
+    strip-minor (row r of image n is row r % hs of batch entry n * strips +
+    r // hs, hs = h / strips; MPIDecoder folds, and says why).
+
+    W reflects within a strip. Along H a strip's halo rows are its
+    neighbours' edge rows, and only an image's first and last strip reflect:
+    padding the strips is padding the image, bit for bit, so a VALID 3x3
+    conv of the strips is the strips of the conv of the image.
+    """
+    x = jnp.pad(x, ((0, 0), (0, 0), (1, 1), (0, 0)), mode="reflect")
+
+    def row(r):
+        """Row r of every strip, strips apart: [N, k, 1, w + 2, C]. Only
+        these single rows are ever viewed with the strips as an axis; the
+        whole tensor keeps its four axes and so its layout on the chip."""
+        return x[:, r:r + 1].reshape(
+            (x.shape[0] // strips, strips, 1) + x.shape[2:])
+    hs = x.shape[1]
+    top = jnp.concatenate([row(1)[:, :1], row(hs - 1)[:, :-1]], axis=1)
+    bottom = jnp.concatenate([row(0)[:, 1:], row(hs - 2)[:, -1:]], axis=1)
+    halo = (x.shape[0], 1) + x.shape[2:]
+    return jnp.concatenate([top.reshape(halo), x, bottom.reshape(halo)],
+                           axis=1)
+
+
 class _PartsConv(nn.Module):
     """Conv over concat([x, expand(shared), broadcast(tail)], -1) that never
     forms the concat.
@@ -51,6 +78,10 @@ class _PartsConv(nn.Module):
     once to the per-plane conv's result, so y is rounded to `dtype` once
     after the conv. Autodiff transposes the broadcast to a sum over S: the
     shared part's input and weight gradients are convs at batch B too.
+
+    With `strips` = k > 1, x is [N*k, h/k, w, Cx] (reflect_pad_strips' folding)
+    and so is the result; shared and tail stay [B, h, w, Cs] and [N, E],
+    and the side term is viewed as strips before it is added.
     """
     features: int
     kernel_size: int
@@ -62,7 +93,7 @@ class _PartsConv(nn.Module):
     dtype: Optional[Dtype]
 
     @nn.compact
-    def __call__(self, x, shared, tail):
+    def __call__(self, x, shared, tail, strips: int = 1):
         k = self.kernel_size
         Cx, Cs, E = (0 if t is None else t.shape[-1]
                      for t in (x, shared, tail))
@@ -70,8 +101,10 @@ class _PartsConv(nn.Module):
                             (k, k, Cx + Cs + E, self.features), jnp.float32)
         bias = self.param("bias", self.bias_init, (self.features,),
                           jnp.float32) if self.use_bias else None
-        # output batch N = B*S; B is the shared part's batch when there is one
+        # planes N = B*S; B is the shared part's batch when there is one
         N = next(t for t in (x, tail, shared) if t is not None).shape[0]
+        if x is not None:
+            N //= strips
         B = N if shared is None else shared.shape[0]
         dt = self.dtype or jnp.promote_types(
             (shared if x is None else x).dtype, jnp.float32)
@@ -89,21 +122,28 @@ class _PartsConv(nn.Module):
             either way); the accumulation and the result stay float32."""
             return t.astype(dt).astype(jnp.float32)
 
-        # float32 side term: [F] + [B, 1, h, w, F] + [B, S, 1, 1, F]
+        # float32 side term: [F] + [B, 1, h, w, F] + [B, S, 1, 1, F], or
+        # with strips [F] + [B, 1, k, h/k, w, F] + [B, S, 1, 1, 1, F]
+        fold = (strips,) if strips > 1 else ()
         side = jnp.zeros((), jnp.float32) if bias is None else bias
         if shared is not None:
-            side = side + conv(f32(shared),
-                               f32(kernel[:, :, Cx:Cx + Cs]))[:, None]
+            s = conv(f32(shared), f32(kernel[:, :, Cx:Cx + Cs]))[:, None]
+            if fold:
+                s = s.reshape((B, 1) + fold + (s.shape[2] // strips,)
+                              + s.shape[3:])
+            side = side + s
         if tail is not None:
             w_tail = jnp.sum(kernel[:, :, Cx + Cs:], axis=(0, 1))  # [E, F]
             side = side + (f32(tail) @ f32(w_tail)).reshape(
-                B, N // B, 1, 1, self.features)
+                (B, N // B) + (1,) * len(fold) + (1, 1, self.features))
         if x is None:
+            assert not fold, "a conv with no per-plane part has no strips"
             y = side
         else:
             y = conv(x.astype(dt), kernel[:, :, :Cx].astype(dt))
-            y = y.reshape((B, N // B) + y.shape[1:]) + side
-        return y.reshape((N,) + y.shape[2:]).astype(dt)
+            y = y.reshape((B, N // B) + fold + y.shape[1:]) + side
+        lead = 2 + len(fold)
+        return y.reshape((-1,) + y.shape[lead:]).astype(dt)
 
 
 class Conv(nn.Module):
@@ -116,6 +156,9 @@ class Conv(nn.Module):
     broadcast ever existing (see _PartsConv). `x` may then be None (the
     whole input is shared + tail). Only valid with reflect padding (or
     none): zero padding breaks the constant-map identity at borders.
+
+    `strips` > 1: x arrives, and the result leaves, as row strips folded
+    into the batch (reflect_pad_strips); reflect padding by 1 only.
     """
     features: int
     kernel_size: int = 3
@@ -127,14 +170,18 @@ class Conv(nn.Module):
     dtype: Optional[Dtype] = None
 
     @nn.compact
-    def __call__(self, x, shared=None, const_tail=None):
+    def __call__(self, x, shared=None, const_tail=None, strips: int = 1):
         k = self.kernel_size
         p = (k - 1) // 2 if self.padding is None else self.padding
         pad = ((p, p), (p, p))
+        assert strips == 1 or (p == 1 and self.pad_mode == "reflect"), \
+            "strips need the halo of a reflect pad by 1"
         if p > 0 and self.pad_mode == "reflect":
-            x, shared = (t if t is None else jnp.pad(
-                t, ((0, 0), (p, p), (p, p), (0, 0)), mode="reflect")
-                for t in (x, shared))
+            def reflect(t):
+                return t if t is None else jnp.pad(
+                    t, ((0, 0), (p, p), (p, p), (0, 0)), mode="reflect")
+            x = reflect_pad_strips(x, strips) if strips > 1 else reflect(x)
+            shared = reflect(shared)
             pad = ((0, 0), (0, 0))
         fan_in = k * k * sum(t.shape[-1] for t in (x, shared, const_tail)
                              if t is not None)
@@ -148,7 +195,7 @@ class Conv(nn.Module):
                 strides=self.strides, padding=pad,
                 use_bias=self.use_bias, kernel_init=self.kernel_init,
                 bias_init=bias_init, dtype=self.dtype,
-                name="conv")(x, shared, const_tail)
+                name="conv")(x, shared, const_tail, strips)
         conv = nn.Conv(
             features=self.features,
             kernel_size=(k, k),
@@ -213,16 +260,20 @@ class ConvBlock(nn.Module):
     """Reflect-pad 3x3 conv (with bias) + BN + ELU.
 
     Reference: monodepth2/layers.py:106-120 (ConvBlock = Conv3x3 + BN + ELU,
-    Conv3x3 uses ReflectionPad2d). `shared` / `const_tail` are Conv's: BN
-    and ELU act on the summed conv output at the full batch either way.
+    Conv3x3 uses ReflectionPad2d). `shared` / `const_tail` / `strips` are
+    Conv's: BN and ELU act on the summed conv output at the full batch
+    either way (BN reduces over all axes but the last: the same elements
+    whether an image is one batch entry or `strips` of them).
     """
     features: int
     dtype: Optional[Dtype] = None
 
     @nn.compact
-    def __call__(self, x, train: bool, shared=None, const_tail=None):
+    def __call__(self, x, train: bool, shared=None, const_tail=None,
+                 strips: int = 1):
         x = Conv(self.features, 3, pad_mode="reflect", dtype=self.dtype,
-                 name="conv3x3")(x, shared=shared, const_tail=const_tail)
+                 name="conv3x3")(x, shared=shared, const_tail=const_tail,
+                                 strips=strips)
         x = BatchNorm(use_running_average=not train, dtype=self.dtype,
                       name="bn")(x)
         return nn.elu(x)

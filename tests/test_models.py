@@ -287,6 +287,184 @@ def test_parts_conv_equals_conv_over_concat(case, dtype):
             name, err(got, name), err(parent, name))
 
 
+# --- row strips folded into the batch (decoder.fold_strips) ---------------
+
+@pytest.mark.parametrize("per_device, rows, want", [
+    (64, 48, 2),     # llff_n32: B*S = 64 planes a chip, 48 rows at the entry
+    (32, 48, 4),     # eval and the serve encode: B = 1
+    (96, 48, 4),     # gcd 32
+    (128, 32, 1),    # re10k_n32: the lanes are full
+    (256, 32, 1),
+    (16, 48, 1),     # 8 strips: more halo than padding
+    (8, 4, 1),       # the tiny programs of tools/analysis_baseline.json
+    (64, 12, 1),     # strips of 6 rows
+    (64, 17, 1),     # rows the strips do not divide
+])
+def test_fold_strips_follows_from_shapes(per_device, rows, want):
+    from mine_tpu.models.decoder import fold_strips
+    assert fold_strips(per_device, rows) == want
+
+
+@pytest.mark.parametrize("k", [1, 2, 3, 4])
+def test_reflect_pad_of_strips_is_the_strips_of_the_padded_image(k):
+    """Bit for bit: strip s of the halo pad is rows [s*hs, s*hs + hs + 2)
+    of jnp.pad(mode="reflect") of the unfolded image."""
+    from mine_tpu.models.layers import reflect_pad_strips
+    N, hs, w, C = 3, 4, 5, 2
+    img = jax.random.normal(jax.random.PRNGKey(k), (N, k * hs, w, C))
+    got = reflect_pad_strips(img.reshape(N * k, hs, w, C), k)
+    padded = jnp.pad(img, ((0, 0), (1, 1), (1, 1), (0, 0)), mode="reflect")
+    want = jnp.stack([padded[:, s * hs:s * hs + hs + 2] for s in range(k)],
+                     axis=1).reshape(N * k, hs + 2, w + 2, C)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _fold_gauge():
+    from mine_tpu import telemetry
+    return telemetry.REGISTRY.snapshot()["model.decoder.fold_strips"]
+
+
+def _decoder_run(k, variant, use_skips, chunks, natural=False, dropout=0.0):
+    """A jitted decoder forward + backward: outputs, batch_stats updates
+    and gradients w.r.t. every parameter and encoder feature. `natural`:
+    the least shapes at which k strips engage by themselves (128 / k
+    planes a call, strips of 8 rows at the entry); else a few planes of
+    strips of 8 / k rows, for a test that forces k."""
+    if natural:
+        (B, S), H = {2: (2, 32), 4: (1, 32)}[k], 64 * k
+    else:
+        (B, S), H = (2, 3), 64
+    S, W = S * chunks, 64
+    chans = num_ch_enc(18)
+    rng = np.random.RandomState(k)
+    feats = [jnp.asarray(rng.normal(size=(
+        B, H // 2 ** (i + 1), W // 2 ** (i + 1), c)).astype(np.float32))
+        for i, c in enumerate(chans)]
+    disp = jnp.asarray(rng.uniform(0.1, 1.0, size=(B, S)).astype(np.float32))
+    if chunks == 1:
+        model = MPIDecoder(num_ch_enc=chans, variant=variant,
+                           use_skips=use_skips, sigma_dropout_rate=dropout)
+        variables = jax.jit(lambda: model.init(
+            jax.random.PRNGKey(0), feats, disp, False))()
+        method = None
+    else:
+        assert use_skips and not dropout
+        model = MPIPredictor(num_layers=18, decoder_variant=variant,
+                             plane_chunks=chunks)
+        variables = jax.jit(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((B, H, W, 3)), disp,
+            train=False))()
+        method = "decode"
+
+    def loss(params, feats):
+        out, mut = model.apply(
+            {"params": params, "batch_stats": variables["batch_stats"]},
+            feats, disp, True, method=method, mutable=["batch_stats"],
+            rngs={"dropout": jax.random.PRNGKey(5)})
+        out = [out[s] for s in sorted(out)] if isinstance(out, dict) else out
+        return sum(jnp.mean(jnp.sin(3.0 * o)) for o in out), (out, mut)
+
+    def run():
+        (_, (out, mut)), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True))(variables["params"], feats)
+        return {"out": out, "batch_stats": mut["batch_stats"],
+                "params": grads[0], "features": grads[1]}
+    return run
+
+
+FOLD_CASES = [(k, variant, use_skips, chunks, False)
+              for k in (2, 4) for variant in ("reference", "packed")
+              for use_skips, chunks in ((True, 1), (False, 1), (True, 2))]
+FOLD_CASES += [(2, "reference", True, 1, True), (4, "reference", True, 1, True)]
+
+
+@pytest.mark.parametrize("k, variant, use_skips, chunks, natural", FOLD_CASES)
+def test_folded_decoder_is_the_plain_decoder(k, variant, use_skips, chunks,
+                                             natural, monkeypatch):
+    """Float32: the four outputs, the batch_stats updates and every
+    gradient of the decoder on k strips equal the k = 1 decoder's, to the
+    noise of float32 sums taken in another order (readings up to 1.0e-4
+    of a module's scale, on a conv bias whose gradient sums a whole
+    level's cotangents; one wrong halo row reads 1e-1). The natural cases
+    (N = 64 and N = 32 planes) fold by themselves; the others are a few
+    planes with k forced, as the k = 1 side always is."""
+    from mine_tpu.models import decoder
+    run = _decoder_run(k, variant, use_skips, chunks, natural)
+    if not natural:
+        monkeypatch.setattr(decoder, "fold_strips", lambda *a: k)
+    got = run()
+    assert _fold_gauge() == k
+    monkeypatch.setattr(decoder, "fold_strips", lambda *a: 1)
+    want = run()
+    assert _fold_gauge() == 1
+
+    def scale_of(leaf, module):
+        """A conv bias under a BatchNorm has a zero gradient: both sides
+        hold rounding noise there, so a leaf's scale is its module's."""
+        return max(float(jnp.max(jnp.abs(leaf))),
+                   max(float(jnp.max(jnp.abs(v)))
+                       for v in jax.tree_util.tree_leaves(module)))
+
+    for name in want:
+        flat_w = jax.tree_util.tree_flatten_with_path(want[name])[0]
+        flat_g = jax.tree_util.tree_leaves(got[name])
+        assert len(flat_w) == len(flat_g)
+        for (path, w), g in zip(flat_w, flat_g):
+            module = want[name]
+            for key in path[:-2] if name == "params" else ():
+                module = module[key.key]
+            # (the backbone's gradients through `decode` are zeros)
+            err = float(jnp.max(jnp.abs(g - w))) / max(scale_of(w, module),
+                                                       1e-30)
+            assert g.shape == w.shape and err < 5e-4, (
+                name, jax.tree_util.keystr(path), err)
+
+
+@pytest.mark.parametrize("k", [2, 4])
+def test_sigma_dropout_draws_one_mask_a_plane_on_strips(k, monkeypatch):
+    """Whole-plane dropout stays whole-plane where a plane is k batch
+    entries: every plane's sigma is all zero or nowhere zero, at every
+    scale, and the planes dropped are the k = 1 decoder's."""
+    from mine_tpu.models import decoder
+    run = _decoder_run(k, "reference", True, 1, dropout=0.5)
+    monkeypatch.setattr(decoder, "fold_strips", lambda *a: k)
+    got = run()["out"]
+    assert _fold_gauge() == k
+    monkeypatch.setattr(decoder, "fold_strips", lambda *a: 1)
+    want = run()["out"]
+    for g, w in zip(got, want):
+        dropped = np.asarray(g[:, :, 3] == 0.0)      # [B, S, h, w]
+        per_plane = dropped.reshape(dropped.shape[:2] + (-1,))
+        assert np.all(per_plane.all(-1) | ~per_plane.any(-1))
+        assert 0 < per_plane.all(-1).sum() < per_plane[..., 0].size
+        np.testing.assert_array_equal(dropped, np.asarray(w[:, :, 3] == 0.0))
+
+
+def test_fold_counts_the_planes_of_a_device_on_a_mesh(monkeypatch):
+    """B*S = 256 planes over a data mesh of 4 are 64 a device: two strips,
+    as on one chip at B*S = 64. The rule reads the mesh, not a key."""
+    from mine_tpu.models import decoder
+    from mine_tpu.parallel.mesh import make_mesh
+    seen = []
+    real = decoder.fold_strips
+    monkeypatch.setattr(decoder, "fold_strips",
+                        lambda n, rows: seen.append(n) or real(n, rows))
+    B, S, H, W = 8, 32, 128, 64
+    chans = num_ch_enc(18)
+    feats = [jnp.zeros((B, H // 2 ** (i + 1), W // 2 ** (i + 1), c))
+             for i, c in enumerate(chans)]
+    disp = jnp.full((B, S), 0.5)
+    for mesh, want in ((make_mesh(data=4, devices=jax.devices()[:4]), 64),
+                       (make_mesh(data=2, plane=2,
+                                  devices=jax.devices()[:4]), 64),
+                       (None, 256)):
+        dec = MPIDecoder(num_ch_enc=chans, mesh=mesh)
+        jax.eval_shape(lambda: dec.init(jax.random.PRNGKey(0), feats, disp,
+                                        False))
+        assert seen[-1] == want
+        assert _fold_gauge() == (2 if want == 64 else 1)
+
+
 # --- what the decoder lowers to, and what its checkpoints hold -------------
 
 def _lowered_convs(text):

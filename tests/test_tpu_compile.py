@@ -175,6 +175,88 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_compile_cache):
         f"{case}: compiled, but no Pallas kernel is in the program")
 
 
+def _decoder_fwd_bwd(topo, devices, batch):
+    """The MPI decoder's forward + backward at llff_n32's shapes (384x512,
+    32 planes, ResNet-50 features, bfloat16) lowered for `devices` of the
+    described slice: one chip, or a data mesh with the batch sharded."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from mine_tpu.models.decoder import MPIDecoder
+    from mine_tpu.models.resnet import num_ch_enc
+    from mine_tpu.parallel.mesh import make_mesh
+    mesh = make_mesh(data=len(devices), devices=devices)
+    repl, by_batch = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    (H, W), chans = LLFF[0], num_ch_enc(50)
+    dec = MPIDecoder(num_ch_enc=chans, dtype=jnp.bfloat16,
+                     mesh=mesh if len(devices) > 1 else None)
+    feats = [jax.ShapeDtypeStruct(
+        (batch, H // 2 ** (i + 1), W // 2 ** (i + 1), c), jnp.bfloat16,
+        sharding=by_batch) for i, c in enumerate(chans)]
+    disp = jax.ShapeDtypeStruct((batch, S), jnp.float32, sharding=by_batch)
+    variables = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=repl),
+        jax.eval_shape(lambda f, d: dec.init(jax.random.PRNGKey(0), f, d,
+                                             False), feats, disp))
+
+    def loss(params, stats, feats, disp):
+        out, mut = dec.apply({"params": params, "batch_stats": stats},
+                             feats, disp, True, mutable=["batch_stats"])
+        return _sq(out), mut
+    with jax.default_matmul_precision("default"):
+        return jax.jit(jax.grad(loss, argnums=(0, 2), has_aux=True)).lower(
+            variables["params"], variables["batch_stats"], feats,
+            disp).compile()
+
+
+def test_decoder_fills_the_lanes_at_llff_n32(topo, no_compile_cache):
+    """B*S = 64 planes a chip run the narrow stages as 128 half-height
+    strips: the compiler keeps those tensors batch-minor ({0,3,2,1}: the
+    batch in the 128 lanes, now full, the channels on sublanes), and the
+    decoder's scratch is the full-lane program's (6.38 GB before the fold,
+    where each tile of 64 was padded to 128; 4.46 GB with it).
+
+    Not batch-minor, and allowed: a halo's single rows (h = 1), and the
+    float32 sums of upconv_2_1 / upconv_1_1 with their per-image side term,
+    which the compiler lays out W- or H-minor at B*S = 128 without any
+    fold too (re10k_n32)."""
+    import re
+    compiled = _decoder_fwd_bwd(topo, topo.devices[:1], batch=2)
+    assert compiled.memory_analysis().temp_size_in_bytes < 5.0e9
+    narrow = re.findall(r"(?:bf16|f32)\[(\d+),(\d+),\d+,(?:16|32|64)\]"
+                        r"\{([\d,]+)", compiled.as_text())
+    assert not [m for m in narrow if m[0] == "64"], "unfolded planes"
+    folded = [layout for n, h, layout in narrow if n == "128" and h != "1"]
+    off = [layout for layout in folded if layout != "0,3,2,1"]
+    assert len(folded) > 1000 and len(off) <= 4, (len(folded), off)
+
+
+def test_fold_adds_no_collective_on_the_2x2_mesh(topo, no_compile_cache,
+                                                 monkeypatch):
+    """mesh data: 4 at the per-chip batch of llff_train_dp4 (64 planes a
+    device, two strips): a device's shard of the folded batch is its own
+    planes' strips, so the halo crosses no device, and the decoder's
+    forward + backward holds the collectives of the unfolded one (SyncBN
+    statistics and the gradients' all-reduces)."""
+    import re
+
+    from mine_tpu import telemetry
+    from mine_tpu.models import decoder
+
+    def collectives():
+        """Collectives by channel: the compiler writes one all-gather out
+        as a chain of steps that share its channel_id."""
+        text = _decoder_fwd_bwd(topo, topo.devices, batch=8).as_text()
+        return {op: len(set(re.findall(
+            r" %s(?:-start)?\(.*?channel_id=(\d+)" % op, text)))
+            for op in ("all-reduce", "all-gather", "collective-permute",
+                       "all-to-all", "reduce-scatter")}
+    folded = collectives()
+    assert telemetry.REGISTRY.snapshot()["model.decoder.fold_strips"] == 2
+    monkeypatch.setattr(decoder, "fold_strips", lambda *a: 1)
+    assert folded == collectives()
+    assert folded["all-reduce"] > 0 and folded["collective-permute"] == 0
+
+
 def test_looplm_step_compiles_and_fits_v5e(one_chip, no_compile_cache,
                                            monkeypatch):
     """The looped language model's whole train step at its cell's sizes

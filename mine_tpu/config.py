@@ -11,7 +11,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import yaml
 
@@ -411,6 +411,36 @@ class ServeConfig:
     wire_codec: str = "f32"
     wire_coalesce_ms: float = 0.0
     wire_coalesce_max: int = 8
+
+
+@dataclasses.dataclass(frozen=True)
+class LMServeConfig:
+    """The token server's knobs (`serve.lm.*`; serve/lm_scheduler.py)."""
+    max_step_tokens: int = 2048
+    max_running: int = 32
+    page_size: int = 256
+    cache_tokens: int = 393216
+    chunk_buckets: Tuple[int, ...] = (512, 2048)
+    context_buckets: Tuple[int, ...] = (8192, 16384, 34816)
+
+
+def lm_serve_config_from_dict(config: Dict[str, Any]) -> LMServeConfig:
+    g = lambda k: config["serve.lm." + k]                      # noqa: E731
+    out = LMServeConfig(
+        max_step_tokens=int(g("max_step_tokens")),
+        max_running=int(g("max_running")), page_size=int(g("page_size")),
+        cache_tokens=int(g("cache_tokens")),
+        chunk_buckets=tuple(int(c) for c in g("chunk_buckets")),
+        context_buckets=tuple(int(c) for c in g("context_buckets")))
+    if out.cache_tokens % out.page_size:
+        raise ValueError("serve.lm.cache_tokens must be whole pages of "
+                         "serve.lm.page_size")
+    if max(out.chunk_buckets) > out.max_step_tokens:
+        raise ValueError("serve.lm.chunk_buckets may not exceed "
+                         "serve.lm.max_step_tokens")
+    if any(c % out.page_size for c in out.context_buckets):
+        raise ValueError("serve.lm.context_buckets must be whole pages")
+    return out
 
 
 def serve_config_from_dict(config: Dict[str, Any]) -> ServeConfig:

@@ -285,3 +285,251 @@ def plain_attention(q, k, v, heads: int):
     o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), split(v),
                    preferred_element_type=jnp.float32)
     return o.reshape(B, S, HD).astype(q.dtype)
+
+
+# ======================================================================
+# Latent attention for serving (models/moe_mla.py): forward only.
+#
+# `prefix_attention`: a prompt chunk's queries against a longer run of keys,
+# at UNEQUAL widths: a query/key head is 128 + 64 wide (a `nope` part of its
+# own and a rotary part whose key is shared by all heads), a value head 128.
+# 192 is no multiple of the 128 lanes, so the two parts arrive as two arrays
+# and the scores are the sum of two products; q row r stands at position
+# `q_offset + r` and sees keys 0..q_offset + r. `q_offset` is a runtime
+# scalar (scalar prefetch): one compiled program serves every prefix.
+#
+# `paged_latent_attention`: one query token a sequence, in latent space,
+# against that sequence's pages of the latent cache: every head's query
+# [H, rank + rope] reads each page [page, rank + rope] ONCE; the page's
+# first `rank` columns are also the values. Block tables and lengths are
+# scalar-prefetched; a page past a sequence's length is neither fetched nor
+# computed.
+#
+# `impl`: "pallas" on the chip, "interpret" (the same kernels interpreted),
+# "xla" the same functions in plain XLA (off the chip, and the tests' other
+# side).
+# ======================================================================
+
+def plain_prefix_attention(q_nope, q_rope, k_nope, k_rope, v, heads, q_offset,
+                           scale):
+    """q_nope [Tq, H*dn], q_rope [H, Tq, dr], k_nope [Tk, H*dn], k_rope
+    [Tk, dr] (one for all heads), v [Tk, H*dv] -> [Tq, H*dv]."""
+    Tq, Tk = q_nope.shape[0], k_nope.shape[0]
+    split = lambda x: x.reshape(x.shape[0], heads, -1)           # noqa: E731
+    s = jnp.einsum("qhd,khd->hqk", split(q_nope), split(k_nope),
+                   preferred_element_type=jnp.float32)
+    s = s + jnp.einsum("hqd,kd->hqk", q_rope, k_rope,
+                       preferred_element_type=jnp.float32)
+    s = s * scale
+    seen = (jnp.arange(Tk)[None, :] <= q_offset + jnp.arange(Tq)[:, None])
+    p = jax.nn.softmax(jnp.where(seen[None], s, _NEG), axis=-1)
+    o = jnp.einsum("hqk,khd->qhd", p.astype(v.dtype), split(v),
+                   preferred_element_type=jnp.float32)
+    return o.reshape(Tq, -1).astype(q_nope.dtype)
+
+
+def _prefix_kernel(off_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
+                   m_sc, l_sc, acc_sc, *, scale, bq, bk, nk):
+    i, j = pl.program_id(1), pl.program_id(2)
+    offset = off_ref[0]
+    # the last key block any row of this q block sees
+    last = jnp.minimum((offset + (i + 1) * bq - 1) // bk, nk - 1)
+
+    @pl.when(j == 0)
+    def _():
+        m_sc[...] = jnp.full(m_sc.shape, _NEG, jnp.float32)
+        l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    def step(masked):
+        v = v_ref[...]
+        s = (_dot(qn_ref[...], kn_ref[...], ((1,), (1,)))
+             + _dot(qr_ref[...], kr_ref[...], ((1,), (1,)))) * scale
+        if masked:
+            row = offset + i * bq + lax.broadcasted_iota(
+                jnp.int32, (bq, bk), 0)
+            col = j * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+            s = jnp.where(col <= row, s, _NEG)
+        m_prev, l_prev = m_sc[...], l_sc[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new[:, :1])
+        l_sc[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        acc_sc[...] = acc_sc[...] * alpha[:, :1] + _dot(
+            p.astype(v.dtype), v, ((1,), (0,)))
+        m_sc[...] = m_new
+
+    # every row of the q block sees the whole key block
+    whole = (j + 1) * bk - 1 <= offset + i * bq
+
+    @pl.when(whole)
+    def _():
+        step(False)
+
+    @pl.when(jnp.logical_and(jnp.logical_not(whole), j <= last))
+    def _():
+        step(True)
+
+    @pl.when(j == last)
+    def _():
+        o_ref[...] = (acc_sc[...] / l_sc[...][:, :1]).astype(o_ref.dtype)
+
+
+def prefix_blocks(Tq: int, Tk: int):
+    """(q block, key block): 1024 x 1024 where the lengths allow it. On the
+    v5e the kernel is bound by the vector unit's work per score, and the
+    per-block work on the running max, the normaliser and the accumulator
+    is amortised over a larger block: 2,048 queries against a 30k prefix
+    take 34.3 ms at 512 x 512 and 21.5 ms at 1024 x 1024 (chip run, PR 35)."""
+    pick = lambda n: next((b for b in (1024, 512, 256, 128)  # noqa: E731
+                           if n % b == 0), n)
+    return pick(Tq), pick(Tk)
+
+
+def prefix_attention(q_nope, q_rope, k_nope, k_rope, v, heads: int, q_offset,
+                     scale: float, impl: str = "pallas",
+                     blocks=None):
+    """Causal attention of a chunk's queries at positions `q_offset`.. over
+    keys 0..Tk-1 (see the section's comment for the layouts)."""
+    if impl == "xla":
+        return plain_prefix_attention(q_nope, q_rope, k_nope, k_rope, v,
+                                      heads, q_offset, scale)
+    Tq, Tk = q_nope.shape[0], k_nope.shape[0]
+    dn, dv, dr = q_nope.shape[1] // heads, v.shape[1] // heads, k_rope.shape[1]
+    bq, bk = blocks or prefix_blocks(Tq, Tk)
+    nq, nk = Tq // bq, Tk // bk
+
+    def kv_block(h, i, j, off):
+        return jnp.minimum(j, jnp.minimum((off[0] + (i + 1) * bq - 1) // bk,
+                                          nk - 1))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(heads, nq, nk),
+        in_specs=[
+            pl.BlockSpec((bq, dn), lambda h, i, j, off: (i, h)),
+            pl.BlockSpec((None, bq, dr), lambda h, i, j, off: (h, i, 0)),
+            pl.BlockSpec((bk, dn),
+                         lambda h, i, j, off: (kv_block(h, i, j, off), h)),
+            pl.BlockSpec((bk, dr),
+                         lambda h, i, j, off: (kv_block(h, i, j, off), 0)),
+            pl.BlockSpec((bk, dv),
+                         lambda h, i, j, off: (kv_block(h, i, j, off), h))],
+        out_specs=pl.BlockSpec((bq, dv), lambda h, i, j, off: (i, h)),
+        scratch_shapes=[pltpu.VMEM((bq, LANES), jnp.float32),
+                        pltpu.VMEM((bq, LANES), jnp.float32),
+                        pltpu.VMEM((bq, dv), jnp.float32)])
+    return pl.pallas_call(
+        functools.partial(_prefix_kernel, scale=scale, bq=bq, bk=bk, nk=nk),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((Tq, heads * dv), q_nope.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "arbitrary")),
+        name="mla_prefix_attention_fwd",
+        interpret=(impl == "interpret"),
+    )(jnp.asarray(q_offset, jnp.int32).reshape(1), q_nope, q_rope, k_nope,
+      k_rope, v)
+
+
+def plain_paged_latent_attention(q, cache, layer, tables, lengths, rank,
+                                 page_size, scale):
+    """q [B, H, rank + dr]; cache [L, rows, rank + dr]; tables [B, P] page
+    ids; lengths [B] -> o_lat [B, H, rank] float32 (zeros where length 0)."""
+    B, P = tables.shape
+    rows = (tables[:, :, None] * page_size
+            + jnp.arange(page_size)[None, None, :]).reshape(B, P * page_size)
+    kv = cache[layer, rows].astype(q.dtype)                 # [B, ctx, width]
+    s = jnp.einsum("bhd,bkd->bhk", q, kv,
+                   preferred_element_type=jnp.float32) * scale
+    seen = jnp.arange(P * page_size)[None, :] < lengths[:, None]
+    p = jax.nn.softmax(jnp.where(seen[:, None, :], s, _NEG), axis=-1)
+    p = jnp.where(seen[:, None, :], p, 0.0)
+    return jnp.einsum("bhk,bkd->bhd", p.astype(q.dtype), kv[:, :, :rank],
+                      preferred_element_type=jnp.float32)
+
+
+PAGES_A_STEP = 4   # pages of one sequence a grid step reads (a grid step
+                   # costs ~0.35 us whether its body runs or not: at one page
+                   # a step 32 sequences x 136 pages cost 1.4 ms a layer)
+
+
+def _paged_kernel(layer_ref, tables_ref, lengths_ref, q_ref, *refs, scale,
+                  rank, page_size, group):
+    page_refs, o_ref = refs[:group], refs[group]
+    m_sc, l_sc, acc_sc = refs[group + 1:]
+    b, j = pl.program_id(0), pl.program_id(1)
+    length = lengths_ref[b]
+    last = jnp.maximum((length + page_size * group - 1)
+                       // (page_size * group) - 1, 0)
+
+    @pl.when(j == 0)
+    def _():
+        m_sc[...] = jnp.full(m_sc.shape, _NEG, jnp.float32)
+        l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    for g, page_ref in enumerate(page_refs):
+        start = (j * group + g) * page_size
+
+        @pl.when(start < length)
+        def _(page_ref=page_ref, start=start):
+            q, page = q_ref[...], page_ref[...]
+            s = _dot(q, page, ((1,), (1,))) * scale           # [H, page]
+            col = start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(col < length, s, _NEG)
+            m_prev, l_prev = m_sc[...], l_sc[...]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new[:, :1])
+            l_sc[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            acc_sc[...] = acc_sc[...] * alpha[:, :1] + _dot(
+                p.astype(page.dtype), page[:, :rank], ((1,), (0,)))
+            m_sc[...] = m_new
+
+    @pl.when(j == last)
+    def _():
+        l = l_sc[...][:, :1]
+        o_ref[...] = (acc_sc[...] / jnp.where(l > 0, l, 1.0)).astype(
+            o_ref.dtype)
+
+
+def paged_latent_attention(q, cache, layer, tables, lengths, rank: int,
+                           page_size: int, scale: float,
+                           impl: str = "pallas"):
+    """Decode attention in latent space through the block tables (see the
+    section's comment). -> o_lat [B, H, rank] float32."""
+    if impl == "xla":
+        return plain_paged_latent_attention(q, cache, layer, tables, lengths,
+                                            rank, page_size, scale)
+    B, H, width = q.shape
+    P = tables.shape[1]
+    group = PAGES_A_STEP if P % PAGES_A_STEP == 0 else 1
+
+    def page_of(g):
+        def index(b, j, layer, tables, lengths):
+            # a page past the sequence's last repeats it: nothing is fetched
+            last = jnp.maximum((lengths[b] + page_size - 1) // page_size - 1,
+                               0)
+            return (layer[0], tables[b, jnp.minimum(j * group + g, last)], 0)
+        return index
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3, grid=(B, P // group),
+        in_specs=[pl.BlockSpec((None, H, width),
+                               lambda b, j, *_: (b, 0, 0))] + [
+            pl.BlockSpec((None, page_size, width), page_of(g))
+            for g in range(group)],
+        out_specs=pl.BlockSpec((None, H, rank), lambda b, j, *_: (b, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((H, LANES), jnp.float32),
+                        pltpu.VMEM((H, LANES), jnp.float32),
+                        pltpu.VMEM((H, rank), jnp.float32)])
+    return pl.pallas_call(
+        functools.partial(_paged_kernel, scale=scale, rank=rank,
+                          page_size=page_size, group=group),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, rank), jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "arbitrary")),
+        name="mla_paged_latent_attention",
+        interpret=(impl == "interpret"),
+    )(jnp.asarray(layer, jnp.int32).reshape(1), tables.astype(jnp.int32),
+      lengths.astype(jnp.int32), q, *([cache] * group))

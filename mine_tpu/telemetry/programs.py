@@ -34,7 +34,11 @@ from typing import Callable, Dict, Optional
 # model's: models/looplm.py opens `lm_embed`, `lm_attention`, `lm_mlp` (inside
 # the scans' bodies: a scan is one `while` in the HLO, so the map names the
 # ops inside it), train/lm_loss.py and the pass's final norm and gate
-# `lm_head_loss`; Adam and the guard are `optimizer` for both families.
+# `lm_head_loss`; Adam and the guard are `optimizer` for both families. The
+# token model's serve step (models/moe_mla.py): `lm_mla_proj` (norms,
+# projections, RoPE, W_o), `lm_mla_prefill`, `lm_mla_decode`, `lm_dense_mlp`,
+# `lm_moe_router`, `lm_moe_experts`, `lm_moe_shared`, `lm_head` (`lm_head_loss`
+# stands before it: the first prefix that matches names the layer).
 SCOPE_LAYERS = (
     ("encoder", "encoder"),
     ("decoder", "decoder"),
@@ -48,11 +52,23 @@ SCOPE_LAYERS = (
     ("lm_attention", "attention"),
     ("lm_mlp", "mlp"),
     ("lm_head_loss", "head_loss"),
+    ("lm_mla_proj", "mla_proj"),
+    ("lm_mla_prefill", "mla_prefill"),
+    ("lm_mla_decode", "mla_decode"),
+    ("lm_dense_mlp", "dense_mlp"),
+    ("lm_moe_router", "moe_router"),
+    ("lm_moe_experts", "moe_experts"),
+    ("lm_moe_shared", "moe_shared"),
+    ("lm_head", "head"),
 )
 # the layers that partition each model family's train step
 FAMILY_LAYERS = {
     "mine": ("encoder", "decoder", "render", "loss_pyramid", "optimizer"),
     "looplm": ("embed", "attention", "mlp", "head_loss", "optimizer"),
+    # the token model's serve step (serve/lm_engine.py)
+    "moe_mla": ("embed", "mla_proj", "mla_prefill", "mla_decode",
+                "dense_mlp", "moe_router", "moe_experts", "moe_shared",
+                "head"),
 }
 LAYERS = tuple(dict.fromkeys(
     layer for layers in FAMILY_LAYERS.values() for layer in layers))

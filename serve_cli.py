@@ -12,6 +12,16 @@ for the same image skip the encoder entirely. Prints the cache stats line
 and views/s at exit. Accepts a single image file or a directory of images;
 checkpoint handling (params.yaml next to the checkpoint, .npz or orbax)
 matches infer_cli.py.
+
+A token model is served through the same door (`model.family: moe_mla`):
+
+  python serve_cli.py --config_path mine_tpu/configs/params_kimi_k2p5.yaml \
+      --data_path requests.jsonl --output_dir out/ [--seed 0]
+
+builds the token server (`serve/lm_scheduler.py build_server`: weights from
+`--seed`, latent cache, step engine, scheduler), sends every line of
+`requests.jsonl` ({"doc_id", "document": [ids], "question": [ids],
+"max_tokens"}) and writes `answers.jsonl`.
 """
 
 import argparse
@@ -30,9 +40,75 @@ def _image_paths(data_path):
     return [data_path]
 
 
+def serve_token_model(args, config, compile_cache):
+    """`model.family: moe_mla`: every request of --data_path through the
+    token server, answers and the stats line out."""
+    import numpy as np
+
+    from mine_tpu import telemetry
+    from mine_tpu.config import telemetry_config_from_dict
+    from mine_tpu.serve.lm_scheduler import LMRequest, build_server
+    from mine_tpu.utils import describe_runtime, make_logger
+
+    os.makedirs(args.output_dir, exist_ok=True)
+    logger = make_logger(os.path.join(args.output_dir, "serve.log"))
+    logger.info("Runtime: %s", json.dumps(
+        dict(describe_runtime(), compile_cache=compile_cache)))
+    telem_cfg = telemetry_config_from_dict(config)
+    if telem_cfg.enabled:
+        telemetry.ensure_configured(
+            telem_cfg.events_path
+            or os.path.join(args.output_dir, "events.jsonl"),
+            max_mb=telem_cfg.events_max_mb, keep=telem_cfg.events_keep)
+    t0 = time.perf_counter()
+    server = build_server(config, seed=args.seed)
+    engine = server.engine
+    logger.info("token server: %d step programs %s warmed in %.1fs; latent "
+                "cache %s", len(engine.buckets()), engine.buckets(),
+                time.perf_counter() - t0, engine.cache.stats())
+    with open(args.data_path) as f:
+        lines = [json.loads(line) for line in f if line.strip()]
+    t0 = time.perf_counter()
+    futures = [server.submit(LMRequest(
+        question=np.asarray(r["question"], np.int32),
+        max_tokens=int(r["max_tokens"]), doc_id=r.get("doc_id"),
+        document=(np.asarray(r["document"], np.int32)
+                  if r.get("document") else None))) for r in lines]
+    results = [f.result() for f in futures]
+    dt = time.perf_counter() - t0
+    server.close()
+    with open(os.path.join(args.output_dir, "answers.jsonl"), "w") as f:
+        for r, res in zip(lines, results):
+            f.write(json.dumps({"doc_id": r.get("doc_id"),
+                                "tokens": [int(t) for t in res.tokens],
+                                "prompt_tokens": res.prompt_tokens,
+                                "cached_tokens": res.cached_tokens}) + "\n")
+    stats = telemetry.REGISTRY.snapshot("serve.lm.")
+    tokens_out = sum(len(res.tokens) for res in results)
+    logger.info("lm serve stats: requests=%d tokens_out=%d prompt_tokens=%d "
+                "prompt_tokens_cached=%d steps=%d evictions=%d "
+                "dropped_tokens=%d", len(results), tokens_out,
+                sum(res.prompt_tokens for res in results),
+                sum(res.cached_tokens for res in results), engine.steps,
+                int(stats.get("serve.lm.evictions", 0)),
+                int(stats.get("serve.lm.dropped_tokens", 0)))
+    logger.info("answered %d requests, %d tokens in %.2fs (%.1f tokens/s)",
+                len(results), tokens_out, dt, tokens_out / max(dt, 1e-9))
+    telemetry.spans.export()
+    telemetry.emit("metrics.snapshot", scope="serve_cli_end",
+                   metrics=stats)
+
+
 def main():
     parser = argparse.ArgumentParser(description="Render-only serving")
-    parser.add_argument("--checkpoint_path", type=str, required=True)
+    parser.add_argument("--checkpoint_path", type=str, default=None,
+                        help="required unless --config_path names a token "
+                             "model (model.family: moe_mla)")
+    parser.add_argument("--config_path", type=str, default=None,
+                        help="a model YAML; with model.family moe_mla the "
+                             "token server answers --data_path's requests")
+    parser.add_argument("--seed", type=int, default=0,
+                        help="token model: the seed of its weights")
     parser.add_argument("--data_path", type=str, required=True,
                         help="image file or directory of images")
     parser.add_argument("--output_dir", type=str, required=True)
@@ -47,6 +123,15 @@ def main():
 
     from mine_tpu.utils import configure_compile_cache
     compile_cache = configure_compile_cache()
+
+    if args.config_path is not None:
+        from mine_tpu.config import load_config
+        lm_config = load_config(args.config_path,
+                                extra_config=args.extra_config)
+        if lm_config.get("model.family") == "moe_mla":
+            return serve_token_model(args, lm_config, compile_cache)
+    if args.checkpoint_path is None:
+        parser.error("--checkpoint_path is required for model.family mine")
 
     import cv2
     import numpy as np

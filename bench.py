@@ -516,7 +516,7 @@ def _measure_warppass(name, steps=MEASURE_STEPS, keep_run=False):
     timed region, the scale-0 warp inputs are derived exactly as
     loss_per_scale derives them (unit scale factor), and each warp backend
     gets its own jitted value_and_grad of sum(homography_warp(volume))
-    with respect to the 7-channel plane volume. Per-backend img/s and the
+    with respect to the 4-channel plane volume. Per-backend img/s and the
     in-domain flag go to stderr (a 0.0 flag means that row priced the
     gather FALLBACK, not the banded path — same honesty rule as the
     warp_fallback_frac training metric); the JSON ips is the pallas_diff
@@ -547,12 +547,9 @@ def _measure_warppass(name, steps=MEASURE_STEPS, keep_run=False):
     p0 = loss_mod.build_scale_plan(batch, cfg, num_scales=1)[0]
     mpi = mpi_list[0]                                    # [B,S,4,H,W]
     B, S, _, H, W = mpi.shape
-    xyz_src = geometry.plane_xyz_src(p0.grid, disparity_all, p0.K_src_inv)
     G_tgt_src = jax.lax.stop_gradient(
         geometry.rigid_inverse(batch["G_src_tgt"]))
-    xyz_tgt = geometry.plane_xyz_tgt(xyz_src, G_tgt_src)
-    volume = jnp.concatenate([mpi[:, :, 0:3], mpi[:, :, 3:4], xyz_tgt],
-                             axis=2).reshape(B * S, 7, H, W)
+    volume = mpi[:, :, 0:4].reshape(B * S, 4, H, W)
     depths = (1.0 / disparity_all).reshape(B * S)
 
     def expand(x):

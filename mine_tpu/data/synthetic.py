@@ -52,17 +52,14 @@ def _render_views(mpi_rgb, mpi_sigma, disparity, K_33, G_V44):
     """The one canonical MPI [1,S,...] rendered into V poses, as ONE
     program (op by op this is ~100 small compiles per dataset, about a
     second each on the TPU). Returns (rgb [V,3,H,W], depth [V,H,W])."""
-    H, W = mpi_rgb.shape[-2:]
     V = G_V44.shape[0]
     K = jnp.broadcast_to(K_33, (V, 3, 3))
     K_inv = geometry.inverse_intrinsics(K)
     disp = jnp.broadcast_to(disparity, (V,) + disparity.shape[1:])
-    xyz_world = geometry.plane_xyz_src(geometry.cached_pixel_grid(H, W),
-                                       disp, K_inv)
     res = rendering.render_tgt_rgb_depth(
         jnp.broadcast_to(mpi_rgb, (V,) + mpi_rgb.shape[1:]),
         jnp.broadcast_to(mpi_sigma, (V,) + mpi_sigma.shape[1:]),
-        disp, geometry.plane_xyz_tgt(xyz_world, G_V44), G_V44, K_inv, K)
+        disp, G_V44, K_inv, K)
     return res.rgb, res.depth[:, 0]
 
 

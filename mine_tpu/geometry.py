@@ -176,6 +176,38 @@ def plane_xyz_tgt(xyz_src_BS3HW: jnp.ndarray, G_tgt_src: jnp.ndarray) -> jnp.nda
     return xyz.reshape(B, S, 3, H, W)
 
 
+def plane_xyz_tgt_at(x: jnp.ndarray,
+                     y: jnp.ndarray,
+                     d_src: jnp.ndarray,
+                     G_tgt_src: jnp.ndarray,
+                     K_src_inv: jnp.ndarray) -> jnp.ndarray:
+    """Target-frame plane points at continuous source pixels, in closed form.
+
+    plane_xyz_tgt(plane_xyz_src(...)) is, for one plane, affine in the source
+    pixel: X(x, y) = d (R K_src^-1) [x, y, 1] + t. Bilinear interpolation
+    reproduces an affine field exactly, so this IS the field sampled at
+    (x, y) — without the field, the gather or the MXU. Written as broadcast
+    multiply-adds in float32 (a default-precision einsum over the pixels
+    would be one bfloat16 pass on the TPU); plain autodiff, so it
+    differentiates in the depths and in G where the sampled field did.
+
+    One row a plane, as ops/warp.homography_coords takes them (B' is
+    typically B*S).
+
+    Args:
+      x, y: [B', H, W] source-pixel coordinates (the caller clips them to
+        the image where it wants border semantics)
+      d_src: [B'] plane depths; G_tgt_src: [B', 4, 4]; K_src_inv: [B', 3, 3]
+    Returns: [B', 3, H, W]
+    """
+    A = jnp.einsum("bij,bjk->bik", G_tgt_src[:, :3, :3], K_src_inv,
+                   precision="highest")[:, :, :, None, None]  # [B',3,3,1,1]
+    t = G_tgt_src[:, :3, 3, None, None]  # [B',3,1,1]
+    x, y = x[:, None], y[:, None]  # [B',1,H,W]
+    return d_src[:, None, None, None] \
+        * (A[:, :, 0] * x + A[:, :, 1] * y + A[:, :, 2]) + t
+
+
 def intrinsics_from_fov(height: int, width: int, fov_degrees: float = 90.0) -> np.ndarray:
     """Pinhole K from a horizontal FoV (reference: image_to_video.py:192-202)."""
     fov = np.deg2rad(fov_degrees)

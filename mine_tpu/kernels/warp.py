@@ -1,9 +1,11 @@
 """Pallas TPU kernel: banded bilinear gather for homography warping.
 
 The reference's hot warp op is grid_sample over a B*S x 7 x H x W plane
-volume (homography_sampler.py:138, called from mpi_rendering.py:214). On TPU
-a per-pixel gather is the worst-case memory pattern; this kernel restructures
-it around the TPU's strengths:
+volume (homography_sampler.py:138, called from mpi_rendering.py:214); since
+PR 36 the volume here is B*S x 4 x H x W (rgb + sigma: the plane points are
+a formula at the same coordinates, ops/rendering.py; the kernel is generic
+in C). On TPU a per-pixel gather is the worst-case memory pattern; this
+kernel restructures it around the TPU's strengths:
 
   * the source rows a target row samples from lie in a narrow band (camera
     trajectories are translation-dominated; the plane-induced homography maps
@@ -26,7 +28,12 @@ it around the TPU's strengths:
     this one-hot matrix (ledger, PR 28: `warp_roofline.*` 3.9-4.9%); the
     windowed form takes 9.3 ms against 28.1 at 64x7x384x512, band 48 (my
     chip run, PR 29; 7.8 with its lane-tile loop unrolled, _lane_tiles)
-    and is bit-identical there.
+    and is bit-identical there. At the four channels the step warps since
+    PR 36 it takes 6.1 ms in llff_train's step against 8.0 for seven (my
+    chip run, PR 36; 1.29 / 1.60 at 192x256): about 3.6 ms of a call do
+    not scale with C (the tent weights' build and, by the product's shape,
+    the MXU loading each unit's [K, 128] weights for 64 streamed rows where
+    it had 112; the two were not measured apart).
 
 Correctness domain: a row-block's source y-span must fit in BAND-2 rows
 (after clamping to the image). The span includes the block's own extent —
@@ -80,7 +87,7 @@ def _lane_tiles(T: int, TILE: int, body, unroll: bool = False):
     4 on the chip's host against 0.08 s for the whole-band kernel, +12 s of
     warm set-up over the serve cell's ten render programs. The straight run
     of code is faster on the device: the 64x7x384x512 pair takes 7.8 + 10.1
-    ms unrolled, 9.3 + 11.7 as loops (v5e, PR 29). So the forward, which
+    ms unrolled, 9.3 + 11.7 as loops (v5e, PR 29; channels were 7 then). So the forward, which
     serving starts many programs of, loops; the backward, which only the
     train step holds, unrolls."""
     if T == 1:

@@ -4,7 +4,9 @@ Makes the banded bilinear-gather kernel (kernels.warp) usable in the
 TRAINING path, replacing the vmapped per-pixel gather (ops/warp.py
 bilinear_sample) whose scatter/gather lowering is the worst-case TPU memory
 pattern for the reference's hot warp op (homography_sampler.py:138 over a
-B*S x 7 x H x W volume, called from mpi_rendering.py:214). Measured on v5e
+B*S x 7 x H x W volume, called from mpi_rendering.py:214; B*S x 4 x H x W
+here since PR 36, the plane points being a formula and carrying no gradient
+to any parameter: ops/rendering.py). Measured on v5e
 (round 4): the gather/scatter fusions were 95% of the train step — 0.595
 img/s vs 7.99 with these kernels.
 
@@ -30,7 +32,10 @@ BLOCK"): its unit is the block's 8 rows of one lane tile, splatted into a
 sub-band of 24 rows and the forward's column window, the 8 rows summed
 inside one accumulation so d_src is read and written once per unit. The
 whole-band form was MXU-bound on zeros (27.5 ms at 64x7x384x512, band 48,
-v5e); this one takes 10.2 ms (my chip run, PR 29).
+v5e); this one takes 10.2 ms (my chip run, PR 29). At the step's four
+channels it takes 5.8 ms against 9.5 for seven in llff_train's step (my
+chip run, PR 36; 1.22 / 1.83 at 192x256), and its resident d_src block is
+3.1 MB at 384x512 where it was 5.5.
 
 Because the backward mirrors the forward's band placement row-for-row, it
 is the EXACT adjoint of the actual (band-clamped) forward everywhere —

@@ -4,7 +4,7 @@ This is the workload's true "sequence parallelism" (SURVEY.md section 5,
 long-context row): the reference keeps the whole S-plane volume on one
 device and composites with a serial cumprod (mpi_rendering.py:42-67); the
 GSPMD fallback for an S-sharded volume is an all-gather of the full
-7-channel volume. Here each device composites ONLY its local planes and the
+warped volume. Here each device composites ONLY its local planes and the
 cross-shard combination rides two tiny collectives:
 
   1. one `ppermute` halo exchange of the FIRST plane's xyz per shard (the

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from mine_tpu import geometry
 from mine_tpu.ops import warp
+from mine_tpu.parallel.mesh import DATA_AXIS, PLANE_AXIS, constrain, shard_map
 
 
 def alpha_composition(alpha_BK1HW: jnp.ndarray,
@@ -148,7 +149,6 @@ class TgtRender(NamedTuple):
 def render_tgt_rgb_depth(mpi_rgb_src: jnp.ndarray,
                          mpi_sigma_src: jnp.ndarray,
                          mpi_disparity_src: jnp.ndarray,
-                         xyz_tgt_BS3HW: jnp.ndarray,
                          G_tgt_src: jnp.ndarray,
                          K_src_inv: jnp.ndarray,
                          K_tgt: jnp.ndarray,
@@ -161,15 +161,19 @@ def render_tgt_rgb_depth(mpi_rgb_src: jnp.ndarray,
                          mesh=None) -> TgtRender:
     """Render the MPI into a target camera.
 
-    Concatenates [rgb, sigma, xyz_tgt] into a 7-channel plane volume, warps all
-    S planes with per-plane homographies (flattened to a B*S batch), zeroes
-    density where the warped point is behind the target camera (z<0), and
-    composites. Reference: mpi_rendering.render_tgt_rgb_depth
-    (mpi_rendering.py:181-241).
+    Concatenates [rgb, sigma] into a 4-channel plane volume, warps all S
+    planes with per-plane homographies (flattened to a B*S batch), evaluates
+    the target-frame plane points at the warp's own (border-clipped) source
+    coordinates, zeroes density where that point is behind the target camera
+    (z<0), and composites. The reference (mpi_rendering.render_tgt_rgb_depth,
+    mpi_rendering.py:181-241) warps the points as three more channels; a
+    plane's points are affine in the source pixel, so their bilinear sample
+    is geometry.plane_xyz_tgt_at's formula, in float32 and with no gradient
+    for the backward warp to carry.
 
     Args:
       mpi_rgb_src: [B,S,3,H,W]; mpi_sigma_src: [B,S,1,H,W]
-      mpi_disparity_src: [B,S]; xyz_tgt_BS3HW: [B,S,3,H,W]
+      mpi_disparity_src: [B,S]
       G_tgt_src: [B,4,4]; K_src_inv, K_tgt: [B,3,3]
       mesh: ("data","plane") Mesh — on multi-device meshes the Pallas
         backends run under shard_map (warp: B*S split over data*plane;
@@ -179,32 +183,40 @@ def render_tgt_rgb_depth(mpi_rgb_src: jnp.ndarray,
     B, S, _, H, W = mpi_rgb_src.shape
     mpi_depth_src = 1.0 / mpi_disparity_src  # [B,S]
 
-    volume = jnp.concatenate([mpi_rgb_src, mpi_sigma_src, xyz_tgt_BS3HW], axis=2)
-    volume_bs = volume.reshape(B * S, 7, H, W)
+    volume = jnp.concatenate([mpi_rgb_src, mpi_sigma_src], axis=2)
+    volume_bs = volume.reshape(B * S, 4, H, W)
 
     def expand(x):
         return jnp.repeat(x, S, axis=0)  # [B,...] -> [B*S,...] (plane-major per b)
 
-    grid = geometry.cached_pixel_grid(H, W)
-    warped, valid, warp_in_domain, warp_subband = warp.homography_warp(
-        volume_bs,
-        mpi_depth_src.reshape(B * S),
-        expand(G_tgt_src),
-        expand(K_src_inv),
-        expand(K_tgt),
-        grid,
+    d_bs = mpi_depth_src.reshape(B * S)
+    G_bs, K_src_inv_bs = expand(G_tgt_src), expand(K_src_inv)
+    src_x, src_y, valid = warp.homography_coords(
+        d_bs, G_bs, K_src_inv_bs, expand(K_tgt),
+        geometry.cached_pixel_grid(H, W), (H, W))
+    warped, warp_in_domain, warp_subband = warp.sample_planes(
+        volume_bs, src_x, src_y,
         impl=warp_impl,
         band=warp_band,
         mesh=mesh,
         mxu_dtype=jnp.bfloat16 if warp_dtype == "bfloat16" else jnp.float32,
-        with_domain_flag=True,
         with_subband_frac=True,
     )
 
-    warped = warped.reshape(B, S, 7, H, W)
+    warped = warped.reshape(B, S, 4, H, W)
     tgt_rgb = warped[:, :, 0:3]
     tgt_sigma = warped[:, :, 3:4]
-    tgt_xyz = warped[:, :, 4:7]
+    # the clip is grid_sample(border)'s, the one every sampler applies
+    # (ops/warp._lerp_gather, kernels/warp.band_plan): out-of-image pixels
+    # read the point of the border texel they sample
+    tgt_xyz = geometry.plane_xyz_tgt_at(
+        jnp.clip(src_x, 0.0, W - 1.0), jnp.clip(src_y, 0.0, H - 1.0),
+        d_bs, G_bs, K_src_inv_bs).reshape(B, S, 3, H, W)
+    # on a train mesh the points lie as train/loss.py lays out the MPI they
+    # are composited with. (The serve fleet passes no mesh: its program is
+    # partitioned from its operands' shardings alone, and
+    # tests/test_serve_fleet.py holds its result to a single device's.)
+    tgt_xyz = constrain(tgt_xyz, mesh, DATA_AXIS, PLANE_AXIS)
 
     if mesh is not None and mesh.size > 1 \
             and B % mesh.shape.get("data", 1) != 0 and backend != "xla":
@@ -219,7 +231,6 @@ def render_tgt_rgb_depth(mpi_rgb_src: jnp.ndarray,
         # (ops/plane_scan.py) — the volume stays plane-sharded end to end.
         # Requires a multi-device plane-divisible mesh (see the config
         # comment in params_default.yaml); otherwise the XLA composite.
-        from mine_tpu.parallel.mesh import PLANE_AXIS
         if not (mesh is not None and mesh.size > 1 and not use_alpha
                 and S % mesh.shape.get(PLANE_AXIS, 1) == 0):
             _warn_backend_fallback(
@@ -260,11 +271,9 @@ def render_tgt_rgb_depth(mpi_rgb_src: jnp.ndarray,
         if mesh is not None and mesh.size > 1:
             # batch over "data"; the plane axis is gathered to each device
             # (the transparency cumprod chains over S — a distributed scan
-            # over "plane" is possible but the all-gather of the 7ch volume
+            # over "plane" is possible but the all-gather of the warped volume
             # matches what GSPMD inserts for the XLA composite anyway)
             from jax.sharding import PartitionSpec as P
-
-            from mine_tpu.parallel.mesh import DATA_AXIS, shard_map
             fn = shard_map(fn, mesh=mesh,
                            in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS)),
                            out_specs=(P(DATA_AXIS), P(DATA_AXIS)))

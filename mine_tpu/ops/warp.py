@@ -2,9 +2,11 @@
 
 Replaces the reference's HomographySample (homography_sampler.py:10-141),
 whose hot op is `F.grid_sample(padding_mode='border', align_corners=False)`
-over a B*S x 7 x H x W volume. On TPU this is a gather; the XLA path below is
-the reference implementation, designed so a Pallas kernel with the same
-contract can slot in as the fused fast path.
+over a B*S x 7 x H x W volume (rgb, sigma and the plane points; here the
+volume is the four learned channels, and the points are a formula at the
+same coordinates: ops/rendering.render_tgt_rgb_depth). On TPU this is a
+gather; the XLA path below is the reference implementation, designed so a
+Pallas kernel with the same contract can slot in as the fused fast path.
 
 Sampling semantics (must match for checkpoint parity — SURVEY.md section 7
 "hard parts" #1): the reference normalizes pixel coords p to grid
@@ -18,6 +20,8 @@ coordinates with border clamping. We implement that directly, skipping the
 from __future__ import annotations
 
 import functools
+from typing import Tuple
+
 import jax
 import jax.numpy as jnp
 
@@ -38,7 +42,7 @@ def bilinear_sample(src: jnp.ndarray,
       coords_x, coords_y: [B, Ho, Wo] sample locations in src pixel coords
       gather_dtype: optional storage dtype for the gathered FORWARD values
         (jnp.bfloat16 halves the forward HBM read of the hot
-        B*S x 7 x H x W volume at ~2^-8 relative value rounding; the lerp
+        B*S x 4 x H x W volume at ~2^-8 relative value rounding; the lerp
         runs in float32 and the BACKWARD scatter-add accumulates in float32
         via a custom VJP — a bf16 scatter would drop contributions below
         ~2^-8 of the running sum wherever many target pixels hit the same
@@ -124,70 +128,35 @@ _bilinear_sample_cast.defvjp(_bsc_fwd, _bsc_bwd)
 WARP_IMPLS = ("xla", "pallas", "pallas_diff")
 
 
-def homography_warp(src_BCHW: jnp.ndarray,
-                    d_src: jnp.ndarray,
-                    G_tgt_src: jnp.ndarray,
-                    K_src_inv: jnp.ndarray,
-                    K_tgt: jnp.ndarray,
-                    meshgrid_tgt: jnp.ndarray,
-                    impl: str = "xla",
-                    band: int = 16,
-                    mesh=None,
-                    mxu_dtype=jnp.float32,
-                    with_domain_flag: bool = False,
-                    with_subband_frac: bool = False):
-    """Warp source-plane images into the target camera via inverse homography.
+def homography_coords(d_src: jnp.ndarray,
+                      G_tgt_src: jnp.ndarray,
+                      K_src_inv: jnp.ndarray,
+                      K_tgt: jnp.ndarray,
+                      meshgrid_tgt: jnp.ndarray,
+                      src_hw: Tuple[int, int]):
+    """The coordinate half of the warp: where each target pixel samples its
+    source plane.
 
     For each batch element: compose H_tgt_src = K_tgt (R - t n^T / -d) K_src^-1,
     invert it (closed form, no grad — matching the reference's no_grad inverse,
-    homography_sampler.py:112-113), map the target pixel grid into source
-    pixels, bilinear-sample with border padding, and report which target pixels
-    landed inside the source image.
-
-    Reference: HomographySample.sample (homography_sampler.py:58-141).
+    homography_sampler.py:112-113) and map the target pixel grid into source
+    pixels. The coordinates are constants to autodiff (stop_gradient), so
+    whatever is sampled or evaluated at them differentiates in its values
+    alone. Reference: HomographySample.sample (homography_sampler.py:58-141).
 
     Args:
-      src_BCHW: [B', C, H, W] plane images (B' is typically B*S)
-      d_src: [B'] plane depths
-      G_tgt_src: [B', 4, 4]
+      d_src: [B'] plane depths; G_tgt_src: [B', 4, 4]
       K_src_inv, K_tgt: [B', 3, 3]
       meshgrid_tgt: [3, Ht, Wt] homogeneous target pixel grid
-      impl: one of WARP_IMPLS. "xla" (gather; autodiffed; the reference and
-        the guarded fallback), "pallas" (banded MXU gather kernel,
-        forward-only; caller must validate the band via
-        kernels.warp.band_span), or "pallas_diff" (banded fwd+bwd kernels
-        with a built-in runtime gather fallback — the Pallas training
-        backend). Anything else raises ValueError.
-      mesh: ("data","plane") jax Mesh. With impl="pallas_diff" on a
-        multi-device mesh the kernel runs under shard_map with the
-        flat B' axis split over data*plane (matching the decoder's B*S
-        layout, models/decoder.py shard_bs) — each device warps its local
-        planes, no cross-device traffic.
-      with_domain_flag: also return `in_domain`, a scalar f32 diagnostic —
-        the FRACTION of this call that took pallas_diff's fast path:
-        1.0 all-fast, 0.0 all on the runtime gather fallback, NaN for
-        backends with no guard (plain xla / forward-only pallas). Under a
-        sharded Pallas mesh the cond decides per shard, and the flag is
-        the pmean of the per-shard guards over data*plane — e.g. 0.75 when
-        one of four shards drew an out-of-band pose. Powers the
-        `warp_fallback_frac` training metric.
-      with_subband_frac: also return `subband_frac`, a scalar f32 — the
-        share of this call's (output row, lane tile) units that the
-        pallas_diff kernels contracted against their window alone
-        (kernels/warp.subband_frac), 0.0 for the part of the call on the
-        gather fallback, NaN for the other backends (the forward-only
-        `pallas` among them: serving reads no metric, and every render
-        program would pay the plan's trace a second time). Sharded like
-        `in_domain`. Powers `warp_subband_frac`.
+      src_hw: (H, W) of the source planes (decides `valid`)
     Returns:
-      tgt [B', C, Ht, Wt], valid_mask [B', Ht, Wt] (bool)
-      [, in_domain scalar f32 — only when with_domain_flag]
-      [, subband_frac scalar f32 — only when with_subband_frac]
+      x, y [B', Ht, Wt] float32 source-pixel coordinates (unclipped: every
+        sampler clips them to the pixel-centre box, grid_sample's border
+        mode), valid [B', Ht, Wt] bool — the target pixel landed inside the
+        source image.
     """
-    if impl not in WARP_IMPLS:
-        raise ValueError(
-            f"homography_warp impl={impl!r}: must be one of {WARP_IMPLS}")
-    Bp, C, H, W = src_BCHW.shape
+    H, W = src_hw
+    Bp = d_src.shape[0]
     _, Ht, Wt = meshgrid_tgt.shape
 
     H_tgt_src = geometry.homography_tgt_src(K_tgt, K_src_inv, G_tgt_src, d_src)
@@ -200,15 +169,61 @@ def homography_warp(src_BCHW: jnp.ndarray,
     y = src_xy[:, 1, :].reshape(Bp, Ht, Wt)
 
     valid = ((x > -1.0) & (x < float(W)) & (y > -1.0) & (y < float(H)))
+    return x, y, valid
 
-    # diagnostic only — mirrors pallas_diff's fallback decision
+
+def sample_planes(src_BCHW: jnp.ndarray,
+                  x: jnp.ndarray,
+                  y: jnp.ndarray,
+                  impl: str = "xla",
+                  band: int = 16,
+                  mesh=None,
+                  mxu_dtype=jnp.float32,
+                  with_subband_frac: bool = False):
+    """The sampling half of the warp: bilinear-sample `src_BCHW` with border
+    padding at `homography_coords`' (x, y), on the chosen implementation.
+
+    Args:
+      src_BCHW: [B', C, H, W] plane images (B' is typically B*S)
+      x, y: [B', Ht, Wt] source-pixel coordinates
+      impl: one of WARP_IMPLS. "xla" (gather; autodiffed; the reference and
+        the guarded fallback), "pallas" (banded MXU gather kernel,
+        forward-only; caller must validate the band via
+        kernels.warp.band_span), or "pallas_diff" (banded fwd+bwd kernels
+        with a built-in runtime gather fallback — the Pallas training
+        backend). Anything else raises ValueError.
+      mesh: ("data","plane") jax Mesh. With impl="pallas_diff" on a
+        multi-device mesh the kernel runs under shard_map with the
+        flat B' axis split over data*plane (matching the decoder's B*S
+        layout, models/decoder.py shard_bs) — each device warps its local
+        planes, no cross-device traffic.
+      with_subband_frac: compute `subband_frac` (below) on pallas_diff;
+        otherwise it is NaN (serving reads no metric, and every render
+        program would pay the plan's trace a second time).
+    Returns:
+      tgt [B', C, Ht, Wt];
+      in_domain, a scalar f32 diagnostic — the FRACTION of this call that
+        took pallas_diff's fast path: 1.0 all-fast, 0.0 all on the runtime
+        gather fallback, NaN for backends with no guard (plain xla /
+        forward-only pallas). Under a sharded Pallas mesh the cond decides
+        per shard, and the flag is the pmean of the per-shard guards over
+        data*plane — e.g. 0.75 when one of four shards drew an out-of-band
+        pose. Powers the `warp_fallback_frac` training metric;
+      subband_frac, a scalar f32 — the share of this call's (output row,
+        lane tile) units that the pallas_diff kernels contracted against
+        their window alone (kernels/warp.subband_frac), 0.0 for the part of
+        the call on the gather fallback, NaN for the other backends.
+        Sharded like `in_domain`. Powers `warp_subband_frac`.
+    """
+    if impl not in WARP_IMPLS:
+        raise ValueError(
+            f"sample_planes impl={impl!r}: must be one of {WARP_IMPLS}")
+    Bp = src_BCHW.shape[0]
+
+    # diagnostics only — mirror pallas_diff's fallback decision
     # (NaN = backend has no runtime guard to measure)
     in_domain = jnp.full((), jnp.nan, jnp.float32)
     subband = jnp.full((), jnp.nan, jnp.float32)
-
-    def result(tgt):
-        return (tgt, valid) + ((in_domain,) if with_domain_flag else ()) \
-            + ((subband,) if with_subband_frac else ())
 
     if impl == "pallas":
         from mine_tpu.kernels import on_tpu_backend
@@ -218,8 +233,9 @@ def homography_warp(src_BCHW: jnp.ndarray,
     elif impl == "pallas_diff":
         # training path: Pallas fwd+bwd with runtime gather fallback
         # outside the band's domain (kernels/warp_vjp.py). Coords are
-        # non-learnable (no-grad inverse above), so stop_gradient keeps the
-        # two branches' autodiff structurally identical.
+        # non-learnable (no-grad inverse in homography_coords), so
+        # stop_gradient keeps the two branches' autodiff structurally
+        # identical.
         from mine_tpu.kernels import on_tpu_backend
         from mine_tpu.kernels.warp_vjp import (
             bilinear_sample_diff_guarded, guard_ok, guarded_subband_frac)
@@ -263,8 +279,7 @@ def homography_warp(src_BCHW: jnp.ndarray,
                     sharded, mesh=mesh,
                     in_specs=(P(bs_axes), P(bs_axes), P(bs_axes)),
                     out_specs=(P(bs_axes), P(), P()))
-                tgt, in_domain, subband = sharded(src_BCHW, xs, ys)
-                return result(tgt)
+                return sharded(src_BCHW, xs, ys)
             # a bare pallas_call inside a GSPMD-partitioned program has
             # no partitioning spec — fall back to the autodiffed gather
             # for non-divisible batches (e.g. remainder eval examples);
@@ -283,4 +298,35 @@ def homography_warp(src_BCHW: jnp.ndarray,
         # training.warp_dtype reaches the gather too: bf16 storage halves
         # the volume's HBM traffic, lerp stays f32 (f32 is a no-op knob)
         tgt = bilinear_sample(src_BCHW, x, y, gather_dtype=mxu_dtype)
-    return result(tgt)
+    return tgt, in_domain, subband
+
+
+def homography_warp(src_BCHW: jnp.ndarray,
+                    d_src: jnp.ndarray,
+                    G_tgt_src: jnp.ndarray,
+                    K_src_inv: jnp.ndarray,
+                    K_tgt: jnp.ndarray,
+                    meshgrid_tgt: jnp.ndarray,
+                    impl: str = "xla",
+                    band: int = 16,
+                    mesh=None,
+                    mxu_dtype=jnp.float32,
+                    with_domain_flag: bool = False,
+                    with_subband_frac: bool = False):
+    """Warp source-plane images into the target camera via inverse homography:
+    `homography_coords` then `sample_planes` (their docstrings hold the
+    arguments; ops/rendering.render_tgt_rgb_depth calls the two halves itself,
+    because the composite evaluates the plane points at the same coordinates).
+
+    Returns:
+      tgt [B', C, Ht, Wt], valid_mask [B', Ht, Wt] (bool)
+      [, in_domain scalar f32 — only when with_domain_flag]
+      [, subband_frac scalar f32 — only when with_subband_frac]
+    """
+    x, y, valid = homography_coords(d_src, G_tgt_src, K_src_inv, K_tgt,
+                                    meshgrid_tgt, src_BCHW.shape[-2:])
+    tgt, in_domain, subband = sample_planes(
+        src_BCHW, x, y, impl=impl, band=band, mesh=mesh, mxu_dtype=mxu_dtype,
+        with_subband_frac=with_subband_frac)
+    return (tgt, valid) + ((in_domain,) if with_domain_flag else ()) \
+        + ((subband,) if with_subband_frac else ())

@@ -5,9 +5,13 @@ Decouples MPI *prediction* (the expensive encoder-decoder pass) from view
 jitted program renders P poses from R cached MPIs in a single device call:
 
     planes [R,S,4,H,W] (quantized)   ──dequant──┐
-    disparity [R,S], K/K_inv [R,3,3] ──xyz_src──┤ gather by idx [P]
+    disparity [R,S], K/K_inv [R,3,3] ───────────┤ gather by idx [P]
     idx [P] int32, G_tgt_src [P,4,4] ───────────┴─> render_tgt_rgb_depth
                                                     -> rgb [P,3,H,W], depth
+
+The four cached channels are all the warp moves: the plane points the
+composite needs are evaluated in closed form at the warp's coordinates
+(geometry.plane_xyz_tgt_at), so nothing is built over the R resident images.
 
 Pose and entry counts are padded to power-of-two buckets (identity poses /
 repeated entries, results sliced back), so the compile set is BOUNDED by
@@ -236,12 +240,8 @@ class RenderEngine:
             x = x * scales  # fused dequant: int8 never leaves this program
         rgb = x[:, :, 0:3]
         sigma = x[:, :, 3:4]
-        H, W = x.shape[-2], x.shape[-1]
-        grid = geometry.cached_pixel_grid(H, W)
-        xyz_src = geometry.plane_xyz_src(grid, disp, K_inv)  # [R,S,3,H,W]
-        xyz_tgt = geometry.plane_xyz_tgt(xyz_src[idx], G)
         res = rendering.render_tgt_rgb_depth(
-            rgb[idx], sigma[idx], disp[idx], xyz_tgt, G,
+            rgb[idx], sigma[idx], disp[idx], G,
             K_inv[idx], K[idx],
             use_alpha=self.use_alpha,
             is_bg_depth_inf=self.is_bg_depth_inf,

@@ -239,11 +239,9 @@ def render_per_scale(scale: int,
     t_scaled = G_tgt_src[:, 0:3, 3] / scale_factor[:, None]
     G_render = jax.lax.stop_gradient(
         G_tgt_src.at[:, 0:3, 3].set(t_scaled))
-    xyz_tgt = geometry.plane_xyz_tgt(xyz_src, G_render)
-    xyz_tgt = constrain(xyz_tgt, mesh, DATA_AXIS, PLANE_AXIS)
     with jax.named_scope(f"warp_composite_tgt_s{scale}"):
         res = rendering.render_tgt_rgb_depth(
-            mpi_rgb, mpi_sigma, disparity, xyz_tgt, G_render,
+            mpi_rgb, mpi_sigma, disparity, G_render,
             K_src_inv, K_tgt,
             use_alpha=cfg.use_alpha, is_bg_depth_inf=cfg.is_bg_depth_inf,
             backend=cfg.composite_backend,

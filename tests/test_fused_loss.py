@@ -118,9 +118,8 @@ def _ref_loss_per_scale(scale, mpi, disparity, batch, G_tgt_src, cfg,
 
     t_scaled = G_tgt_src[:, 0:3, 3] / scale_factor[:, None]
     G_render = jax.lax.stop_gradient(G_tgt_src.at[:, 0:3, 3].set(t_scaled))
-    xyz_tgt = geometry.plane_xyz_tgt(xyz_src, G_render)
     res = rendering.render_tgt_rgb_depth(
-        mpi_rgb, mpi_sigma, disparity, xyz_tgt, G_render, K_src_inv, K_tgt,
+        mpi_rgb, mpi_sigma, disparity, G_render, K_src_inv, K_tgt,
         use_alpha=cfg.use_alpha, is_bg_depth_inf=cfg.is_bg_depth_inf,
         backend=cfg.composite_backend, warp_impl=cfg.warp_backend,
         warp_band=cfg.warp_band, warp_dtype=cfg.warp_dtype, mesh=None)

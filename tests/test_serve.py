@@ -186,11 +186,8 @@ def _reference_render(planes_S4HW, disp_S, K_33, G_44, warp_impl):
     disp = disp_S[None]
     K = K_33[None]
     K_inv = geometry.inverse_intrinsics(K)
-    grid = geometry.cached_pixel_grid(H, W)
-    xyz_src = geometry.plane_xyz_src(grid, disp, K_inv)
-    xyz_tgt = geometry.plane_xyz_tgt(xyz_src, G_44[None])
     res = rendering.render_tgt_rgb_depth(
-        rgb, sigma, disp, xyz_tgt, G_44[None], K_inv, K,
+        rgb, sigma, disp, G_44[None], K_inv, K,
         use_alpha=False, is_bg_depth_inf=False, backend="xla",
         warp_impl=warp_impl, warp_band=48)
     return res.rgb[0], res.depth[0]
@@ -390,9 +387,6 @@ def _legacy_render_poses(gen, poses_F44, chunk):
     """VERBATIM replication of the pre-engine VideoGenerator chunk loop
     (git history: _render_chunk_impl + render_poses) — the bitwise baseline
     the engine-backed path must reproduce."""
-    grid = geometry.cached_pixel_grid(H, W)
-    xyz_src = geometry.plane_xyz_src(grid, gen.disparity, gen.K_inv)
-
     @functools.partial(jax.jit, static_argnames=("warp_impl",))
     def render_chunk(G_tgt_src_F44, warp_impl):
         F = G_tgt_src_F44.shape[0]
@@ -400,10 +394,9 @@ def _legacy_render_poses(gen, poses_F44, chunk):
         def tile(x):
             return jnp.broadcast_to(x, (F,) + x.shape[1:])
 
-        xyz_tgt = geometry.plane_xyz_tgt(tile(xyz_src), G_tgt_src_F44)
         res = rendering.render_tgt_rgb_depth(
             tile(gen.mpi_rgb), tile(gen.mpi_sigma),
-            tile(gen.disparity), xyz_tgt, G_tgt_src_F44,
+            tile(gen.disparity), G_tgt_src_F44,
             tile(gen.K_inv), tile(gen.K),
             use_alpha=gen.cfg.use_alpha,
             is_bg_depth_inf=gen.cfg.is_bg_depth_inf,

@@ -71,15 +71,15 @@ def _sq(out):
 
 
 def _warp_case(hw):
-    """fwd + VJP of the guarded training warp over all B*S planes (7
-    channels: rgb + sigma + xyz, ops/rendering.py)."""
+    """fwd + VJP of the guarded training warp over all B*S planes (4
+    channels: rgb + sigma, ops/rendering.py)."""
     def build():
         from mine_tpu.kernels.warp_vjp import bilinear_sample_diff_guarded
         fn = functools.partial(bilinear_sample_diff_guarded, band=BAND,
                                interpret=False)
         H, W = hw
         n = BATCH[hw] * S
-        shapes = [((n, 7, H, W), jnp.float32),
+        shapes = [((n, 4, H, W), jnp.float32),
                   ((n, H, W), jnp.float32), ((n, H, W), jnp.float32)]
         return jax.grad(lambda s, x, y: _sq(fn(s, x, y))), shapes
     return build
@@ -87,7 +87,8 @@ def _warp_case(hw):
 
 def _composite_case(hw):
     """fwd + VJP of the training composite, called as ops/rendering.py calls
-    it: rgb, sigma and xyz are slices of the warped 7-channel volume. (With
+    it: rgb and sigma are slices of the warped 4-channel volume, xyz is the
+    closed-form field beside it (no cotangent: nothing learns from it). (With
     three entry parameters and this file's sum-of-squares loss the compiler
     places the loss's fused cotangents beside the kernel and refuses 32x48:
     18.41 MiB of scoped VMEM against 16. The step never builds that program;
@@ -95,10 +96,10 @@ def _composite_case(hw):
     def build():
         from mine_tpu.kernels.composite_vjp import fused_volume_render_diff
         H, W = hw
-        shapes = [((BATCH[hw], S, 7, H, W), jnp.float32)]
+        shapes = [((BATCH[hw], S, c, H, W), jnp.float32) for c in (4, 3)]
         return jax.grad(
-            lambda v: _sq(fused_volume_render_diff(
-                v[:, :, 0:3], v[:, :, 3:4], v[:, :, 4:7], True, False,
+            lambda v, xyz: _sq(fused_volume_render_diff(
+                v[:, :, 0:3], v[:, :, 3:4], xyz, True, False,
                 False))), shapes
     return build
 
@@ -117,15 +118,15 @@ def _src_blend_case(hw):
 
 def _serve_warp_case(hw, views=SERVE_POSES):
     """The render engine's forward warp of one pose bucket: views x S
-    planes of the 7-channel volume (the ledger's `warp_bilinear_sample_fwd`
-    f32[32|64|128|256, 7, 384, 512] in llff_serve_steady)."""
+    planes of the 4-channel volume (`warp_bilinear_sample_fwd`
+    f32[32|64|128|256, 4, 384, 512] in llff_serve_steady's device_ops)."""
     def build():
         from mine_tpu.kernels.warp import pallas_bilinear_sample
         H, W = hw
         n = views * S
         return (functools.partial(pallas_bilinear_sample, band=SERVE_BAND,
                                   interpret=False),
-                [((n, 7, H, W), jnp.float32), ((n, H, W), jnp.float32),
+                [((n, 4, H, W), jnp.float32), ((n, H, W), jnp.float32),
                  ((n, H, W), jnp.float32)])
     return build
 

@@ -166,8 +166,6 @@ def _case_fn(case: str):
         disp = jnp.linspace(1.0, 0.05, S)[None]    # [1,S]
         K = jnp.asarray(geometry.intrinsics_from_fov(H, W, 90.0))[None]
         K_inv = geometry.inverse_intrinsics(K)
-        grid = geometry.cached_pixel_grid(H, W)
-        xyz_src = geometry.plane_xyz_src(grid, disp, K_inv)
         # straight-line dolly: small translations keep the warp in-band
         ts = jnp.linspace(-0.05, 0.05, F)
         G = jnp.broadcast_to(jnp.eye(4), (F, 4, 4)).at[:, 0, 3].set(ts)
@@ -176,9 +174,8 @@ def _case_fn(case: str):
             return jnp.broadcast_to(x, (F,) + x.shape[1:])
 
         def render(rgb_, sigma_, G_):
-            xyz_tgt = geometry.plane_xyz_tgt(tile(xyz_src), G_)
             res = rendering.render_tgt_rgb_depth(
-                tile(rgb_), tile(sigma_), tile(disp), xyz_tgt, G_,
+                tile(rgb_), tile(sigma_), tile(disp), G_,
                 tile(K_inv), tile(K), backend=backend,
                 warp_impl=warp_impl, warp_band=32)
             return res.rgb, res.depth

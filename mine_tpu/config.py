@@ -422,6 +422,7 @@ class LMServeConfig:
     cache_tokens: int = 393216
     chunk_buckets: Tuple[int, ...] = (512, 2048)
     context_buckets: Tuple[int, ...] = (8192, 16384, 34816)
+    window_cache_tokens: int = 0
 
 
 def lm_serve_config_from_dict(config: Dict[str, Any]) -> LMServeConfig:
@@ -431,7 +432,11 @@ def lm_serve_config_from_dict(config: Dict[str, Any]) -> LMServeConfig:
         max_running=int(g("max_running")), page_size=int(g("page_size")),
         cache_tokens=int(g("cache_tokens")),
         chunk_buckets=tuple(int(c) for c in g("chunk_buckets")),
-        context_buckets=tuple(int(c) for c in g("context_buckets")))
+        context_buckets=tuple(int(c) for c in g("context_buckets")),
+        window_cache_tokens=int(g("window_cache_tokens") or 0))
+    if out.window_cache_tokens % out.page_size:
+        raise ValueError("serve.lm.window_cache_tokens must be whole pages "
+                         "of serve.lm.page_size")
     if out.cache_tokens % out.page_size:
         raise ValueError("serve.lm.cache_tokens must be whole pages of "
                          "serve.lm.page_size")

@@ -305,10 +305,34 @@ def plain_attention(q, k, v, heads: int):
 # scalar-prefetched; a page past a sequence's length is neither fetched nor
 # computed.
 #
+# `masked_prefix_attention`: `prefix_attention` under a SELECTION: row r sees
+# the keys its row of `mask` [Tq, Tk] (int8) marks, which lie at or before
+# `q_offset + r`. What a short context takes under learned sparse attention
+# (dense up-projected attention, masked: the selected rows need no gather).
+#
+# `window_attention`: the same under a sliding window W, a head's query and
+# key in one piece (a sliding layer's head is 192 + 64 = 256 wide): row r
+# sees keys in [r - (W - 1), r] that stand at or after `first_valid` (a
+# runtime scalar: where the sequence starts inside the keys), and key blocks
+# wholly behind a q block's window are neither fetched nor computed.
+#
+# `index_scores`: the lightning indexer of learned sparse attention,
+# I[r, s] = sum_j w[r, j] relu(q[r, j] . k[s]) over the index heads j, one
+# key for all heads, causal with a runtime offset; float32 [Tq, Tk] out. The
+# [Tq, heads, Tk] products never leave the chip's fast memory.
+#
+# `gathered_latent_attention`: one query against ITS OWN gathered rows of a
+# latent cache (the rows a selection or a window names), in latent space;
+# plain XLA everywhere (the gather is the work; see PERF.md section 6,
+# PR 37), a block of queries at a time through `in_blocks`.
+#
 # `impl`: "pallas" on the chip, "interpret" (the same kernels interpreted),
 # "xla" the same functions in plain XLA (off the chip, and the tests' other
 # side).
 # ======================================================================
+
+MASKED = _NEG   # what a masked index score reads
+
 
 def plain_prefix_attention(q_nope, q_rope, k_nope, k_rope, v, heads, q_offset,
                            scale):
@@ -375,6 +399,141 @@ def _prefix_kernel(off_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
         o_ref[...] = (acc_sc[...] / l_sc[...][:, :1]).astype(o_ref.dtype)
 
 
+def _softmax_step(s, seen, v, m_sc, l_sc, acc_sc):
+    """One key block of the running softmax under a mask: scores s [bq, bk]
+    (scaled), seen [bq, bk], values v [bk, dv]; a row may see nothing of a
+    block its q block needs."""
+    s = jnp.where(seen, s, _NEG)
+    m_prev, l_prev = m_sc[...], l_sc[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.where(seen, jnp.exp(s - m_new[:, :1]), 0.0)
+    l_sc[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+    acc_sc[...] = acc_sc[...] * alpha[:, :1] + _dot(
+        p.astype(v.dtype), v, ((1,), (0,)))
+    m_sc[...] = m_new
+
+
+def _masked_kernel(off_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, mask_ref,
+                   o_ref, m_sc, l_sc, acc_sc, *, scale, bq, bk, nk):
+    """`_prefix_kernel` with a row's keys named by a mask (which holds the
+    causal order too: a key after its query is never marked)."""
+    i, j = pl.program_id(1), pl.program_id(2)
+    last = jnp.minimum((off_ref[0] + (i + 1) * bq - 1) // bk, nk - 1)
+
+    @pl.when(j == 0)
+    def _():
+        m_sc[...] = jnp.full(m_sc.shape, _NEG, jnp.float32)
+        l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    @pl.when(j <= last)
+    def _():
+        v = v_ref[...]
+        seen = mask_ref[...] != 0
+        s = (_dot(qn_ref[...], kn_ref[...], ((1,), (1,)))
+             + _dot(qr_ref[...], kr_ref[...], ((1,), (1,)))) * scale
+        _softmax_step(s, seen, v, m_sc, l_sc, acc_sc)
+
+    @pl.when(j == last)
+    def _():
+        l = l_sc[...][:, :1]
+        o_ref[...] = (acc_sc[...] / jnp.where(l > 0, l, 1.0)).astype(
+            o_ref.dtype)
+
+
+def masked_prefix_attention(q_nope, q_rope, k_nope, k_rope, v, mask,
+                            heads: int, q_offset, scale: float,
+                            impl: str = "pallas", blocks=None):
+    """`prefix_attention` over the keys `mask` [Tq, Tk] (int8, nonzero:
+    attend) marks; every marked key lies at or before its query (row r at
+    `q_offset + r`), so key blocks past a q block's last row are skipped."""
+    Tq, Tk = q_nope.shape[0], k_nope.shape[0]
+    if impl == "xla":
+        split = lambda x: x.reshape(x.shape[0], heads, -1)       # noqa: E731
+        s = (jnp.einsum("qhd,khd->hqk", split(q_nope), split(k_nope),
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("hqd,kd->hqk", q_rope, k_rope,
+                          preferred_element_type=jnp.float32)) * scale
+        seen = (mask != 0)[None]
+        p = jnp.where(seen, jax.nn.softmax(jnp.where(seen, s, _NEG), axis=-1),
+                      0.0)
+        o = jnp.einsum("hqk,khd->qhd", p.astype(v.dtype), split(v),
+                       preferred_element_type=jnp.float32)
+        return o.reshape(Tq, -1).astype(q_nope.dtype)
+    dn, dv, dr = q_nope.shape[1] // heads, v.shape[1] // heads, k_rope.shape[1]
+    bq, bk = blocks or prefix_blocks(Tq, Tk)
+    nq, nk = Tq // bq, Tk // bk
+
+    def kv_block(h, i, j, off):
+        return jnp.minimum(j, jnp.minimum((off[0] + (i + 1) * bq - 1) // bk,
+                                          nk - 1))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(heads, nq, nk),
+        in_specs=[
+            pl.BlockSpec((bq, dn), lambda h, i, j, off: (i, h)),
+            pl.BlockSpec((None, bq, dr), lambda h, i, j, off: (h, i, 0)),
+            pl.BlockSpec((bk, dn),
+                         lambda h, i, j, off: (kv_block(h, i, j, off), h)),
+            pl.BlockSpec((bk, dr),
+                         lambda h, i, j, off: (kv_block(h, i, j, off), 0)),
+            pl.BlockSpec((bk, dv),
+                         lambda h, i, j, off: (kv_block(h, i, j, off), h)),
+            pl.BlockSpec((bq, bk),
+                         lambda h, i, j, off: (i, kv_block(h, i, j, off)))],
+        out_specs=pl.BlockSpec((bq, dv), lambda h, i, j, off: (i, h)),
+        scratch_shapes=[pltpu.VMEM((bq, LANES), jnp.float32),
+                        pltpu.VMEM((bq, LANES), jnp.float32),
+                        pltpu.VMEM((bq, dv), jnp.float32)])
+    return pl.pallas_call(
+        functools.partial(_masked_kernel, scale=scale, bq=bq, bk=bk, nk=nk),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((Tq, heads * dv), q_nope.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "arbitrary")),
+        name="mla_masked_attention_fwd",
+        interpret=(impl == "interpret"),
+    )(jnp.asarray(q_offset, jnp.int32).reshape(1), q_nope, q_rope, k_nope,
+      k_rope, v, mask)
+
+
+def _window_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc,
+                   *, scale, bq, bk, nk, window):
+    """`_prefix_kernel` under a sliding window, a head's query and key in
+    one piece: `off_ref` = (q offset, first valid key)."""
+    i, j = pl.program_id(1), pl.program_id(2)
+    offset, first_valid = off_ref[0], off_ref[1]
+    first, last = _window_blocks(offset, first_valid, i, bq, bk, nk, window)
+
+    @pl.when(j == 0)
+    def _():
+        m_sc[...] = jnp.full(m_sc.shape, _NEG, jnp.float32)
+        l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    @pl.when(jnp.logical_and(j >= first, j <= last))
+    def _():
+        v = v_ref[...]
+        s = _dot(q_ref[...], k_ref[...], ((1,), (1,))) * scale
+        row = offset + i * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        col = j * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        seen = (col <= row) & (col > row - window) & (col >= first_valid)
+        _softmax_step(s, seen, v, m_sc, l_sc, acc_sc)
+
+    @pl.when(j == last)
+    def _():
+        o_ref[...] = (acc_sc[...] / l_sc[...][:, :1]).astype(o_ref.dtype)
+
+
+def _window_blocks(offset, first_valid, i, bq, bk, nk, window):
+    """(first, last) key block that q block i's window touches."""
+    lowest = jnp.maximum(offset + i * bq - (window - 1), first_valid)
+    first = jnp.maximum(lowest, 0) // bk
+    last = jnp.minimum((offset + (i + 1) * bq - 1) // bk, nk - 1)
+    return jnp.minimum(first, last), last
+
+
 def prefix_blocks(Tq: int, Tk: int):
     """(q block, key block): 1024 x 1024 where the lengths allow it. On the
     v5e the kernel is bound by the vector unit's work per score, and the
@@ -428,6 +587,171 @@ def prefix_attention(q_nope, q_rope, k_nope, k_rope, v, heads: int, q_offset,
         interpret=(impl == "interpret"),
     )(jnp.asarray(q_offset, jnp.int32).reshape(1), q_nope, q_rope, k_nope,
       k_rope, v)
+
+
+def plain_window_attention(q, k, v, heads, q_offset, scale, window,
+                           first_valid):
+    Tq, Tk = q.shape[0], k.shape[0]
+    split = lambda x: x.reshape(x.shape[0], heads, -1)           # noqa: E731
+    s = jnp.einsum("qhd,khd->hqk", split(q), split(k),
+                   preferred_element_type=jnp.float32) * scale
+    col, row = jnp.arange(Tk)[None, :], q_offset + jnp.arange(Tq)[:, None]
+    seen = (col <= row) & (col > row - window) & (col >= first_valid)
+    p = jax.nn.softmax(jnp.where(seen[None], s, _NEG), axis=-1)
+    o = jnp.einsum("hqk,khd->qhd", p.astype(v.dtype), split(v),
+                   preferred_element_type=jnp.float32)
+    return o.reshape(Tq, -1).astype(q.dtype)
+
+
+def window_attention(q, k, v, heads: int, q_offset, scale: float,
+                     window: int, first_valid=0, impl: str = "pallas",
+                     blocks=None):
+    """Sliding-window attention of a chunk's queries q [Tq, H*d] at key
+    indices `q_offset`.. over keys k [Tk, H*d], v [Tk, H*dv]: row r sees
+    the keys in [r - (window - 1), r] at or after `first_valid` (see the
+    section's comment). On the chip d and dv are multiples of the lanes."""
+    if impl == "xla":
+        return plain_window_attention(q, k, v, heads, q_offset, scale,
+                                      window, first_valid)
+    Tq, Tk = q.shape[0], k.shape[0]
+    d, dv = q.shape[1] // heads, v.shape[1] // heads
+    bq, bk = blocks or prefix_blocks(Tq, Tk)
+    nq, nk = Tq // bq, Tk // bk
+
+    def kv_block(h, i, j, off):
+        first, last = _window_blocks(off[0], off[1], i, bq, bk, nk, window)
+        return jnp.clip(j, first, last)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(heads, nq, nk),
+        in_specs=[
+            pl.BlockSpec((bq, d), lambda h, i, j, off: (i, h)),
+            pl.BlockSpec((bk, d),
+                         lambda h, i, j, off: (kv_block(h, i, j, off), h)),
+            pl.BlockSpec((bk, dv),
+                         lambda h, i, j, off: (kv_block(h, i, j, off), h))],
+        out_specs=pl.BlockSpec((bq, dv), lambda h, i, j, off: (i, h)),
+        scratch_shapes=[pltpu.VMEM((bq, LANES), jnp.float32),
+                        pltpu.VMEM((bq, LANES), jnp.float32),
+                        pltpu.VMEM((bq, dv), jnp.float32)])
+    scalars = jnp.stack([jnp.asarray(q_offset, jnp.int32),
+                         jnp.asarray(first_valid, jnp.int32)])
+    return pl.pallas_call(
+        functools.partial(_window_kernel, scale=scale, bq=bq, bk=bk, nk=nk,
+                          window=window),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((Tq, heads * dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "arbitrary")),
+        name="mla_window_attention_fwd",
+        interpret=(impl == "interpret"),
+    )(scalars, q, k, v)
+
+
+# ---------------- the indexer's scores ----------------
+
+def plain_index_scores(q, w, k, q_offset):
+    """q [Tq, J, d], w [Tq, J] float32, k [Tk, d] -> I [Tq, Tk] float32,
+    MASKED where the key stands after the query (row r at `q_offset + r`)."""
+    s = jnp.einsum("qjd,kd->qjk", q, k.astype(q.dtype),
+                   preferred_element_type=jnp.float32)
+    s = jnp.einsum("qjk,qj->qk", jax.nn.relu(s), w,
+                   precision=lax.Precision.HIGHEST)
+    seen = (jnp.arange(k.shape[0])[None, :]
+            <= q_offset + jnp.arange(q.shape[0])[:, None])
+    return jnp.where(seen, s, MASKED)
+
+
+def _index_kernel(off_ref, q_ref, w_ref, k_ref, o_ref, *, bq, bk, heads):
+    i, j = pl.program_id(0), pl.program_id(1)
+    offset = off_ref[0]
+    seen_any = j * bk <= offset + (i + 1) * bq - 1
+
+    @pl.when(seen_any)
+    def _():
+        k, w = k_ref[...], w_ref[...]
+        acc = jnp.zeros((bq, bk), jnp.float32)
+        for h in range(heads):
+            s = _dot(q_ref[h], k, ((1,), (1,)))              # [bq, bk]
+            acc = acc + jnp.maximum(s, 0.0) * w[:, h:h + 1]
+        row = offset + i * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        col = j * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        o_ref[...] = jnp.where(col <= row, acc, MASKED)
+
+    @pl.when(jnp.logical_not(seen_any))
+    def _():
+        o_ref[...] = jnp.full((bq, bk), MASKED, jnp.float32)
+
+
+def index_blocks(Tq: int, Tk: int):
+    pick = lambda n, sizes: next((b for b in sizes if n % b == 0), n)  # noqa
+    return pick(Tq, (256, 128)), pick(Tk, (512, 256, 128))
+
+
+def index_scores(q, w, k, q_offset, impl: str = "pallas", blocks=None):
+    """The indexer's scores of a chunk's rows (see the section's comment):
+    q [Tq, J, d], w [Tq, J] float32, k [Tk, d] -> [Tq, Tk] float32."""
+    if impl == "xla":
+        return plain_index_scores(q, w, k, q_offset)
+    Tq, J, d = q.shape
+    Tk = k.shape[0]
+    bq, bk = blocks or index_blocks(Tq, Tk)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(Tq // bq, Tk // bk),
+        in_specs=[
+            pl.BlockSpec((J, bq, d), lambda i, j, off: (0, i, 0)),
+            pl.BlockSpec((bq, J), lambda i, j, off: (i, 0)),
+            # a key block no row of the q block sees repeats the last one
+            pl.BlockSpec((bk, d), lambda i, j, off: (jnp.minimum(
+                j, (off[0] + (i + 1) * bq - 1) // bk), 0))],
+        out_specs=pl.BlockSpec((bq, bk), lambda i, j, off: (i, j)))
+    return pl.pallas_call(
+        functools.partial(_index_kernel, bq=bq, bk=bk, heads=J),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((Tq, Tk), jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "arbitrary")),
+        name="dsa_index_scores",
+        interpret=(impl == "interpret"),
+    )(jnp.asarray(q_offset, jnp.int32).reshape(1), q.transpose(1, 0, 2),
+      w.astype(jnp.float32), k.astype(q.dtype))
+
+
+# ---------------- attention over gathered rows ----------------
+
+GATHER_BLOCK = 64    # queries whose gathered rows are held at once
+
+
+def gathered_latent_attention(q, rows, ids, valid, rank: int, scale: float):
+    """q [B, H, width]; rows [N, width]; ids [B, K] rows of each query;
+    valid [B, K] -> o_lat [B, H, rank] float32: the softmax over each
+    query's valid gathered rows, whose first `rank` columns are the values.
+    A caller with many queries hands them over through `in_blocks`."""
+    got = rows.at[ids].get(mode="promise_in_bounds").astype(q.dtype)
+    s = jnp.einsum("bhd,bkd->bhk", q, got,
+                   preferred_element_type=jnp.float32) * scale
+    seen = valid[:, None, :]
+    p = jax.nn.softmax(jnp.where(seen, s, _NEG), axis=-1)
+    p = jnp.where(seen, p, 0.0)
+    # over the whole rows (a slice of them would be a copy of them)
+    return jnp.einsum("bhk,bkd->bhd", p.astype(q.dtype), got,
+                      preferred_element_type=jnp.float32)[:, :, :rank]
+
+
+def in_blocks(fn, *arrays, block: int = GATHER_BLOCK):
+    """fn (-> an array or a tuple of them) over the leading axis of
+    `arrays`, `block` rows at a time (one call where they are no more):
+    what bounds a gather's memory."""
+    R = arrays[0].shape[0]
+    if R <= block:
+        return fn(*arrays)
+    pad = -R % block
+    blocked = lambda a: jnp.pad(  # noqa: E731
+        a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)).reshape(
+            (-1, block) + a.shape[1:])
+    out = lax.map(lambda args: fn(*args), tuple(blocked(a) for a in arrays))
+    return jax.tree_util.tree_map(
+        lambda o: o.reshape((-1,) + o.shape[2:])[:R], out)
 
 
 def plain_paged_latent_attention(q, cache, layer, tables, lengths, rank,

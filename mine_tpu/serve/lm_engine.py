@@ -5,6 +5,20 @@ sequence: the chunk in the up-projected form against its cached prefix, the
 decode tokens in the absorbed (latent-space) form through their block tables
 (models/moe_mla.py, kernels/attention.py).
 
+Where the model has them (`lm.layer_types`, `lm.index_topk`), a layer's kind
+decides its attention and its cache: a full layer under an indexer scores
+every cached index key, selects exactly (`moe_mla.dsa_threshold`) and attends
+the selection alone: a chunk densely under the selection's mask
+(up-projected) while that form's transients fit `DENSE_SELECTED_BYTES`, a
+chunk on a longer block table and every decode row over the gathered rows
+(latent space); a sliding layer attends its window: the chunk over its own
+rows and the window before them, a decode row over its gathered window,
+both out of the window pool (`LatentCache.window_rows`), whose rows the
+host names a step at a time (`_ints`: where a row is written, the window
+before the chunk, a decode row's window). The expert layers run as one
+scan over whole periods of the layer pattern; with every layer full, dense
+and ungated the step is the one program it was before there were kinds.
+
 A step's rows are [chunk rows (Tc) | decode rows (max_running)]; a bucket is
 (Tc, P): Tc of `chunk_buckets` (0: decode only), P pages of `page_buckets`
 in the CHUNK's block table (its cached prefix and itself, rounded up: what
@@ -19,7 +33,9 @@ What a step returns, for its `logit_rows` (every decode row, and the last
 slice (taken on the device), and, for whoever asks (`LMRequest.want_logits`;
 the benchmark's check), the float32 logits, the normed hidden rows under the
 head, each expert layer's router input, scores and choice, and layer 0's
-latent rows as the cache holds them. Also every
+latent rows as the cache holds them (and, under an indexer or a window,
+every layer's attention output and the full layers' index queries, weights,
+scores and S: `detail_names`). Also every
 held expert's rows (the load) and the (token, expert) pairs held here: no
 pair is ever dropped, and the difference of the two is the counter that
 says so.
@@ -50,18 +66,30 @@ STEP_PROGRAM = "_lm_serve_step_impl"
 # what a step returns of its logit rows to whoever asks (`detail_steps`)
 DETAIL = ("logits", "hidden", "router_input", "sigma", "chosen",
           "cached_latent0")
+# and where the model selects keys or slides a window (`detail_names`): every
+# layer's attention output, the full layers' index queries, weights, scores
+# over the context and S (positions, -1 where a row sees fewer)
+DETAIL_ATTN = ("attn_out",)
+DETAIL_INDEX = ("index_q", "index_w", "index_scores", "selected")
+
+
+def detail_names(cfg) -> Tuple[str, ...]:
+    sliding = moe_mla.SLIDING in cfg.kinds
+    return (DETAIL + (DETAIL_ATTN if cfg.index_topk or sliding else ())
+            + (DETAIL_INDEX if cfg.index_topk else ()))
 
 
 @dataclasses.dataclass
 class StepInput:
     """One step as the scheduler composed it. `chunk`: (tokens [n],
-    first position, block table) of one prompt's next chunk, or None;
-    `decode`: (token, position, block table, feedback row) of each running
-    sequence: the token on the host, or None and the row of the step before
-    (decode row i: i; a chunk's last token: `Pending.chunk_row`) that is
-    sampling it."""
-    chunk: Optional[Tuple[np.ndarray, int, List[int]]]
-    decode: List[Tuple[Optional[int], int, List[int], int]]
+    first position, block table, window table) of one prompt's next chunk,
+    or None; `decode`: (token, position, block table, feedback row, window
+    table) of each running sequence: the token on the host, or None and the
+    row of the step before (decode row i: i; a chunk's last token:
+    `Pending.chunk_row`) that is sampling it. A window table
+    (`latent_cache.WindowTable`) only where the model has sliding layers."""
+    chunk: Optional[Tuple[np.ndarray, int, List[int], Any]]
+    decode: List[Tuple[Optional[int], int, List[int], int, Any]]
     want_logits: bool = False
 
 
@@ -87,17 +115,55 @@ class StepOutput:
     detail: Optional[Dict[str, np.ndarray]] = None   # want_logits
 
 
+# A chunk's attention over its selection is DENSE under the selection's mask
+# (`moe_mla.masked_attend`) while what that form alone holds stays within
+# this many bytes (`selected_dense_bytes`), and runs over the GATHERED rows
+# beyond. Readings at dots3_note_ep16_d9's widths, 2,048 rows a chunk, a v5e
+# (PERF.md section 6, PR 37). A block table of 65,536 tokens (0.67 GB by the
+# count below): a step 345 ms dense, 598 ms gathered, on the chip. One of
+# 133,120 tokens (1.36 GB): the dense program asks for 3.75 GiB beside
+# 12.04 GiB of arguments, 15.79 of the 15.75 GiB a v5e has, and is refused
+# (compiled for a described v5e; with 8 heads a group it is taken with 0.27
+# GiB to spare, which no run could count on); the gathered one 2.61 GiB.
+DENSE_SELECTED_BYTES = 1 << 30
+
+
+def selected_dense_bytes(cfg, chunk_rows: int, tokens: int) -> int:
+    """What the dense form holds that the gathered form does not, for a
+    chunk of `chunk_rows` on a block table of `tokens`: one head group's
+    up-projected keys and values over the table, and the int8 mask."""
+    group = min(moe_mla.DENSE_HEADS, cfg.num_attention_heads)
+    values = group * (cfg.qk_nope_head_dim + cfg.v_head_dim)
+    return tokens * (values * np.dtype(moe_mla.DTYPE).itemsize + chunk_rows)
+
+
+def _lin(i, a: int, b: int):
+    """i * a + b of a traced i and two Python numbers, without the
+    operations that do nothing."""
+    out = i if a == 1 else i * a
+    return out if b == 0 else out + b
+
+
 def _step_impl(params, cache_rows, ints, feedback, *, cfg, chunk_rows, pages,
-               decode_pages, running, logit_rows, page_size, impl):
-    """See the module docstring. `ints`: the step's integers packed into one
-    array (`_pack`); `feedback`: the step before's `sampled`."""
+               decode_pages, running, logit_rows, page_size, impl,
+               dense_selected=False):
+    """See the module docstring. `cache_rows`: `LatentCache.arrays()`;
+    `ints`: the step's integers packed into one array (`_pack`); `feedback`:
+    the step before's `sampled`."""
     import jax
     import jax.numpy as jnp
     from jax import lax
 
+    FULL, SLIDING = moe_mla.FULL, moe_mla.SLIDING
+    alone = not isinstance(cache_rows, dict)    # one kind of row, bare
+    if alone:
+        cache_rows = {"latent": cache_rows}
     Tc, P, Pd, D, R = chunk_rows, pages, decode_pages, running, logit_rows
     T = Tc + D
-    at = iter(np.cumsum([0, T, T, T, T, T, P, D * Pd, D, R, 1]))
+    W = cfg.sliding_window_size if SLIDING in cfg.kinds else 0
+    back = W - 1 if Tc else 0        # a chunk's window rows before itself
+    at = iter(np.cumsum([0, T, T, T, T, T, P, D * Pd, D, R, 1]
+                        + ([T, back, D * W] if W else [])))
     take = lambda n: lax.dynamic_slice(ints, (int(next(at)),), (n,))  # noqa
     tokens, positions, slots, valid = take(T), take(T), take(T), take(T)
     source = take(T)    # >= 0: the row of `feedback` that holds the token
@@ -106,49 +172,206 @@ def _step_impl(params, cache_rows, ints, feedback, *, cfg, chunk_rows, pages,
     rows_out, chunk_offset = take(R), take(1)[0]
     dec_tables = dec_tables.reshape(D, Pd)
     valid = valid > 0
-    cos, sin = moe_mla.rope_tables(positions, cfg)
-    pad = cache_rows.shape[-1] - cfg.latent_width
+    if W:   # the window pool's rows: written, before the chunk, a decode row
+        window_slots, window_back = take(T), take(back)
+        window_dec = take(D * W).reshape(D, W)
+    ropes = {kind: moe_mla.rope_tables(positions, moe_mla.of_kind(cfg, kind))
+             for kind in dict.fromkeys(cfg.kinds)}
+    rich = bool(cfg.index_topk or W)    # the detail the newer kinds return
 
-    def attend(x, cache_rows, w, layer):
-        q_nope, q_rope, latent = moe_mla.mla_project(x, w, cfg, cos, sin)
-        latent = jnp.pad(latent, ((0, 0), (0, pad))).astype(cache_rows.dtype)
-        cache_rows = cache_rows.at[layer, slots].set(latent)
+    def attend_dense(q_nope, q_rope, cache, w, k, layer):
         outs = []
         if Tc:
             ctx_rows = (chunk_table[:, None] * page_size
                         + jnp.arange(page_size)[None, :]).reshape(-1)
-            ctx = cache_rows[layer, ctx_rows][:, :cfg.latent_width]
+            ctx = cache[layer, ctx_rows][:, :k.latent_width]
             outs.append(moe_mla.mla_prefill(q_nope[:Tc], q_rope[:Tc], ctx, w,
-                                            cfg, chunk_offset, impl))
-        outs.append(moe_mla.mla_decode(q_nope[Tc:], q_rope[Tc:], cache_rows,
-                                       layer, dec_tables, dec_lens, w, cfg,
+                                            k, chunk_offset, impl))
+        outs.append(moe_mla.mla_decode(q_nope[Tc:], q_rope[Tc:], cache,
+                                       layer, dec_tables, dec_lens, w, k,
                                        page_size, impl))
-        return moe_mla.attention_out(x, jnp.concatenate(outs, axis=0),
-                                     w), cache_rows
+        return outs
 
+    def attend_selected(q_nope, q_rope, caches, w, k, layer, inputs, cos, sin):
+        """A full layer under the indexer: scores, S, the gathered rows."""
+        q_i, k_i, w_i = moe_mla.dsa_project(*inputs, w, k, cos, sin)
+        index = caches["index"].at[layer, slots].set(
+            k_i.astype(caches["index"].dtype))
+        cache = caches["latent"]
+        flat = cache.reshape(-1, cache.shape[-1])
+        base = layer * cache.shape[1]
+        outs, scores, chosen = [], [], []
+        if Tc:
+            ctx_rows = (chunk_table[:, None] * page_size
+                        + jnp.arange(page_size)[None, :]).reshape(-1)
+            sc = moe_mla.dsa_index(q_i[:Tc], w_i[:Tc], index[layer, ctx_rows],
+                                   chunk_offset, impl)
+            sees = jnp.minimum(chunk_offset + 1 + jnp.arange(Tc),
+                               P * page_size)
+            tau, bound = moe_mla.dsa_threshold(sc, sees, k)
+            mine = rows_out[D:]
+            if dense_selected:
+                # a short context: dense under the selection's mask; only
+                # the returned rows' S is written out as positions
+                outs.append(moe_mla.masked_attend(
+                    q_nope[:Tc], q_rope[:Tc],
+                    cache[layer, ctx_rows][:, :k.latent_width],
+                    moe_mla.dsa_mask(sc, tau, bound), w, k, chunk_offset,
+                    impl))
+                ids, ok = moe_mla.dsa_positions(
+                    sc[mine], sees[mine], tau[mine], bound[mine], k)
+                chosen.append(jnp.where(ok, ids, -1))
+            else:
+                ids, ok, at_rows = moe_mla.dsa_positions(
+                    sc, sees, tau, bound, k, chunk_table, page_size)
+                outs.append(moe_mla.gathered_attend(
+                    q_nope[:Tc], q_rope[:Tc], flat, base + at_rows, ok, w, k,
+                    "lm_dsa_prefill"))
+                chosen.append(jnp.where(ok, ids, -1)[mine])
+            scores.append(jnp.pad(sc[mine], ((0, 0), (0, (Pd - P)
+                                                      * page_size))))
+        sc = moe_mla.dsa_index_paged(q_i[Tc:], w_i[Tc:], index, layer,
+                                     dec_tables, dec_lens, page_size)
+        tau, bound = moe_mla.dsa_threshold(sc, dec_lens, k)
+        ids, ok, at_rows = moe_mla.dsa_positions(
+            sc, dec_lens, tau, bound, k, dec_tables, page_size)
+        outs.append(moe_mla.gathered_attend(
+            q_nope[Tc:], q_rope[Tc:], flat, base + at_rows, ok, w, k,
+            "lm_dsa_decode"))
+        K = ids.shape[1]
+        chosen = [jnp.where(ok, ids, -1)] + [
+            jnp.pad(c, ((0, 0), (0, K - c.shape[1])), constant_values=-1)
+            for c in chosen]
+        detail = {"index_q": q_i[rows_out].reshape(R, -1),
+                  "index_w": w_i[rows_out],
+                  "index_scores": jnp.concatenate([sc] + scores, axis=0),
+                  "selected": jnp.concatenate(chosen, axis=0)}
+        return outs, dict(caches, index=index), detail
+
+    def attend_window(q_nope, q_rope, latent, caches, w, k, layer):
+        """A sliding layer: the chunk over its own rows and the window
+        before it, a decode row over its gathered window."""
+        win = caches["window"]
+        row = jnp.pad(latent, ((0, 0), (0, win.shape[-1] - k.latent_width)))
+        win = win.at[layer, window_slots].set(row.astype(win.dtype))
+        flat = win.reshape(-1, win.shape[-1])
+        base = layer * win.shape[1]
+        outs = []
+        if Tc:
+            before = flat[base + window_back][:, :k.latent_width]
+            ctx = jnp.concatenate([before.astype(latent.dtype), latent[:Tc]],
+                                  axis=0)
+            outs.append(moe_mla.mla_prefill(
+                q_nope[:Tc], q_rope[:Tc], ctx, w, k, back, impl,
+                first_valid=jnp.maximum(back - chunk_offset, 0)))
+        seen = (dec_lens[:, None] - W + jnp.arange(W)[None, :]) >= 0
+        outs.append(moe_mla.gathered_attend(
+            q_nope[Tc:], q_rope[Tc:], flat, base + window_dec, seen, w, k,
+            "lm_swa_decode"))
+        return outs, dict(caches, window=win)
+
+    def attend(x, caches, w, kind, layer):
+        """One attention sub-layer of `kind`, its rows in layer `layer` of
+        its kind's cache. -> (x', caches', detail)"""
+        k, (cos, sin) = moe_mla.of_kind(cfg, kind), ropes[kind]
+        inputs = None
+        if k.index_topk or k.attention_gate_type:
+            with jax.named_scope(moe_mla._scope(k, "proj")):
+                inputs = moe_mla.mla_inputs(x, w, k)
+        q_nope, q_rope, latent = moe_mla.mla_project(x, w, k, cos, sin,
+                                                     inputs)
+        detail = {}
+        if kind == SLIDING:
+            outs, caches = attend_window(q_nope, q_rope, latent, caches, w,
+                                         k, layer)
+        else:
+            cache = caches["latent"]
+            latent = jnp.pad(latent, ((0, 0), (
+                0, cache.shape[-1] - k.latent_width))).astype(cache.dtype)
+            caches = dict(caches, latent=cache.at[layer, slots].set(latent))
+            if k.index_topk:
+                outs, caches, detail = attend_selected(
+                    q_nope, q_rope, caches, w, k, layer, inputs, cos, sin)
+            else:
+                outs = attend_dense(q_nope, q_rope, caches["latent"], w, k,
+                                    layer)
+        o = jnp.concatenate(outs, axis=0)
+        if k.attention_gate_type:
+            o = moe_mla.attention_gate(o, inputs[0], w, k)
+        after = moe_mla.attention_out(x, o, w, k)
+        if rich:
+            detail["attn_out"] = (after.astype(jnp.float32)
+                                  - x.astype(jnp.float32))[rows_out]
+        return after, caches, detail
+
+    first = cfg.first_k_dense_replace
     x = moe_mla.embed(params, tokens)
-    x, cache_rows = attend(x, cache_rows, params["dense"], 0)
+    x, cache_rows, detail0 = attend(x, cache_rows, params["dense"],
+                                    cfg.kinds[0], 0)
     # layer 0's rows of the returned positions, as the cache now holds them
-    cached0 = cache_rows[0, slots[rows_out], :cfg.latent_width]
+    cached0 = cache_rows["latent"][0, slots[rows_out], :cfg.latent_width]
     x = moe_mla.dense_mlp(x, params["dense"], cfg)
     moe = params["moe"]
-    stacked = {k: v for k, v in moe.items() if k not in ("eg", "eu", "ed")}
+    period = cfg.period
+    n = {kind: period.count(kind) for kind in (FULL, SLIDING)}
+    before = {kind: cfg.kinds[:first].count(kind) for kind in (FULL, SLIDING)}
+    stacked = {k: v for k, v in moe.items()
+               if k not in moe_mla.EXPERT_LEAVES + ("swa",)}
+    if len(period) == 1:
+        stacks = {"layers": stacked}     # a layer's leaves, whole
+    else:
+        split = lambda tree, m: jax.tree_util.tree_map(  # noqa: E731
+            lambda a: a.reshape((-1, m) + a.shape[1:]), tree)
+        stacks = {"ffn": split({k: stacked[k] for k in moe_mla.FFN_LEAVES},
+                               len(period)),
+                  FULL: split({k: v for k, v in stacked.items()
+                               if k not in moe_mla.FFN_LEAVES}, n[FULL]),
+                  SLIDING: split(moe.get("swa", {}), max(n[SLIDING], 1))}
 
     def body(carry, xs):
         x, cache_rows = carry
-        w, index = xs
-        x, cache_rows = attend(x, cache_rows, w, index + 1)
-        x, info = moe_mla.moe_mlp(x, w, moe["eg"], moe["eu"], moe["ed"],
-                                  index * cfg.experts_held, cfg, impl, valid)
-        kept = {"router_input": info["router_input"][rows_out],
-                "sigma": info["sigma"][rows_out],
-                "chosen": info["chosen"][rows_out],
-                "expert_rows": info["expert_rows"],
-                "held_pairs": info["held_pairs"]}
-        return (x, cache_rows), kept
+        ws, index = xs
+        kept, seen = [], {FULL: 0, SLIDING: 0}
+        for j, kind in enumerate(period):
+            if len(period) == 1:
+                w = ws["layers"]
+            else:
+                pick = lambda tree, i: jax.tree_util.tree_map(  # noqa: E731
+                    lambda a: a[i], tree)
+                w = dict(pick(ws[kind], seen[kind]), **pick(ws["ffn"], j))
+            x, cache_rows, detail = attend(
+                x, cache_rows, w, kind,
+                _lin(index, n[kind], before[kind] + seen[kind]))
+            seen[kind] += 1
+            x, info = moe_mla.moe_mlp(
+                x, w, moe["eg"], moe["eu"], moe["ed"],
+                _lin(index, len(period) * cfg.experts_held,
+                     j * cfg.experts_held), cfg, impl, valid)
+            kept.append(dict(
+                detail, router_input=info["router_input"][rows_out],
+                sigma=info["sigma"][rows_out],
+                chosen=info["chosen"][rows_out],
+                expert_rows=info["expert_rows"],
+                held_pairs=info["held_pairs"]))
+        if len(period) == 1:
+            return (x, cache_rows), kept[0]
+        # a period's layers: what every layer has stacked, what only the
+        # full layers have stacked over them
+        every = [k for k in kept[0] if all(k in d for d in kept)]
+        some = [k for k in kept[0] if k not in every]
+        out = {k: jnp.stack([d[k] for d in kept]) for k in every}
+        out.update({k: jnp.stack([d[k] for d in kept if k in d])
+                    for k in some})
+        return (x, cache_rows), out
 
     (x, cache_rows), kept = lax.scan(
-        body, (x, cache_rows), (stacked, jnp.arange(cfg.moe_layers)))
+        body, (x, cache_rows),
+        (stacks, jnp.arange(cfg.moe_layers // len(period))))
+    if len(period) > 1:     # [periods, layers of a period, ...] -> [layers]
+        kept = {k: v.reshape((-1,) + v.shape[2:]) for k, v in kept.items()}
+    if rich:                # layer 0's in front
+        kept.update({k: jnp.concatenate([v[None], kept[k]], axis=0)
+                     for k, v in detail0.items()})
     logits, hidden = moe_mla.head(params, x[rows_out], cfg)
     with jax.named_scope("lm_head"):
         sampled = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -157,6 +380,8 @@ def _step_impl(params, cache_rows, ints, feedback, *, cfg, chunk_rows, pages,
     counts = jnp.concatenate([sampled, kept["expert_rows"].reshape(-1),
                               kept["held_pairs"].reshape(-1)])
     sampled = jnp.pad(sampled, (0, feedback.shape[0] - R))
+    if alone:
+        cache_rows = cache_rows["latent"]
     return cache_rows, sampled, counts, dict(
         kept, logits=logits, hidden=hidden,
         cached_latent0=cached0.astype(jnp.float32))
@@ -182,6 +407,7 @@ class LMEngine:
                                           | {0}))
         self.page_buckets = tuple(sorted(int(p) for p in page_buckets))
         self.prompt_logits = int(prompt_logits)
+        self.detail_names = detail_names(cfg)
         self.impl = impl or ("pallas" if on_tpu_backend() else "xla")
         self._programs: Dict[Tuple[int, int], Any] = {}
         self.steps = 0
@@ -243,7 +469,7 @@ class LMEngine:
         import jax
         return jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-            (self.params, self.cache.rows,
+            (self.params, self.cache.arrays(),
              self._ints(bucket, StepInput(None, [])), self._feedback_array()))
 
     def _program(self, bucket):
@@ -254,6 +480,10 @@ class LMEngine:
                 decode_pages=self.page_buckets[-1], running=self.max_running,
                 logit_rows=self.logit_rows(bucket[0]),
                 page_size=self.cache.page_size, impl=self.impl)
+            if self.cfg.index_topk and bucket[0]:
+                static["dense_selected"] = selected_dense_bytes(
+                    self.cfg, bucket[0], bucket[1] * self.cache.page_size
+                ) <= DENSE_SELECTED_BYTES
 
             def _lm_serve_step_impl(params, cache_rows, ints, feedback):
                 return _step_impl(params, cache_rows, ints, feedback,
@@ -299,9 +529,22 @@ class LMEngine:
         dec_tables = np.zeros((D, Pd), np.int32)
         dec_lens = np.zeros(D, np.int32)
         rows_out = np.zeros(R, np.int32)
+        W = self.cache.window
+        window_slots = np.arange(T, dtype=np.int32) % ps  # page 0
+        window_back = np.zeros(W - 1 if W and Tc else 0, np.int32)
+        window_dec = np.zeros((D, W), np.int32)
+
+        def window_rows(table, at):
+            """The window pool's rows of positions `at`; page 0 where the
+            sequence holds none."""
+            first, held = table.first, np.asarray(table.pages + [0], np.int32)
+            i = at // ps - first
+            i = np.where((at >= 0) & (i >= 0) & (i < len(held) - 1), i, -1)
+            return held[i] * ps + at % ps
+
         offset = 0
         if step.chunk is not None:
-            ids, start, table = step.chunk
+            ids, start, table = step.chunk[:3]
             n = len(ids)
             tokens[:n] = ids
             # padded rows stand at the positions that follow: finite work
@@ -314,7 +557,11 @@ class LMEngine:
             offset = start
             K = R - D
             rows_out[D:] = np.clip(n - K + np.arange(K), 0, None)
-        for i, (token, position, table, row) in enumerate(step.decode):
+            if W:
+                window_slots[:n] = window_rows(step.chunk[3], at)
+                window_back[:] = window_rows(
+                    step.chunk[3], start - (W - 1) + np.arange(W - 1))
+        for i, (token, position, table, row, *rest) in enumerate(step.decode):
             if token is None:
                 source[Tc + i] = row
             else:
@@ -324,9 +571,14 @@ class LMEngine:
             valid[Tc + i] = 1
             dec_tables[i, :min(len(table), Pd)] = table[:Pd]
             dec_lens[i] = position + 1
+            if W:
+                at = position - (W - 1) + np.arange(W)
+                window_dec[i] = window_rows(rest[0], at)
+                window_slots[Tc + i] = window_dec[i, -1]
         rows_out[:D] = Tc + np.arange(D)
         return _pack([tokens, positions, slots, valid, source, chunk_table,
-                      dec_tables, dec_lens, rows_out, [offset]])
+                      dec_tables, dec_lens, rows_out, [offset]]
+                     + ([window_slots, window_back, window_dec] if W else []))
 
     def dispatch(self, step: StepInput) -> Pending:
         """Issue one step; returns at once."""
@@ -338,8 +590,10 @@ class LMEngine:
         t0 = time.perf_counter_ns()
         with telemetry.span("serve.lm.dispatch"):
             ints = self._ints(bucket, step)
-            self.cache.rows, sampled, counts, aux = self._program(bucket)(
-                self.params, self.cache.rows, ints, self._feedback_array())
+            arrays, sampled, counts, aux = self._program(bucket)(
+                self.params, self.cache.arrays(), ints,
+                self._feedback_array())
+            self.cache.set_arrays(arrays)
         self._feedback = sampled
         return Pending(step=step, bucket=bucket, sampled=sampled,
                        aux=dict(aux, counts=counts),
@@ -354,7 +608,7 @@ class LMEngine:
         with telemetry.host_readback("serve.lm.readback"):
             fetch = {"counts": aux["counts"]}
             if step.want_logits:
-                fetch.update({k: aux[k] for k in DETAIL})
+                fetch.update({k: aux[k] for k in self.detail_names})
             got = jax.device_get(fetch)
         self.steps += 1
         D, R = self.max_running, self.logit_rows(pending.bucket[0])
@@ -370,7 +624,8 @@ class LMEngine:
             chunk_tokens=sampled[D:] if R > D else None,
             chunk_rows=min(R - D, n_chunk), bucket=pending.bucket,
             expert_rows=rows, held_pairs=pairs,
-            detail={k: got[k] for k in DETAIL} if step.want_logits else None)
+            detail=({k: got[k] for k in self.detail_names}
+                    if step.want_logits else None))
 
     def expert_rows_total(self) -> np.ndarray:
         """Rows each held expert has computed since the start, [expert

@@ -13,7 +13,13 @@ A request is a document (optional, shared by its id), a question and a
 number of answer tokens. Admission finds or reserves the document's pages in
 the latent cache (serve/latent_cache.py), pins the document, and takes pages
 of the request's own for the tokens past the document's last whole page, the
-question and the answer. A request that cannot have its pages yet stays at
+question and the answer. Where the model has sliding-window layers the
+request also draws on the window pool: admission sets aside the most window
+pages it can hold at once (`_window_need`), every step takes the pages its
+tokens are written to and gives back those that lie wholly behind the window
+(`_window_step`), and a new document keeps the pages that cover its last
+window before its last page boundary, so that a question on it can start.
+A request that cannot have its pages yet stays at
 the head of the queue; everything behind it waits (no overtaking). Sampling
 is greedy on the device; a request ends at its `max_tokens` (with weights
 from a seed no id means "end").
@@ -32,7 +38,9 @@ benchmark's driver do.
 
 Spans: `serve.lm.step` (from a step's issue to its reading: tokens, decode,
 prefill, prefill_start, decode_context, sampled_rows, bucket, pages,
-expert_pairs, experts_touched),
+expert_pairs, experts_touched; under an indexer or a window also
+index_pairs, selected_pairs, window_pairs, dense_rows:
+`StepPlan.shape_fields`),
 `serve.lm.schedule`, `serve.lm.prefill_done` (a request's first token: its
 time to first token). Counters `serve.lm.tokens_out`, `.prompt_tokens`,
 `.prompt_tokens_cached`, `.step_tokens`, `.step_budget`, `.requests_done`;
@@ -51,7 +59,8 @@ from typing import Any, Deque, Dict, Hashable, List, Optional
 import numpy as np
 
 from mine_tpu import telemetry
-from mine_tpu.serve.latent_cache import Document, LatentCache
+from mine_tpu.serve.latent_cache import (Document, LatentCache,
+                                         WindowTable)
 from mine_tpu.serve.lm_engine import LMEngine, StepInput, StepOutput
 
 LOG_EVERY_STEPS = 50   # the cadence of the expert-load gauges
@@ -97,6 +106,8 @@ class Sequence:
         self.document: Optional[Document] = None
         self.own_pages: List[int] = []
         self.table: List[int] = []
+        self.window: Optional[WindowTable] = None   # sliding layers' pages
+        self.window_budget = 0        # set aside for it and not yet taken
         self.pos = 0                  # next prompt position to prefill
         self.cached = 0               # prompt tokens found resident
         self.tokens: List[int] = []   # delivered
@@ -134,6 +145,8 @@ class StepPlan:
     chunk_tokens: int = 0
     completes: bool = False
     detail: Any = ()
+    index_topk: int = 0      # the model's: what `shape_fields` counts by
+    window: int = 0
 
     @property
     def tokens(self) -> int:
@@ -144,26 +157,44 @@ class StepPlan:
         if self.chunk is not None:
             s = self.chunk
             chunk = (s.prompt[self.chunk_start:self.chunk_start
-                              + self.chunk_tokens], self.chunk_start, s.table)
+                              + self.chunk_tokens], self.chunk_start, s.table,
+                     s.window)
         return StepInput(
             chunk=chunk, want_logits=bool(self.detail),
-            decode=[(token, position, s.table, row)
+            decode=[(token, position, s.table, row, s.window)
                     for s, token, row, position in self.decode])
 
     def shape_fields(self) -> Dict[str, int]:
         """What the step's span says of its shape (the benchmark prices a
         step's work from these)."""
-        return {"tokens": self.tokens, "decode": len(self.decode),
-                "prefill": self.chunk_tokens,
-                "prefill_start": self.chunk_start,
-                "decode_context": sum(p + 1 for _, _, _, p in self.decode),
-                "sampled_rows": len(self.decode) + int(self.completes)}
+        fields = {"tokens": self.tokens, "decode": len(self.decode),
+                  "prefill": self.chunk_tokens,
+                  "prefill_start": self.chunk_start,
+                  "decode_context": sum(p + 1 for _, _, _, p in self.decode),
+                  "sampled_rows": len(self.decode) + int(self.completes)}
+        if self.index_topk or self.window:
+            # keys each row sees (a full layer's (query, key) pairs: what
+            # the indexer scores), of them selected, and inside the window
+            sees = np.concatenate([
+                self.chunk_start + 1 + np.arange(self.chunk_tokens),
+                np.asarray([p + 1 for _, _, _, p in self.decode], np.int64)])
+            fields["index_pairs"] = int(sees.sum())
+            if self.index_topk:
+                fields["selected_pairs"] = int(
+                    np.minimum(sees, self.index_topk).sum())
+                fields["dense_rows"] = int((sees <= self.index_topk).sum())
+            if self.window:
+                fields["window_pairs"] = int(
+                    np.minimum(sees, self.window).sum())
+        return fields
 
 
 class StepScheduler:
     def __init__(self, cache: LatentCache, max_step_tokens: int,
-                 max_running: int, max_chunk: int, max_context: int):
+                 max_running: int, max_chunk: int, max_context: int,
+                 index_topk: int = 0):
         self.cache = cache
+        self.index_topk = int(index_topk)
         self.max_step_tokens = int(max_step_tokens)
         self.max_running = int(max_running)
         self.max_chunk = int(max_chunk)
@@ -212,7 +243,10 @@ class StepScheduler:
         shared = 0 if doc is None else doc.tokens
         own = cache.allocate(cache.pages_for(
             len(seq.prompt) + seq.request.max_tokens - shared))
-        if own is None:
+        need = self._window_need(seq, doc)
+        if own is None or not cache.reserve_window(need):
+            if own is not None:
+                cache.release(own)
             if doc is not None:
                 doc.readers -= 1
                 if reserved:
@@ -222,15 +256,73 @@ class StepScheduler:
         seq.table = ([] if doc is None else list(doc.pages)) + own
         if doc is not None and doc.ready:
             seq.pos = seq.cached = doc.tokens
+        if cache.window:
+            seq.window, seq.window_budget = WindowTable(), need
+            if seq.cached and doc.window is not None:
+                seq.window = WindowTable(doc.window.first, doc.window.pages,
+                                         doc.window.pages)
         self._c["prompt_tokens"].inc(len(seq.prompt))
         self._c["prompt_tokens_cached"].inc(seq.cached)
         return True
 
     def _release(self, seq: Sequence) -> None:
         self.cache.release(seq.own_pages)
+        if seq.window is not None:
+            self.cache.give_window(seq.window.owned(), reserve=False)
+            self.cache.unreserve_window(seq.window_budget)
         if seq.document is not None:
             seq.document.readers -= 1
         self.running.remove(seq)
+
+    # ---- the window pool (sliding layers) ----
+
+    def _window_keep(self, doc_tokens: int):
+        """(first, end) page numbers of the window pages a document keeps:
+        those that hold its last window before its last page boundary."""
+        ps = self.cache.page_size
+        return (max(doc_tokens - (self.cache.window - 1), 0) // ps,
+                doc_tokens // ps)
+
+    def _window_need(self, seq: Sequence, doc: Optional[Document]) -> int:
+        """The most window pages the sequence holds at once: a chunk's rows
+        and the window before them, never more than its own length spans;
+        and what its document will keep, where it writes one."""
+        if not self.cache.window:
+            return 0
+        pages_for, back = self.cache.pages_for, self.cache.window - 1
+        start = doc.tokens if doc is not None and doc.ready else 0
+        total = len(seq.prompt) + seq.request.max_tokens - max(start - back,
+                                                               0)
+        need = min(pages_for(total), pages_for(back + self.max_chunk)) + 1
+        if doc is not None and not doc.ready:
+            first, end = self._window_keep(doc.tokens)
+            need += end - first
+        return need
+
+    def _window_step(self, seq: Sequence, first_read: int, last_written: int):
+        """Before a step that reads the sequence's window rows from position
+        `first_read` on and writes up to `last_written`: give back the pages
+        wholly behind the window, take those the new rows need."""
+        cache, table, ps = self.cache, seq.window, self.cache.page_size
+        drop = min(max(first_read, 0) // ps - table.first, len(table.pages))
+        if drop > 0:
+            gone = table.drop(drop)
+            cache.give_window(gone, reserve=True)
+            seq.window_budget += len(gone)
+        if not table.pages:
+            table.first = max(table.first, max(first_read, 0) // ps)
+        more = last_written // ps + 1 - (table.first + len(table.pages))
+        if more > 0:
+            table.pages.extend(cache.take_window(more))
+            seq.window_budget -= more
+
+    def _window_hand_over(self, seq: Sequence, doc: Document) -> None:
+        """The document is whole: the window pages at its end are its own
+        from here on (the sequence reads on, and no longer gives them back)."""
+        first, end = self._window_keep(doc.tokens)
+        at = first - seq.window.first
+        doc.window = WindowTable(first, seq.window.pages[at:at + end - first])
+        seq.window.shared |= set(doc.window.pages)
 
     # ---- one step ----
 
@@ -253,7 +345,8 @@ class StepScheduler:
                 self.running.append(head)
                 self.prefilling = head
         budget = min(self.max_step_tokens - len(decode), self.max_chunk)
-        plan = StepPlan(decode=decode, chunk=None)
+        plan = StepPlan(decode=decode, chunk=None, index_topk=self.index_topk,
+                        window=self.cache.window)
         if self.prefilling is not None and budget > 0:
             s = self.prefilling
             plan.chunk, plan.chunk_start = s, s.pos
@@ -262,14 +355,22 @@ class StepScheduler:
             return None
         plan.detail = {id(s) for s in [d[0] for d in decode] + (
             [plan.chunk] if plan.chunk is not None else []) if s.wants_detail}
-        for s, _, _, _ in decode:
+        back = self.cache.window - 1
+        for s, _, _, position in decode:
             s.scheduled += 1
+            if s.window is not None:
+                self._window_step(s, position - back, position)
         if plan.chunk is not None:
             s = plan.chunk
+            if s.window is not None:
+                self._window_step(s, s.pos - back,
+                                  s.pos + plan.chunk_tokens - 1)
             s.pos += plan.chunk_tokens
             doc = s.document
             if doc is not None and not doc.ready and s.pos >= doc.tokens:
                 doc.ready = True    # a later step reads what this one writes
+                if s.window is not None:
+                    self._window_hand_over(s, doc)
             if s.prefilled:
                 plan.completes = True
                 self.prefilling = None
@@ -331,13 +432,11 @@ class StepScheduler:
               row: int) -> None:
         if out.detail is None or id(s) not in plan.detail:
             return
-        d = out.detail
-        s.detail.append({
-            "position": position, "logits": d["logits"][row],
-            "hidden": d["hidden"][row],
-            "router_input": d["router_input"][:, row],
-            "sigma": d["sigma"][:, row], "chosen": d["chosen"][:, row],
-            "cached_latent0": d["cached_latent0"][row]})
+        # a row of what the step returned: by row, or by layer and row
+        by_row = ("logits", "hidden", "cached_latent0")
+        s.detail.append(dict(
+            {k: v[row] if k in by_row else v[:, row]
+             for k, v in out.detail.items()}, position=position))
 
 
 class LMServer:
@@ -447,8 +546,15 @@ def build_server(config: Dict[str, Any], seed: int = 0, start: bool = True,
     serve = lm_serve_config_from_dict(config)
     params = jax.jit(lambda s: moe_mla.init_params(
         jax.random.key(s, impl="rbg"), cfg))(seed)
-    cache = LatentCache(cfg.num_hidden_layers, serve.cache_tokens,
-                        serve.page_size, cfg.latent_width, moe_mla.DTYPE)
+    swa = moe_mla.of_kind(cfg, moe_mla.SLIDING)
+    sliding = cfg.layers_of(moe_mla.SLIDING)
+    cache = LatentCache(
+        cfg.layers_of(moe_mla.FULL), serve.cache_tokens, serve.page_size,
+        cfg.latent_width, moe_mla.DTYPE,
+        index_width=cfg.index_head_dim if cfg.index_topk else 0,
+        window_layers=sliding, window_tokens=serve.window_cache_tokens,
+        window_width=swa.latent_width if sliding else 0,
+        window=swa.window if sliding else 0)
     engine = LMEngine(cfg, params, cache, max_running=serve.max_running,
                       chunk_buckets=serve.chunk_buckets,
                       page_buckets=[-(-c // serve.page_size)
@@ -456,5 +562,6 @@ def build_server(config: Dict[str, Any], seed: int = 0, start: bool = True,
                       prompt_logits=prompt_logits)
     engine.warmup()
     scheduler = StepScheduler(cache, serve.max_step_tokens, serve.max_running,
-                              engine.max_chunk, engine.max_context)
+                              engine.max_chunk, engine.max_context,
+                              index_topk=cfg.index_topk)
     return LMServer(engine, scheduler, start=start)

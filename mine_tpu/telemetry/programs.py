@@ -38,7 +38,12 @@ from typing import Callable, Dict, Optional
 # token model's serve step (models/moe_mla.py): `lm_mla_proj` (norms,
 # projections, RoPE, W_o), `lm_mla_prefill`, `lm_mla_decode`, `lm_dense_mlp`,
 # `lm_moe_router`, `lm_moe_experts`, `lm_moe_shared`, `lm_head` (`lm_head_loss`
-# stands before it: the first prefix that matches names the layer).
+# stands before it: the first prefix that matches names the layer); where the
+# model selects keys or slides a window also `lm_dsa_index` (the indexer's
+# projections and scores), `lm_dsa_select` (the exact top-k), `lm_dsa_prefill`
+# / `lm_dsa_decode` (attention over the selected rows, a chunk's queries / the
+# decode rows), `lm_swa_proj` / `lm_swa_prefill` / `lm_swa_decode` (a sliding
+# layer's three parts), `lm_attn_gate`.
 SCOPE_LAYERS = (
     ("encoder", "encoder"),
     ("decoder", "decoder"),
@@ -60,6 +65,15 @@ SCOPE_LAYERS = (
     ("lm_moe_experts", "moe_experts"),
     ("lm_moe_shared", "moe_shared"),
     ("lm_head", "head"),
+    # full layers under an indexer, sliding layers, the gate (PR 37)
+    ("lm_dsa_index", "dsa_index"),
+    ("lm_dsa_select", "dsa_select"),
+    ("lm_dsa_prefill", "dsa_prefill"),
+    ("lm_dsa_decode", "dsa_decode"),
+    ("lm_swa_proj", "swa_proj"),
+    ("lm_swa_prefill", "swa_prefill"),
+    ("lm_swa_decode", "swa_decode"),
+    ("lm_attn_gate", "attn_gate"),
 )
 # the layers that partition each model family's train step
 FAMILY_LAYERS = {
@@ -68,7 +82,9 @@ FAMILY_LAYERS = {
     # the token model's serve step (serve/lm_engine.py)
     "moe_mla": ("embed", "mla_proj", "mla_prefill", "mla_decode",
                 "dense_mlp", "moe_router", "moe_experts", "moe_shared",
-                "head"),
+                "head", "dsa_index", "dsa_select", "dsa_prefill",
+                "dsa_decode", "swa_proj", "swa_prefill", "swa_decode",
+                "attn_gate"),
 }
 LAYERS = tuple(dict.fromkeys(
     layer for layers in FAMILY_LAYERS.values() for layer in layers))

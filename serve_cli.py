@@ -21,7 +21,11 @@ A token model is served through the same door (`model.family: moe_mla`):
 builds the token server (`serve/lm_scheduler.py build_server`: weights from
 `--seed`, latent cache, step engine, scheduler), sends every line of
 `requests.jsonl` ({"doc_id", "document": [ids], "question": [ids],
-"max_tokens"}) and writes `answers.jsonl`.
+"max_tokens"}) and writes `answers.jsonl`. Which member of the family is
+served follows from the YAML's `lm.*` keys alone:
+`mine_tpu/configs/params_dots3_note.yaml` (full layers under a learned
+sparse selection mixed with sliding-window layers; three kinds of cached
+row) goes through the same server.
 """
 
 import argparse

@@ -51,6 +51,7 @@ QUICK = {
     "test_looplm.py::test_exit_distribution_sums_to_one_and_one_pass_is_plain_ce",
     "test_moe_mla.py::test_yarn_frequencies_and_scale_are_the_references",
     "test_lm_serve.py::test_a_document_being_read_is_never_evicted",
+    "test_dots3.py::test_published_config_reads_as_two_periods_of_four",
     "test_loss_aggregation.py::test_compute_scale_factor_formula",
     "test_fused_loss.py::test_ssim_pairs_matches_separate_calls",
     "test_step_breakdown.py::test_parse_extracts_all_buckets",

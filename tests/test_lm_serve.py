@@ -303,3 +303,33 @@ def test_serve_cli_answers_a_request_for_the_token_model(tmp_path,
     log = (out / "serve.log").read_text()
     assert "lm serve stats: requests=2 tokens_out=6" in log
     assert "dropped_tokens=0" in log
+
+
+# ---- one family, two members: the first one's programs are untouched --------
+
+KIMI_STEP_PROGRAMS_SHA256 = (
+    "4a1af8147def43b6b9ccab4a5846a9947f77bb00235a099a7baeea82d4fb3961")
+
+
+def test_all_full_ungated_layers_lower_to_the_step_before_layer_kinds(
+        monkeypatch):
+    """PR 37 gave the family layer kinds, an indexer, a gate and a rescale.
+    With none of them (this YAML) the step programs at this file's sizes
+    lower to the text they lowered to before, byte for byte: the hash is of
+    the parent commit's (5b72aba) three programs, taken with the same
+    lines."""
+    import hashlib
+
+    import jax
+    # (this module's server fixture runs the model in float32)
+    monkeypatch.setattr(moe_mla, "DTYPE", jnp.bfloat16)
+    srv = build_server(tiny_config(**SERVE), seed=3, start=False)
+    engine = srv.engine
+    # (conftest.py raises the matmul precision for the numerics tests; the
+    # hash was taken at JAX's default, as the CLIs run)
+    with jax.default_matmul_precision(None):
+        text = "\n".join(
+            "### %s\n%s" % (b, engine._program(b).lower(
+                *engine._shapes(b)).as_text()) for b in engine.buckets())
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        KIMI_STEP_PROGRAMS_SHA256)

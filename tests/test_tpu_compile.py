@@ -150,7 +150,58 @@ def _attention_case():
         q, k, v, 16, interpret=False)), argnums=(0, 1, 2)), shapes
 
 
+def _index_scores_case(chunk, context):
+    """kernels/attention.py `index_scores` at the sparse serve cell's
+    buckets (params_dots3_note.yaml): a chunk's 64 index heads x 128 against
+    a block table's index keys."""
+    def build():
+        from mine_tpu.kernels.attention import index_scores
+        return (lambda q, w, k: index_scores(q, w, k, context - chunk),
+                [((chunk, 64, 128), jnp.bfloat16), ((chunk, 64), jnp.float32),
+                 ((context, 128), jnp.bfloat16)])
+    return build
+
+
+def _window_attention_case(chunk):
+    """`window_attention` as a sliding layer's chunk runs it: 64 heads of
+    192 + 64, values 128, the chunk's rows behind a window of 512 keys."""
+    def build():
+        from mine_tpu.kernels.attention import window_attention
+        keys = chunk + 512
+        return (lambda q, k, v: window_attention(q, k, v, 64, 512, 256 ** -0.5,
+                                                 513, 100),
+                [((chunk, 64 * 256), jnp.bfloat16),
+                 ((keys, 64 * 256), jnp.bfloat16),
+                 ((keys, 64 * 128), jnp.bfloat16)])
+    return build
+
+
+def _masked_attention_case(chunk, context):
+    """`masked_prefix_attention` as a short context's selected attention
+    runs it: 16 heads a call (moe_mla.DENSE_HEADS) of 128 + 64, values 128,
+    the selection an int8 mask."""
+    def build():
+        from mine_tpu.kernels.attention import masked_prefix_attention
+        return (lambda qn, qr, kn, kr, v, m: masked_prefix_attention(
+            qn, qr, kn, kr, v, m, 16, context - chunk, 192 ** -0.5),
+                [((chunk, 16 * 128), jnp.bfloat16),
+                 ((16, chunk, 64), jnp.bfloat16),
+                 ((context, 16 * 128), jnp.bfloat16),
+                 ((context, 64), jnp.bfloat16),
+                 ((context, 16 * 128), jnp.bfloat16),
+                 ((chunk, context), jnp.int8)])
+    return build
+
+
 CASES = {"attention_vjp-4096x16x128": _attention_case}
+for _chunk in (768, 2048):
+    for _context in (16384, 65536):
+        CASES["masked_attention-%dx%d" % (_chunk, _context)] = (
+            _masked_attention_case(_chunk, _context))
+    CASES["window_attention-%d" % _chunk] = _window_attention_case(_chunk)
+    for _context in (16384, 32768, 65536, 133120):
+        CASES["index_scores-%dx%d" % (_chunk, _context)] = _index_scores_case(
+            _chunk, _context)
 for _hw in LLFF + RE10K:
     CASES["warp_diff_vjp-%dx%d" % _hw] = _warp_case(_hw)
     CASES["composite_vjp-%dx%d" % _hw] = _composite_case(_hw)
@@ -295,5 +346,59 @@ def test_looplm_step_compiles_and_fits_v5e(one_chip, no_compile_cache,
     state_bytes = 12 * 612_438_017   # float32 parameters + Adam's two moments
     assert analysis.argument_size_in_bytes >= state_bytes
     # a v5e has 16 GB of HBM, 15.75 GiB of them usable
+    assert analysis.peak_memory_in_bytes <= 15.75 * 2**30, (
+        analysis.peak_memory_in_bytes / 2**30)
+
+
+def test_sparse_serve_largest_bucket_fits_v5e(one_chip, no_compile_cache):
+    """The token server's largest step program of params_dots3_note.yaml
+    (2,048 chunk rows on a block table of 133,120 tokens, 16 decode rows)
+    beside its weights and three caches: the engine's byte budget
+    (`lm_engine.DENSE_SELECTED_BYTES`) sends this bucket to the GATHERED
+    form of a chunk's selected attention, and the 65,536-token one to the
+    dense form; the chip's compiler takes the program and its peak fits."""
+    import os
+
+    from mine_tpu.config import (CONFIG_DIR, lm_serve_config_from_dict,
+                                 load_config)
+    from mine_tpu.models import moe_mla
+    from mine_tpu.serve import lm_engine
+
+    config = load_config(os.path.join(CONFIG_DIR, "params_dots3_note.yaml"))
+    cfg = moe_mla.moe_mla_config_from_dict(config)
+    serve = lm_serve_config_from_dict(config)
+    ps, D = serve.page_size, serve.max_running
+    Tc, tokens = serve.chunk_buckets[-1], serve.context_buckets[-1]
+    dense = {t: lm_engine.selected_dense_bytes(cfg, Tc, t)
+             <= lm_engine.DENSE_SELECTED_BYTES for t in serve.context_buckets}
+    assert dense == {16384: True, 32768: True, 65536: True, 133120: False}
+    put = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = put(jax.eval_shape(lambda: moe_mla.init_params(
+        jax.random.key(0, impl="rbg"), cfg)))
+    swa = moe_mla.of_kind(cfg, moe_mla.SLIDING)
+    rows = lambda t, width: jax.ShapeDtypeStruct(  # noqa: E731
+        (t[0], (t[1] // ps + 1) * ps, width), jnp.bfloat16, sharding=one_chip)
+    full = (cfg.layers_of(moe_mla.FULL), serve.cache_tokens)
+    caches = {"latent": rows(full, -(-cfg.latent_width // 128) * 128),
+              "index": rows(full, cfg.index_head_dim),
+              "window": rows((cfg.layers_of(moe_mla.SLIDING),
+                              serve.window_cache_tokens),
+                             -(-swa.latent_width // 128) * 128)}
+    P, W, T, R = tokens // ps, cfg.sliding_window_size, Tc + D, D + 1
+    ints = jax.ShapeDtypeStruct(
+        (5 * T + P + D * P + D + R + 1 + T + (W - 1) + D * W,), jnp.int32,
+        sharding=one_chip)
+    feedback = jax.ShapeDtypeStruct((R,), jnp.int32, sharding=one_chip)
+    step = jax.jit(lambda *args: lm_engine._step_impl(
+        *args, cfg=cfg, chunk_rows=Tc, pages=P, decode_pages=P, running=D,
+        logit_rows=R, page_size=ps, impl="pallas",
+        dense_selected=dense[tokens]), donate_argnums=(1,))
+    with jax.default_matmul_precision("default"):
+        analysis = step.lower(params, caches, ints, feedback).compile(
+            ).memory_analysis()
+    # weights 9.23 GB + caches 3.70 GB: what the configuration's file states
+    assert 12.9e9 < analysis.argument_size_in_bytes < 13.0e9
     assert analysis.peak_memory_in_bytes <= 15.75 * 2**30, (
         analysis.peak_memory_in_bytes / 2**30)

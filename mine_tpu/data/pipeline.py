@@ -11,7 +11,11 @@ each independently knobbed:
      assembly is counter-based (common.item_rng): batch b is a pure
      function of (seed, epoch, b), so N workers building batches out of
      order still yield the exact sequence the synchronous loop yields,
-     and checkpoint resume reproduces batch k bitwise.
+     and checkpoint resume reproduces batch k bitwise. The same purity
+     lets the pool of a chained epoch (one opened right after the
+     previous epoch of the same iterator) build the NEXT epoch's first
+     batches once this epoch's are all claimed, so the next open finds
+     them ready instead of waiting on a cold pool.
   2. `prefetch` — a single background producer thread with a bounded
      queue (for iterators with no parallelizable structure, e.g. a
      custom batch_iterator that does not go through the common core).
@@ -33,7 +37,7 @@ from __future__ import annotations
 
 import queue
 import threading
-from typing import Callable, Dict, Iterator, NamedTuple
+from typing import Callable, Dict, Iterator, NamedTuple, Optional
 
 import numpy as np
 
@@ -97,6 +101,165 @@ def prefetch(iterator: Iterator, depth: int = 2,
         stop.set()
 
 
+def _same_fn(a, b) -> bool:
+    """Whether two get_pair (or collate) callables build the same bytes: one
+    bound method of one owner, or one function's code over the same captured
+    objects (llff.py makes its get_pair afresh in every batch_iterator)."""
+    if a is b or a == b:   # bound methods of one owner compare equal
+        return True
+    code = getattr(a, "__code__", None)
+    if code is None or code is not getattr(b, "__code__", None):
+        return False
+
+    def captured(f):
+        return [c.cell_contents for c in f.__closure__ or ()] \
+            + list(f.__defaults__ or ())
+    return all(x is y for x, y in zip(captured(a), captured(b)))
+
+
+class _Key(NamedTuple):
+    """What an epoch's batches are a function of, beside the epoch."""
+    get_pair: Callable
+    collate: Optional[Callable]
+    args: tuple   # (num_items, batch_size, shuffle, seed, drop_last,
+    #               shard_index, num_shards, workers, prefetch_batches)
+
+    def same(self, other: "_Key") -> bool:
+        return (self.args == other.args
+                and _same_fn(self.get_pair, other.get_pair)
+                and _same_fn(self.collate, other.collate))
+
+
+class _Epoch:
+    """One epoch's batches as a pool builds them: its shard order, the next
+    index to claim, indices handed back by dead workers, those being built,
+    the built batches and the errors. `credits` bounds the batches built
+    but not yet taken; an epoch built ahead shares its opener's."""
+
+    def __init__(self, key: _Key, epoch: int, order: np.ndarray, nb: int,
+                 credits: threading.Semaphore):
+        self.key, self.epoch, self.order, self.nb = key, epoch, order, nb
+        self.credits = credits
+        self.cv = threading.Condition()
+        self.next_batch = 0
+        self.requeue = []      # indices whose claiming worker died
+        self.building = set()
+        self.results: Dict[int, Dict] = {}
+        self.errors = []
+        self.stopped = False
+        self.dropped = False   # built ahead and discarded: count what lands
+        self.sealed = False    # the epoch before it ran to its end
+
+    def claim(self, limit: int, take: bool = True) -> Optional[int]:
+        """The next index below `limit` to build, a dead worker's first;
+        None when there is none. `take=False` only looks. Caller holds cv."""
+        if self.stopped or self.errors:
+            return None
+        if self.requeue:
+            b = self.requeue[-1]
+        elif self.next_batch < limit:
+            b = self.next_batch
+        else:
+            return None
+        if take:
+            if self.requeue:
+                self.requeue.pop()
+            else:
+                self.next_batch += 1
+            self.building.add(b)
+        return b
+
+    def finish(self, b: int, batch: Dict):
+        with self.cv:
+            self.building.discard(b)
+            if self.stopped:   # discarded while it was built
+                self.credits.release()
+                if self.dropped:
+                    telemetry.counter("data.lookahead.dropped").inc()
+            else:
+                self.results[b] = batch
+            self.cv.notify_all()
+
+    def fail(self, b: int, error: Exception):
+        with self.cv:
+            self.building.discard(b)
+            self.errors.append((b, error))
+            self.cv.notify_all()
+
+    def hand_back(self, b: int):
+        """The worker building `b` is dying: requeue it, return its credit."""
+        with self.cv:
+            self.building.discard(b)
+            self.requeue.append(b)
+            self.cv.notify_all()
+        self.credits.release()
+
+    def stop(self, dropped: bool = False):
+        """No more claims; discard what was built and return its credits."""
+        with self.cv:
+            held = len(self.results) + len(self.errors)
+            if dropped and self.results:
+                telemetry.counter("data.lookahead.dropped").inc(
+                    len(self.results))
+            self.stopped, self.dropped = True, self.dropped or dropped
+            self.results.clear()
+            self.errors.clear()
+            self.cv.notify_all()
+        for _ in range(held):
+            self.credits.release()
+
+
+class _Handoff:
+    """The epoch chain the process feeds: the newest open's key and epoch,
+    and the next epoch as that open's pool builds it ahead (one epoch, no
+    further). An open that is no next epoch of the same key drops it.
+    One per process: the callers (train/loop.py, the benchmark's feed)
+    open a fresh `batch_iterator` an epoch and hand nothing between them."""
+
+    def __init__(self):
+        self.lock = threading.Lock()
+        self.last = None    # (_Key, epoch) of the newest open
+        self.ahead = None   # _Epoch of (that key, that epoch + 1)
+
+    def open(self, key: _Key, epoch: int, make):
+        """(this epoch's _Epoch, the next one's to build ahead or None).
+        The first is the one built ahead when the previous open of this
+        key, for epoch - 1, ran to its end; `make(epoch, credits)` builds
+        a fresh one."""
+        with self.lock:
+            ahead, self.ahead = self.ahead, None
+            if (ahead is not None and ahead.sealed and ahead.epoch == epoch
+                    and key.same(ahead.key)):
+                own = ahead
+            else:
+                if ahead is not None:
+                    ahead.stop(dropped=True)
+                own = make(epoch, None)
+            # from the second consecutive epoch of a key on: a one-off
+            # iterator never builds what nobody will ask for
+            if (self.last is not None and self.last[1] == epoch - 1
+                    and key.same(self.last[0])):
+                self.ahead = make(epoch + 1, own.credits)
+            self.last = (key, epoch)
+            return own, self.ahead
+
+    def close(self, ahead: Optional[_Epoch], ended: bool):
+        """The open that armed `ahead` is over: an epoch that ran to its
+        end hands it to the next open; any other end drops it."""
+        if ahead is None:
+            return
+        with self.lock:
+            if ended:
+                ahead.sealed = True
+                return
+            if self.ahead is ahead:
+                self.ahead = None
+        ahead.stop(dropped=True)
+
+
+_HANDOFF = _Handoff()
+
+
 def threaded_pair_batches(num_items: int,
                           get_pair,
                           batch_size: int,
@@ -114,11 +277,20 @@ def threaded_pair_batches(num_items: int,
 
     Same arguments and same batch sequence as
     common.iterate_pair_batches(workers=0); the pool only changes WHO
-    assembles each batch. At most max(workers, prefetch_batches) batches
-    are held assembled-but-unconsumed (bounded memory), enforced by a
-    credit semaphore the consumer refills. A worker exception is re-raised
-    on the consumer at the failing batch's position; abandoning the
-    generator stops the pool promptly.
+    assembles each batch, and WHEN. At most max(workers, prefetch_batches)
+    batches are held assembled-but-unconsumed (bounded memory), enforced by
+    a credit semaphore the consumer refills. A worker exception is
+    re-raised on the consumer at the failing batch's position; abandoning
+    the generator stops the pool promptly.
+
+    Lookahead: when this call is the next epoch of the call before it (same
+    arguments, `epoch - 1`), the pool, once this epoch's batches are all
+    claimed, builds the first batches of `epoch + 1` under the same credits
+    (at most the credit bound of them), and then exits. The next call, for
+    exactly `epoch + 1` with the same arguments, takes them if this
+    generator ran to its end; any other call drops them
+    (`data.lookahead.taken` / `data.lookahead.dropped`, and `ready` on the
+    `data.iterator.open` span).
 
     A worker that DIES (thread killed by a non-Exception, e.g. the chaos
     suite's WorkerKill) does not end the epoch: its claimed batch is
@@ -131,53 +303,69 @@ def threaded_pair_batches(num_items: int,
     opening = telemetry.span("data.iterator.open", epoch=epoch,
                              workers=workers)
     opening.__enter__()
-    order = common.shard_order(num_items, shuffle, seed, epoch, shard_index,
-                               num_shards)
-    nb = common.num_batches(len(order), batch_size, drop_last)
+    bound = max(workers, prefetch_batches, 1)
+    key = _Key(get_pair, collate,
+               (num_items, batch_size, shuffle, seed, drop_last, shard_index,
+                num_shards, workers, prefetch_batches))
 
-    pool_size = max(1, workers)
-    credits = threading.Semaphore(max(workers, prefetch_batches, 1))
-    cv = threading.Condition()
-    results: Dict[int, Dict] = {}
-    errors = []
-    requeue = []  # batch indices whose claiming worker died mid-assembly
-    next_batch = [0]  # next index to hand to a worker
-    stop = threading.Event()
+    def make(e, credits):
+        order = common.shard_order(num_items, shuffle, seed, e, shard_index,
+                                   num_shards)
+        return _Epoch(key, e, order,
+                      common.num_batches(len(order), batch_size, drop_last),
+                      credits or threading.Semaphore(bound))
+
+    own, ahead = _HANDOFF.open(key, epoch, make)
+    ahead_limit = min(bound, ahead.nb) if ahead is not None else 0
+    credits = own.credits
+    with own.cv:
+        ready = len(own.results)
+    opening.fields["ready"] = ready
+    if ready:
+        telemetry.counter("data.lookahead.taken").inc(ready)
+
+    def pick(take=True):
+        """(epoch, index) to build next: this epoch's, then the bounded
+        lookahead (never past an epoch that failed or was abandoned);
+        None when nothing is left to claim."""
+        with own.cv:
+            if own.stopped or own.errors:
+                return None
+            b = own.claim(own.nb, take)
+        if b is not None:
+            return own, b
+        if ahead is None:
+            return None
+        with ahead.cv:
+            b = ahead.claim(ahead_limit, take)
+        return None if b is None else (ahead, b)
 
     def worker():
-        while not stop.is_set():
+        while True:
             if not credits.acquire(timeout=0.1):
-                continue
-            with cv:
-                if errors or (next_batch[0] >= nb and not requeue):
-                    credits.release()
+                if pick(take=False) is None:
                     return
-                if requeue:
-                    b = requeue.pop()
-                else:
-                    b = next_batch[0]
-                    next_batch[0] += 1
+                continue
+            picked = pick()
+            if picked is None:
+                credits.release()
+                return
+            work, b = picked
             try:
-                with telemetry.span("data.assemble.batch", batch=b):
-                    batch = common.assemble_batch(get_pair, order, b,
-                                                  batch_size, seed, epoch,
-                                                  collate=collate)
+                with telemetry.span("data.assemble.batch", batch=b,
+                                    epoch=work.epoch):
+                    batch = common.assemble_batch(get_pair, work.order, b,
+                                                  batch_size, seed,
+                                                  work.epoch, collate=collate)
             except Exception as e:
-                with cv:
-                    errors.append((b, e))
-                    cv.notify_all()
+                work.fail(b, e)
                 return
             except BaseException:
                 # the thread is dying (injected kill / interpreter teardown):
                 # hand the claimed batch back so the pool can finish it
-                with cv:
-                    requeue.append(b)
-                    cv.notify_all()
-                credits.release()
+                work.hand_back(b)
                 return
-            with cv:
-                results[b] = batch
-                cv.notify_all()
+            work.finish(b, batch)
 
     def spawn(i):
         t = threading.Thread(target=worker, daemon=True,
@@ -185,23 +373,27 @@ def threaded_pair_batches(num_items: int,
         t.start()
         return t
 
+    pool_size = max(1, workers)
     threads = [spawn(i) for i in range(pool_size)]
     # a dead pool is respawned rather than fatal, but boundedly — a pool
     # that keeps dying (systemic failure, not one bad worker) must still
     # surface instead of flapping forever
     respawn_budget = 3 * pool_size
+    ended = False
     try:
-        for b in range(nb):
-            with cv:
-                while b not in results:
+        for b in range(own.nb):
+            with own.cv:
+                while b not in own.results:
                     # fail at the EARLIEST failing batch position so the
                     # consumer sees errors in sequence order
-                    pending_err = [e for eb, e in errors if eb <= b]
+                    pending_err = [e for eb, e in own.errors if eb <= b]
                     if pending_err:
                         raise pending_err[0]
-                    if not any(t.is_alive() for t in threads) \
-                            and b not in results:
-                        if respawn_budget > 0 and not errors:
+                    # (a batch built ahead may still be in the previous
+                    # epoch's pool's hands)
+                    if b not in own.building \
+                            and not any(t.is_alive() for t in threads):
+                        if respawn_budget > 0 and not own.errors:
                             respawn_budget -= 1
                             common.PIPELINE_STATS.record_respawn()
                             threads = [t for t in threads if t.is_alive()]
@@ -211,19 +403,20 @@ def threaded_pair_batches(num_items: int,
                         raise RuntimeError(
                             "assembler workers died without producing "
                             "batch %d" % b)
-                    cv.wait(0.1)
-                batch = results.pop(b)
+                    own.cv.wait(0.1)
+                batch = own.results.pop(b)
             if opening is not None:
                 opening.__exit__(None, None, None)
                 opening = None
             yield batch
             credits.release()
+        ended = True
     finally:
         if opening is not None:  # closed or failed before a first batch
             opening.__exit__(None, None, None)
-        stop.set()
-        with cv:
-            cv.notify_all()
+        if not ended:
+            own.stop()
+        _HANDOFF.close(ahead, ended)
 
 
 class StagedBatch(NamedTuple):

@@ -144,6 +144,179 @@ def test_exact_resume_mid_queue():
     resumed.close()
 
 
+# --------------------------------------------------------------------------
+# cross-epoch lookahead: a chained epoch's pool builds the next epoch's first
+# batches; the next open takes them only on an exact key match
+# --------------------------------------------------------------------------
+
+def _lookahead(name):
+    from mine_tpu import telemetry
+    return telemetry.counter("data.lookahead." + name).value
+
+
+def _assembler_threads():
+    return [t for t in threading.enumerate()
+            if t.name.startswith("mine-tpu-assembler")]
+
+
+def _assert_same(ref, got):
+    assert len(ref) == len(got)
+    for rb, gb in zip(ref, got):
+        assert sorted(rb) == sorted(gb)
+        for k in rb:
+            np.testing.assert_array_equal(rb[k], gb[k])
+
+
+def _epoch(get_pair, epoch, workers=3, pause=0.0, **kw):
+    """One epoch through iterate_pair_batches, `pause` s a batch (a step)."""
+    kw = dict(dict(num_items=23, batch_size=4, shuffle=True, seed=3), **kw)
+    out = []
+    for batch in iterate_pair_batches(kw.pop("num_items"), get_pair,
+                                      kw.pop("batch_size"), kw.pop("shuffle"),
+                                      epoch=epoch, workers=workers, **kw):
+        out.append(batch)
+        time.sleep(pause)
+    return out
+
+
+def test_chained_epochs_bitwise_and_taken():
+    """Three epochs opened one after another equal the workers=0 sequence
+    bitwise; the second chained open (epoch 2) takes what epoch 1's pool
+    built ahead, the first (epoch 1) finds nothing."""
+    get_pair = _make_get_pair(23, calls=[])   # a key no other test chains
+    taken = [_lookahead("taken")]
+    for e in range(3):
+        got = _epoch(get_pair, e, pause=0.01)
+        _assert_same(_epoch(get_pair, e, workers=0), got)
+        taken.append(_lookahead("taken"))
+        time.sleep(0.1)   # the loop's edge: the lookahead lands meanwhile
+    assert taken[1] == taken[0] and taken[2] == taken[1]
+    assert taken[3] > taken[2]
+
+
+def test_chained_epochs_under_thread_stress():
+    """More workers than cores, a switch interval of a microsecond: two
+    pools claim from one epoch at its edge, and every slot of every epoch
+    is still loaded exactly once, in the workers=0 bytes."""
+    import os
+    import sys
+
+    streams = []
+    base = _make_get_pair(23)
+
+    def get_pair(index, rng=None):
+        streams.append(rng.get_state()[1][0])
+        return base(index, rng)
+
+    workers = (os.cpu_count() or 8) + 2
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        got = [_epoch(get_pair, e, workers=workers, prefetch_batches=5)
+               for e in range(4)]
+    finally:
+        sys.setswitchinterval(interval)
+    for e, batches in enumerate(got):
+        _assert_same(_epoch(base, e, workers=0), batches)
+        slots = [common.item_rng(3, e, p).get_state()[1][0]
+                 for p in range(20)]
+        assert [streams.count(s) for s in slots] == [1] * 20, e
+
+
+@pytest.mark.parametrize("change", ["seed", "batch_size", "epoch_plus_2",
+                                    "dataset"])
+def test_lookahead_dropped_on_any_other_key(change):
+    """A next call with another seed, batch size, epoch + 2 or dataset takes
+    nothing built ahead, counts it dropped and yields the workers=0 bytes."""
+    get_pair = _make_get_pair(23, calls=[])
+    for e in (0, 1):   # epoch 1 is chained: its pool builds epoch 2 ahead
+        _epoch(get_pair, e)
+    time.sleep(0.3)
+    kw = {"seed": {"seed": 4}, "batch_size": {"batch_size": 3},
+          "epoch_plus_2": {"epoch": 3}, "dataset": {}}[change]
+    other = _make_get_pair(23, calls=[]) if change == "dataset" else get_pair
+    epoch = kw.pop("epoch", 2)
+    taken, dropped = _lookahead("taken"), _lookahead("dropped")
+    got = _epoch(other, epoch, **kw)
+    assert _lookahead("taken") == taken
+    assert _lookahead("dropped") > dropped
+    _assert_same(_epoch(other, epoch, workers=0, **kw), got)
+
+
+@pytest.mark.parametrize("end", ["exhausted", "abandoned"])
+def test_no_assembler_left_after_the_last_epoch(end):
+    """No assembler thread outlives the last epoch's generator by 5 s, with
+    lookahead work still in flight when it ends (run out or closed)."""
+    def slow(index, rng=None, base=_make_get_pair(40)):
+        time.sleep(0.02)
+        return base(index, rng)
+
+    _epoch(slow, 0, num_items=40)
+    it = iterate_pair_batches(40, slow, 4, True, seed=3, epoch=1, workers=3)
+    if end == "exhausted":
+        list(it)
+    else:
+        for _ in range(9):   # every batch claimed: the lookahead is running
+            next(it)
+    it.close()
+    deadline = time.time() + 5.0
+    while _assembler_threads() and time.time() < deadline:
+        time.sleep(0.02)
+    assert not _assembler_threads()
+
+
+def test_credit_bound_holds_across_the_edge():
+    """With an epoch's last batch taken, at most max(workers,
+    prefetch_batches) batches of the next epoch are built, and no more
+    when the generator has run out."""
+    calls = []
+    get_pair = _make_get_pair(64, calls=calls)
+    kw = dict(num_items=64, shuffle=False, workers=2, prefetch_batches=3)
+    _epoch(get_pair, 0, **kw)
+    it = iterate_pair_batches(64, get_pair, 4, False, seed=3, epoch=1,
+                              workers=2, prefetch_batches=3)
+    for _ in range(16):
+        next(it)
+    time.sleep(0.3)
+    ahead = len(calls) - 2 * 64
+    assert 0 < ahead <= 3 * 4
+    assert next(it, None) is None
+    time.sleep(0.3)
+    assert len(calls) - 2 * 64 <= 3 * 4
+
+
+def test_worker_killed_in_lookahead_loses_and_duplicates_nothing():
+    """The sole worker dies (testing/faults.py kill) on the second item of
+    epoch 2's first batch, which it builds ahead after epoch 1: the batch is
+    handed back and built again, once, by the next open's pool."""
+    from mine_tpu.testing import faults
+
+    streams = []   # the item stream of every load: (epoch, slot) apart
+    base = _make_get_pair(23)
+
+    def get_pair(index, rng=None):
+        streams.append(rng.get_state()[1][0])
+        return base(index, rng)
+
+    ref = [_epoch(get_pair, e, workers=0) for e in range(3)]
+    del streams[:]
+    # 20 loads an epoch: epoch 0, epoch 1, then epoch 2's batch 0 ahead
+    faults.set_plan(faults.FaultPlan(kill_worker_at_call=42))
+    got = []
+    try:
+        for e in range(3):
+            got.append(_epoch(get_pair, e, workers=1))
+            time.sleep(0.2)   # the lookahead runs (and dies) before the open
+    finally:
+        faults.set_plan(None)
+    for r, g in zip(ref, got):
+        _assert_same(r, g)
+    # the one item loaded before the kill is loaded again with its batch
+    # (epoch 2's pool builds epoch 3 ahead meanwhile: not counted)
+    epoch2 = {common.item_rng(3, 2, p).get_state()[1][0] for p in range(20)}
+    assert sum(s in epoch2 for s in streams) == 20 + 1
+
+
 def test_device_stager_order_values_and_timing():
     import jax.numpy as jnp
 

@@ -399,6 +399,34 @@ def test_feed_spans_of_one_epoch():
     assert not by("data.host.starved")   # nobody named a stage "host" here
 
 
+def test_epoch_open_carries_ready_and_is_short_when_ready():
+    """`data.iterator.open` carries `ready`: 0 on a cold open, and on the
+    open that finds the lookahead's batches it closes before building the
+    first batch would have taken."""
+    from mine_tpu.data.pipeline import threaded_pair_batches
+
+    def get_pair(index, rng=None):
+        time.sleep(0.005)
+        item = {"img": np.zeros((4, 4, 3), np.float32),
+                "K": np.eye(3, dtype=np.float32),
+                "xyzs": np.zeros((3, 2), np.float32)}
+        return item, dict(item, G_src_tgt=np.eye(4, dtype=np.float32))
+
+    mark = _mark()
+    for epoch in range(3):
+        list(threaded_pair_batches(12, get_pair, batch_size=3, shuffle=True,
+                                   epoch=epoch, workers=2))
+        time.sleep(0.1)   # the loop's edge: the lookahead lands meanwhile
+    recs = _since(mark)
+    opens = [r for r in recs if r.name == "data.iterator.open"]
+    assert [r.fields["epoch"] for r in opens] == [0, 1, 2]
+    assert opens[0].fields["ready"] == 0 and opens[1].fields["ready"] == 0
+    assert opens[2].fields["ready"] > 0
+    (first,) = [r for r in recs if r.name == "data.assemble.batch"
+                and r.fields["epoch"] == 2 and r.fields["batch"] == 0]
+    assert opens[2].ms < first.ms
+
+
 # ---------------- the train step, its op map, its scopes ----------------
 
 def test_layer_of_name_paths():

@@ -51,8 +51,11 @@ def prefetch(iterator: Iterator, depth: int = 2,
              name: str = "host") -> Iterator:
     """Background-thread prefetch: overlaps producing `iterator`'s items
     with whatever the consumer does between `next()` calls. `name` is the
-    stage's: a consumer that finds the queue empty waits inside a
-    `data.<name>.starved` span (telemetry/spans.py).
+    stage's: every item the consumer takes is one `data.<name>.take` span
+    (a few us on the consumer's thread, ending just before the item is
+    handed over: it places the consumer's caller on a profiler's clock),
+    and a consumer that finds the queue empty waits inside a
+    `data.<name>.starved` span inside it (telemetry/spans.py).
 
     Abandoning the generator (consumer raised / broke out) stops the
     producer promptly instead of leaving a thread blocked on a full queue
@@ -84,14 +87,15 @@ def prefetch(iterator: Iterator, depth: int = 2,
     t = threading.Thread(target=producer, daemon=True,
                          name="mine-tpu-prefetch")
     t.start()
-    starved = "data.%s.starved" % name
+    take, starved = "data.%s.take" % name, "data.%s.starved" % name
     try:
         while True:
-            try:
-                item = q.get_nowait()
-            except queue.Empty:
-                with telemetry.span(starved):
-                    item = q.get()
+            with telemetry.span(take):
+                try:
+                    item = q.get_nowait()
+                except queue.Empty:
+                    with telemetry.span(starved):
+                        item = q.get()
             if item is _END:
                 if err:
                     raise err[0]
@@ -438,6 +442,8 @@ class DeviceStager:
 
     Iterating yields StagedBatch(batch, h2d_ms). Producer exceptions
     re-raise on the consumer; abandoning the iterator stops the thread.
+    The thread's time is two spans a batch: `data.stage.host_wait` (the
+    next host batch) and `data.stage.h2d` (its copy).
     """
 
     def __init__(self, host_batches: Iterator[Dict],
@@ -450,7 +456,14 @@ class DeviceStager:
     def __iter__(self) -> Iterator[StagedBatch]:
         def stage():
             import jax
-            for np_batch in self._host_batches:
+            host = iter(self._host_batches)
+            while True:
+                # the stager's wait for a host batch (an epoch's open, a
+                # batch still being assembled)
+                with telemetry.span("data.stage.host_wait"):
+                    np_batch = next(host, _END)
+                if np_batch is _END:
+                    return
                 with telemetry.span("data.stage.h2d") as h2d:
                     dev = self._put_fn(np_batch)
                     jax.block_until_ready(dev)

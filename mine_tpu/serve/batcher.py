@@ -333,21 +333,12 @@ class ContinuousBatcher(MicroBatcher):
     new requests keep boarding while a render is in flight and the next
     bucket is typically full by the time the engine returns.
 
-    Same queue-wait / coalesce-size histograms as MicroBatcher (the flush
-    path is inherited); `serve.batcher.flush_full` / `flush_deadline`
-    count which trigger fired — the same full-vs-deadline verdict each
-    request's "queue" trace span carries as `flush_cause`. Tests drive
+    Same queue-wait / coalesce-size histograms and flush spans as
+    MicroBatcher (the flush path is inherited): which trigger fired is the
+    `serve.batcher.flush` span's `cause`, "full" or "deadline". Tests drive
     `_ready` and `flush()` directly with start=False (no timing
     dependence); `close()` joins the deadline loop like the base class.
     """
-
-    def flush(self) -> int:
-        n = super().flush()
-        if n:
-            telemetry.counter(
-                "serve.batcher.flush_full" if n >= self.max_requests
-                else "serve.batcher.flush_deadline").inc()
-        return n
 
     def _ready(self, now: float) -> bool:
         """Dispatch decision (callers hold self._cv): full bucket, expired

@@ -58,6 +58,7 @@ QUICK = {
     "test_telemetry.py::test_histogram_quantiles_match_numpy",
     "test_tracing.py::test_sampling_gate",
     "test_spans.py::test_record_fields_nesting_and_thread",
+    "test_idle_spans.py::test_a_gap_is_split_where_the_span_changes",
     "test_obs_tools.py::test_report_empty_stream",
     "test_losses.py::test_psnr_analytic",
     "test_mesh.py::test_num_slices",

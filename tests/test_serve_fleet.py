@@ -304,17 +304,17 @@ def test_continuous_batcher_flush_trigger_counters(scene):
 
     engine = _put_scene(RenderEngine(cache=MPICache(quant="bf16"),
                                      max_bucket=4), scene)
-    full = telemetry.counter("serve.batcher.flush_full").value
-    deadline = telemetry.counter("serve.batcher.flush_deadline").value
+    mark = telemetry.spans.record("test.mark", 0, 0)
     b = ContinuousBatcher(engine, max_requests=2, max_wait_ms=50.0,
                           start=False)
     futs = [b.submit("img", scene["poses"][j]) for j in range(2)]
     assert b.flush() == 2                          # full bucket
-    assert telemetry.counter("serve.batcher.flush_full").value == full + 1
     b.submit("img", scene["poses"][2])
     assert b.flush() == 1                          # partial: deadline path
-    assert telemetry.counter(
-        "serve.batcher.flush_deadline").value == deadline + 1
+    flushes = [r for r in telemetry.spans.records("serve.batcher.flush")
+               if r.span_id > mark]
+    assert [(r.fields["n"], r.fields["cause"]) for r in flushes] == [
+        (2, "full"), (1, "deadline")]
     for f in futs:
         rgb, depth = f.result(timeout=5)
         assert rgb.shape == (3, H, W) and depth.shape == (1, H, W)

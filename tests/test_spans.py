@@ -391,11 +391,28 @@ def test_feed_spans_of_one_epoch():
     h2d = by("data.stage.h2d")
     assert len(h2d) == 4
     assert [s.h2d_ms for s in staged] == [r.ms for r in h2d]
+    # every copy follows the stager's wait for its host batch, on the
+    # stager's thread (one more wait meets the epoch's end); the epoch's
+    # open happens inside the first wait
+    host_wait = by("data.stage.host_wait")
+    stager = sorted(h2d + host_wait, key=lambda r: r.t0_ns)
+    assert {r.thread for r in stager} == {opened.thread}
+    assert [r.name for r in stager] == [
+        "data.stage.host_wait", "data.stage.h2d"] * 4 + [
+        "data.stage.host_wait"]
+    assert opened.parent == host_wait[0].span_id
     starved = by("data.stage.starved")
     # the consumer met an empty queue at least at the epoch's edge, on its
     # own thread, and waited there for the first batch to be built
     assert starved and starved[0].thread == threading.current_thread().name
     assert starved[0].t1_ns >= opened.t1_ns
+    # each take of a batch (and of the epoch's end) is a span on the
+    # consumer's thread; a starved wait lies inside its take
+    takes = by("data.stage.take")
+    assert len(takes) == 5
+    assert {r.thread for r in takes} == {threading.current_thread().name}
+    ids = {r.span_id for r in takes}
+    assert all(r.parent in ids for r in starved)
     assert not by("data.host.starved")   # nobody named a stage "host" here
 
 
